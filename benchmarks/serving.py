@@ -39,7 +39,7 @@ def _percentile(walls, q: float) -> float:
     return float(np.percentile(np.asarray(walls, dtype=np.float64), q))
 
 
-def _build_flow(data, name: str = "serve-ssb"):
+def build_flow(data, name: str = "serve-ssb"):
     """Serving flow over the lineorder schema: customer-nation lookup,
     region filter, derived profit, terminal group-by aggregate."""
     cust = (data.customer["c_custkey"],
@@ -64,7 +64,7 @@ def _build_flow(data, name: str = "serve-ssb"):
 
 
 def _batch_flow(data, name: str = "serve-ssb-batch"):
-    f = _build_flow(data, name)
+    f = build_flow(data, name)
     src = next(c for c in f.flow.vertices.values()
                if type(c).__name__ == "ArraySource")
     src.set_data(data.lineorder)
@@ -81,7 +81,7 @@ def _serve_loop(data, backend, ticks: int = TICKS):
     """Run one full serve loop; returns (tick_results, summary)."""
     session = repro.Session(backend=backend, metadata=None)
     results = []
-    with session.serve(_build_flow(data)) as srv:
+    with session.serve(build_flow(data)) as srv:
         for t, batch in enumerate(_tick_batches(data.lineorder, ticks)):
             results.append(srv.tick(batch, watermark=time.time()))
         srv.close()

@@ -4,12 +4,13 @@ Kernels:
   - ``searchsorted_probe`` / ``lookup_gather`` — probe over a device-cached
     dimension table (keys/qualifies/payload are device_put once per table and
     reused across every chunk).  Default route is the ``kernels/hash_join``
-    open-addressing table (host-built once per DimTable, probes handle
-    arbitrary key order and multi-column keys); ``REPRO_JOIN_IMPL=
-    searchsorted`` selects the legacy jitted binary search over the sorted
-    keys.  Both return the same (index, matched) pair bit-for-bit: the hash
-    build keeps the FIRST occurrence of a duplicate key, which over the
-    DimTable's sorted keys is exactly ``searchsorted``'s leftmost index.
+    open-addressing table (host-built once per DimTable, probed through XLA;
+    probes handle arbitrary key order and multi-column keys);
+    ``REPRO_JOIN_IMPL=searchsorted`` selects the legacy jitted binary search
+    over the sorted keys.  Both return the same (index, matched) pair
+    bit-for-bit: the hash build keeps the FIRST occurrence of a duplicate
+    key, which over the DimTable's sorted keys is exactly ``searchsorted``'s
+    leftmost index.
   - ``groupby_reduce`` — dense integer key spaces route through
     ``kernels/radix_groupby`` (radix-partitioned one-hot matmul, no sort);
     sparse/non-integer/huge key spaces fall back to the legacy lexsort +
@@ -32,9 +33,11 @@ width so planner channel sizing matches what actually crosses an edge.
 """
 from __future__ import annotations
 
+import os
 import threading
 import time
 import weakref
+from pathlib import Path
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -45,6 +48,35 @@ from ..expr import ColumnsView, Expr
 from ..shared_cache import (GLOBAL_ARENA, is_host_column, record_dim_upload,
                             record_segment_compile, record_transfer)
 from .base import AGG_OPS, Backend, SegmentEnv
+
+
+def _checkout_cache_dir() -> Optional[Path]:
+    """``<checkout>/.jax_cache`` when ``repro`` is imported from a source
+    checkout (``src/`` beside ``pyproject.toml``), else ``None``."""
+    root = Path(__file__).resolve().parents[4]
+    return root / ".jax_cache" if (root / "pyproject.toml").is_file() else None
+
+
+#: persistent compile cache when ``JAX_COMPILATION_CACHE_DIR`` is unset: a
+#: fixed directory in the source checkout (the path is part of every cache
+#: key, so a per-run name would never hit).  An installed package has no
+#: checkout, and then only ``JAX_COMPILATION_CACHE_DIR`` turns the cache on.
+COMPILE_CACHE_DIR = _checkout_cache_dir()
+
+
+def place_compile_cache(jax) -> None:
+    """Turn on JAX's persistent compilation cache.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` (or ``jax_compilation_cache_dir``) is
+    already set, JAX uses that and nothing is changed; otherwise the cache
+    goes to ``COMPILE_CACHE_DIR``, if there is one."""
+    if (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or jax.config.jax_compilation_cache_dir
+            or COMPILE_CACHE_DIR is None):
+        return
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    # a compile before this point settled the cache as unused: re-check
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 class _DeviceCacheView:
@@ -108,12 +140,16 @@ class JaxBackend(Backend):
     #: non-transient kernel failure the route walks ONE rung right and stays
     #: there for this backend instance's lifetime.  Every rung is
     #: bit-identical to its neighbours by the kernels' own equivalence tests.
-    _JOIN_LADDER = ("pallas", "interpret", "reference", "searchsorted")
-    _GROUPBY_LADDER = ("pallas", "interpret", "reference", "sort")
+    #: Interpret mode is no rung: on a chip it is a silent slowdown of orders
+    #: of magnitude (the group-by keeps it selectable explicitly, for CPU
+    #: tests, and a failing explicit interpret route degrades like pallas).
+    _JOIN_LADDER = ("reference", "searchsorted")
+    _GROUPBY_LADDER = ("pallas", "reference", "sort")
 
     def __init__(self) -> None:
         import jax                       # deferred: registry creates lazily
         import jax.numpy as jnp
+        place_compile_cache(jax)
         from ...kernels.hash_join import hash_build, hash_probe, hash_probe_ref
         from ...kernels.radix_groupby import radix_groupby
         from ...kernels.segment_sum import segment_sum
@@ -160,7 +196,8 @@ class JaxBackend(Backend):
                 or not config.degrade_enabled()):
             return None
         ladder = self._JOIN_LADDER if kind == "join" else self._GROUPBY_LADDER
-        i = ladder.index(impl) if impl in ladder else 0   # "auto" => rung 0
+        impl = self._resolve_impl(kind, impl)
+        i = ladder.index(impl) if impl in ladder else 0   # interpret => rung 0
         if i + 1 >= len(ladder):
             return None
         nxt = ladder[i + 1]
@@ -171,6 +208,15 @@ class JaxBackend(Backend):
         else:
             self._groupby_route = nxt
         return nxt
+
+    def _resolve_impl(self, kind: str, impl: str) -> str:
+        """The rung ``auto`` stands for: the XLA hash probe for the join,
+        the Pallas group-by on a TPU and its jnp reference elsewhere."""
+        if impl != "auto":
+            return impl
+        if kind == "join" or self._jax.default_backend() != "tpu":
+            return "reference"
+        return "pallas"
 
     def _view(self, cache) -> _DeviceCacheView:
         with self._views_lock:
@@ -348,7 +394,8 @@ class JaxBackend(Backend):
         if pad:
             v = self._jnp.concatenate([v, self._jnp.full((pad,), dim.keys[0],
                                                          dtype=v.dtype)])
-        impl = self._join_route or config.join_impl()
+        impl = self._resolve_impl("join",
+                                  self._join_route or config.join_impl())
         while True:
             try:
                 if faults.active():
@@ -360,7 +407,7 @@ class JaxBackend(Backend):
                     ht = self._dim_hash(dim)
                     idx, found = self._hash_probe(
                         ht["slot_keys"], ht["slot_idx"], (v,),
-                        ht["max_probes"], impl=impl)
+                        ht["max_probes"])
                     matched = found & dev["qualifies"][idx]
                 break
             except BaseException as e:
@@ -393,14 +440,15 @@ class JaxBackend(Backend):
                     s = self._segment_sum(zeros,
                                           vals.astype(jnp.float32)[:, None],
                                           1, impl=self._segsum_impl)[:, 0]
-                    aggs[out] = s / n if op == "avg" else s
+                    aggs[out] = self._avg(s, [n]) if op == "avg" else s
                 elif op == "min":
                     aggs[out] = jnp.min(vals)[None]
                 elif op == "max":
                     aggs[out] = jnp.max(vals)[None]
             return [], aggs
         keys_d = [self.asarray(k) for k in keys]
-        impl = self._groupby_route or config.groupby_impl()
+        impl = self._resolve_impl("groupby",
+                                  self._groupby_route or config.groupby_impl())
         while impl != "sort":
             try:
                 if faults.active():
@@ -426,7 +474,6 @@ class JaxBackend(Backend):
         counts_h = np.diff(np.append(starts_h, n))
         starts = jnp.asarray(starts_h)
         group_cols = [k[starts] for k in sk]
-        counts_d = jnp.asarray(counts_h)
         aggs = {}
         for out, (col, op) in values.items():
             if op == "count":
@@ -438,7 +485,7 @@ class JaxBackend(Backend):
                 # tile on TPU, jnp segment_sum reference on CPU
                 s = self._segment_sum(seg, vals.astype(jnp.float32)[:, None],
                                       n_groups, impl=self._segsum_impl)[:, 0]
-                aggs[out] = s / counts_d if op == "avg" else s
+                aggs[out] = self._avg(s, counts_h) if op == "avg" else s
             elif op == "min":
                 aggs[out] = self._jax.ops.segment_min(vals, seg,
                                                       num_segments=n_groups)
@@ -446,6 +493,14 @@ class JaxBackend(Backend):
                 aggs[out] = self._jax.ops.segment_max(vals, seg,
                                                       num_segments=n_groups)
         return group_cols, aggs
+
+    def _avg(self, sums, counts) -> np.ndarray:
+        """``sums / counts`` divided on the host, where IEEE division rounds
+        once in the sum's dtype.  The TPU's float32 divide is not correctly
+        rounded, and serving emits and shard merges divide on the host, so
+        this keeps every route's averages bit-identical."""
+        s = self.to_host(sums)
+        return s / np.asarray(counts).astype(s.dtype)
 
     def _groupby_dense(self, keys_d: List, values: Mapping[str, Tuple[object, str]],
                        n: int, impl: str):
@@ -500,14 +555,13 @@ class JaxBackend(Backend):
         occ_d = jnp.asarray(occ.astype(np.int32))
         group_cols = [((occ_d // st) % rg + mn).astype(k.dtype)
                       for k, mn, st, rg in zip(keys_d, mins, strides, ranges)]
-        counts_d = jnp.asarray(counts_h[occ])
         aggs: Dict[str, object] = {}
         for out, (col, op) in values.items():
             if op == "count":
                 aggs[out] = counts_h[occ]
             elif op in ("sum", "avg"):
                 s = sums[occ_d, sum_outs.index(out)]
-                aggs[out] = s / counts_d if op == "avg" else s
+                aggs[out] = self._avg(s, counts_h[occ]) if op == "avg" else s
             else:  # min / max: one segment reduce over the dense ids
                 fn = (self._jax.ops.segment_min if op == "min"
                       else self._jax.ops.segment_max)
@@ -646,6 +700,50 @@ class _JaxSegmentRunner:
         return out, keep_mask
 
     # ------------------------------------------------------------ execution
+    def pack_layout(self, bucket: int, columns) -> Tuple[list, int]:
+        """Staging-buffer layout of ``(name, host dtype)`` columns padded to
+        ``bucket`` rows: ``([(name, device dtype str, byte offset)], total
+        bytes)`` — the static half of the kernel's layout key."""
+        entries = []
+        off = 0
+        for name, dtype in columns:
+            cd = np.dtype(self._jax.dtypes.canonicalize_dtype(dtype))
+            entries.append((name, cd.str, off))
+            off += bucket * cd.itemsize
+        return entries, off
+
+    def device_dims(self) -> list:
+        """Device mirrors of every looked-up DimTable, in op order — uploaded
+        once per table (cached on the table) and structurally identical per
+        call, so the pytree is built once and per-chunk Python cost stays
+        flat.  Also fixes each lookup's static probe bound."""
+        if self._dims is None:
+            bk = self._bk
+            dims = []
+            max_probes = []
+            for op in self.ops:
+                if op[0] == "lookup":
+                    _, dim, _, return_cols, _, _ = op
+                    dev = bk._dim_device(dim)
+                    entry = {
+                        "keys": dev["keys"],
+                        "qualifies": dev["qualifies"],
+                        "payload": {dcol: bk._dim_payload(dim, dcol)
+                                    for dcol in return_cols.values()},
+                    }
+                    if (self._join_impl != "searchsorted"
+                            and len(dim.keys) > 0):
+                        ht = bk._dim_hash(dim)
+                        entry["slot_keys"] = ht["slot_keys"]
+                        entry["slot_idx"] = ht["slot_idx"]
+                        max_probes.append(ht["max_probes"])
+                    else:
+                        max_probes.append(0)   # 0 => legacy searchsorted
+                    dims.append(entry)
+            self._max_probes = max_probes
+            self._dims = dims
+        return self._dims
+
     def __call__(self, cache) -> None:
         bk = self._bk
         jnp = self._jnp
@@ -673,13 +771,8 @@ class _JaxSegmentRunner:
 
         # pack every 1-D host input into ONE staging buffer (canonical
         # device dtypes, zeroed pad tail) and upload it with a single h2d
-        entries = []
-        off = 0
-        for name, v in packable:
-            cd = np.dtype(self._jax.dtypes.canonicalize_dtype(v.dtype))
-            entries.append((name, cd.str, off))
-            off += bucket * cd.itemsize
-        total = off
+        entries, total = self.pack_layout(
+            bucket, [(name, v.dtype) for name, v in packable])
         if total:
             staging, root = GLOBAL_ARENA.acquire(np.uint8, (total,))
             for (name, v), (_, dtype_str, off) in zip(packable, entries):
@@ -698,41 +791,14 @@ class _JaxSegmentRunner:
         else:
             packed = jnp.zeros((0,), np.uint8)
 
-        if self._dims is None:
-            # device mirrors of every looked-up DimTable — uploaded once per
-            # table (cached on the table), structurally identical per call,
-            # so building the pytree once keeps per-chunk Python cost flat
-            dims = []
-            max_probes = []
-            for op in self.ops:
-                if op[0] == "lookup":
-                    _, dim, _, return_cols, _, _ = op
-                    dev = bk._dim_device(dim)
-                    entry = {
-                        "keys": dev["keys"],
-                        "qualifies": dev["qualifies"],
-                        "payload": {dcol: bk._dim_payload(dim, dcol)
-                                    for dcol in return_cols.values()},
-                    }
-                    if (self._join_impl != "searchsorted"
-                            and len(dim.keys) > 0):
-                        ht = bk._dim_hash(dim)
-                        entry["slot_keys"] = ht["slot_keys"]
-                        entry["slot_idx"] = ht["slot_idx"]
-                        max_probes.append(ht["max_probes"])
-                    else:
-                        max_probes.append(0)   # 0 => legacy searchsorted
-                    dims.append(entry)
-            self._max_probes = max_probes
-            self._dims = dims
-
         layout = (bucket, tuple(entries))
+        dims = self.device_dims()
         if layout not in self._layouts:
             # a layout never seen by this runner => the jit call below traces
             # and compiles a fresh executable for it
             self._layouts.add(layout)
             record_segment_compile()
-        out_cols, keep_mask = self._jit(layout, packed, dev_cols, self._dims)
+        out_cols, keep_mask = self._jit(layout, packed, dev_cols, dims)
         self.kernel_calls += 1
 
         final_live = self._final_live(self.ops, cache.names)

@@ -54,9 +54,9 @@ ENV_OPTEQ_EXAMPLES = "REPRO_OPTEQ_EXAMPLES"
 #: segment-sum kernel implementation selector ("auto" / "pallas" /
 #: "interpret" / "reference")
 ENV_SEGSUM_IMPL = "REPRO_SEGSUM_IMPL"
-#: Lookup probe route on the jax backend: hash-join kernel impls ("auto" /
-#: "pallas" / "interpret" / "reference") or "searchsorted" (legacy
-#: binary-search probe over the sorted DimTable)
+#: Lookup probe route on the jax backend: "auto" / "reference" (the
+#: kernels.hash_join open-addressing probe, through XLA) or "searchsorted"
+#: (legacy binary-search probe over the sorted DimTable)
 ENV_JOIN_IMPL = "REPRO_JOIN_IMPL"
 #: groupby route on the jax backend: radix-groupby kernel impls ("auto" /
 #: "pallas" / "interpret" / "reference") or "sort" (legacy lexsort +
@@ -121,7 +121,7 @@ DEAD_LETTER_MAX = 256
 DEFAULT_ARENA_MAX_MB = 256
 DEFAULT_OPTEQ_EXAMPLES = 100
 FLOW_STYLES = ("dsl", "lambda")
-JOIN_IMPLS = ("auto", "pallas", "interpret", "reference", "searchsorted")
+JOIN_IMPLS = ("auto", "reference", "searchsorted")
 GROUPBY_IMPLS = ("auto", "pallas", "interpret", "reference", "sort")
 SHARD_IMPLS = ("auto", "process", "mesh", "inline")
 
@@ -179,8 +179,8 @@ def segsum_impl() -> str:
 
 
 def join_impl() -> str:
-    """Lookup probe route on the jax backend: a hash-join kernel impl or
-    "searchsorted" for the legacy binary-search probe."""
+    """Lookup probe route on the jax backend: the hash-join probe ("auto"
+    or "reference") or "searchsorted" for the legacy binary-search probe."""
     v = _raw(ENV_JOIN_IMPL) or "auto"
     if v not in JOIN_IMPLS:
         raise ValueError(

@@ -78,6 +78,10 @@ class EngineRun:
     #: (1 = serial) and the source rows each shard processed
     shards: int = 1
     shard_rows: List[int] = field(default_factory=list)
+    #: where the sharded run lived: per shard pass, the devices of its cuts'
+    #: device-resident inputs; and the devices of the merge mesh
+    shard_devices: List[List[str]] = field(default_factory=list)
+    merge_devices: List[str] = field(default_factory=list)
     # adaptive path (optimize_level=2): graph rewrites applied before the run
     rewrites: List[Dict[str, str]] = field(default_factory=list)
     # rewrites the optimizer REFUSED for safety (with reasons) — refusals
@@ -521,6 +525,8 @@ class OptimizedEngine:
                 # the exact sum over all shards on every route
                 run.shards = sres.shards
                 run.shard_rows = list(sres.shard_rows)
+                run.shard_devices = list(sres.shard_devices)
+                run.merge_devices = list(sres.merge_devices)
                 # dispatch counts live on Component.calls; process-route
                 # shard passes ran on worker flow copies, so fold their
                 # shipped totals in — inline passes already hit self.flow

@@ -21,6 +21,13 @@ _RUN_STR_FIELDS = ("engine", "backend", "run_id", "created", "git_sha",
                    "trace_file")
 
 
+def _children(root: ET.Element, tag: str) -> List[ET.Element]:
+    """Children of ``root``'s ``tag`` element, or ``[]`` when it is absent
+    (an Element's truth value is its child count, so test ``is None``)."""
+    node = root.find(tag)
+    return list(node) if node is not None else []
+
+
 class MetadataStore:
     def __init__(self) -> None:
         self.component_specs: Dict[str, Dict[str, str]] = {}
@@ -150,15 +157,15 @@ class MetadataStore:
     def from_xml(cls, text: str) -> "MetadataStore":
         store = cls()
         root = ET.fromstring(text)
-        for c in root.find("components") or []:
+        for c in _children(root, "components"):
             store.component_specs[c.attrib["name"]] = dict(c.attrib)
-        for f in root.find("dataflows") or []:
+        for f in _children(root, "dataflows"):
             store.dataflows[f.attrib["name"]] = {
                 "name": f.attrib["name"],
                 "vertices": [],
                 "edges": [[e.attrib["src"], e.attrib["dst"]] for e in f],
             }
-        for pf in root.find("partitions") or []:
+        for pf in _children(root, "partitions"):
             store.partitions[pf.attrib["dataflow"]] = {
                 "trees": [{"id": int(t.attrib["id"]), "root": t.attrib["root"],
                            "members": t.attrib["members"].split(",")}
@@ -166,7 +173,7 @@ class MetadataStore:
                 "edges": [[int(e.attrib["src"]), int(e.attrib["dst"])]
                           for e in pf if e.tag == "tree-edge"],
             }
-        for r in root.find("runs") if root.find("runs") is not None else []:
+        for r in _children(root, "runs"):
             spec: dict = {}
             for k in _RUN_STR_FIELDS:
                 if k in r.attrib:

@@ -107,6 +107,9 @@ class ShardContext:
         self.generic: Dict[str, List[GenericStash]] = {}
         #: bytes stashed for the coordinator merge (the "shuffle" volume)
         self.shuffle_bytes = 0
+        #: pass -> devices ("platform:id") holding the device-resident
+        #: columns its cuts received
+        self.placement: Dict[int, set] = {}
 
     # ------------------------------------------------------------- passes
     def begin_pass(self, k: int) -> None:
@@ -151,12 +154,21 @@ class ShardContext:
         set.  ``tags`` carries the executor's ``(src_tree, split_index)``
         per accumulated cache, in accumulation order."""
         if root.shard_role == "partial":
+            self._note_placement(state)
             if hasattr(root, "shard_partial"):
                 return self._partial_agg(root, state)
             return self._partial_generic(root, state, tags)
         if hasattr(root, "shard_partial"):
             return self._merge_agg(root, state)
         return self._merge_generic(root, state, tags)
+
+    def _note_placement(self, state: List[SharedCache]) -> None:
+        devices = {f"{d.platform}:{d.id}" for cache in state
+                   for col in cache.columns.values()
+                   for d in (col.devices() if hasattr(col, "devices")
+                             else ())}
+        with self._lock:
+            self.placement.setdefault(self.pass_k, set()).update(devices)
 
     # ---------------------------------------------------------- partials
     def _partial_agg(self, root, state: List[SharedCache]) -> SharedCache:

@@ -4,14 +4,15 @@
 ``Aggregate.shard_merge``: concatenated per-shard partial rows are
 aligned to dense ``[groups]`` vectors (group ids from ``np.unique`` over
 the key tuple — lexicographic, matching the backend's group order),
-scattered over the ``(data,)`` axis of a host mesh
-(``launch.mesh.make_host_mesh(model=None)``), locally segment-reduced on
-each device, and combined with ``psum``/``pmin``/``pmax``.
+scattered over the ``(data,)`` axis of a mesh of every device
+(``jax.make_mesh``), locally segment-reduced on each device, and combined
+with ``psum``/``pmin``/``pmax``.
 
 Exactness contract: outputs are cast back to the stage-1 partial dtypes,
 and when jax runs without x64 the combiner refuses (returns ``None`` —
-the caller falls back to the host ``reduce_partials``) any input whose
-values would not round-trip through the 32-bit canonical dtypes.  Rows
+the caller falls back to the host ``reduce_partials``, and the refusal is
+recorded as a ``shard_impl`` Degradation) any input whose values would not
+round-trip through the 32-bit canonical dtypes.  Rows
 padded to a multiple of the device count carry the op identity and land
 in group 0, so they never perturb a real group.
 """
@@ -20,6 +21,8 @@ from __future__ import annotations
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
+
+from .. import faults
 
 
 def _canon_dtype(dtype: np.dtype, x64: bool) -> np.dtype:
@@ -44,26 +47,23 @@ def _identity(op: str, dtype: np.dtype):
     return dtype.type(np.inf if op == "min" else -np.inf)
 
 
-def make_combiner() -> Optional[Callable]:
+def make_combiner() -> Callable:
     """A ``combine(cat, group_names, ops)`` closure with the same contract
     as ``merge.reduce_partials`` — except it may return ``None`` per call
-    (unsafe dtypes), in which case the caller uses the host reduce.
-    Returns ``None`` outright when jax or a device mesh is unavailable."""
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
+    (no rows, or dtypes that would not round-trip), in which case the caller
+    uses the host reduce.  The mesh spans every device of the default
+    backend and is kept as ``combine.mesh``; a failure to build it
+    propagates."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
 
-        from ...launch.jax_compat import shard_map
-        from ...launch.mesh import make_host_mesh
-        devices = jax.devices()
-        if not devices:
-            return None
-        D = len(devices)
-        mesh = make_host_mesh(data=D, model=None)
-    except Exception:
-        return None
-    x64 = bool(getattr(jax.config, "jax_enable_x64", False))
+    devices = jax.devices()
+    D = len(devices)
+    mesh = jax.make_mesh((D,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=devices)
+    x64 = bool(jax.config.jax_enable_x64)
 
     def _mesh_reduce(v: np.ndarray, inv: np.ndarray, n_groups: int,
                      op: str) -> np.ndarray:
@@ -78,8 +78,8 @@ def make_combiner() -> Optional[Callable]:
                 return jax.lax.pmin(full.at[ii].min(vv), "data")
             return jax.lax.pmax(full.at[ii].max(vv), "data")
 
-        f = shard_map(local, mesh=mesh, in_specs=(P("data"), P("data")),
-                      out_specs=P(), check_vma=False)
+        f = jax.shard_map(local, mesh=mesh, in_specs=(P("data"), P("data")),
+                          out_specs=P(), check_vma=False)
         return np.asarray(f(jnp.asarray(v), jnp.asarray(inv)))
 
     def combine(cat: Dict[str, np.ndarray], group_names: Sequence[str],
@@ -90,10 +90,14 @@ def make_combiner() -> Optional[Callable]:
         n = len(next(iter(vals.values()))) if vals else 0
         if n == 0:
             return None
-        for arr in (*keys, *vals.values()):
-            if arr.dtype.kind not in "iufb":
-                return None
-            if not _round_trips(arr, _canon_dtype(arr.dtype, x64)):
+        for name, arr in (*zip(group_names, keys), *vals.items()):
+            if (arr.dtype.kind not in "iufb"
+                    or not _round_trips(arr, _canon_dtype(arr.dtype, x64))):
+                faults.record_degradation(
+                    "shard_impl", src="mesh", dst="host-merge",
+                    component=name,
+                    error=f"{arr.dtype} partial does not round-trip through "
+                          f"the device dtype")
                 return None
         if keys:
             uniq, inv = np.unique(np.stack(keys, axis=1), axis=0,
@@ -117,4 +121,5 @@ def make_combiner() -> Optional[Callable]:
             part_cols[p] = out.astype(v.dtype, copy=False)
         return group_cols, part_cols
 
+    combine.mesh = mesh
     return combine

@@ -113,6 +113,23 @@ def _pick_mode(flow, sources: List[str]) -> Tuple[str, Tuple[str, ...]]:
     return "hash", key
 
 
+def _require_host_device(backend) -> None:
+    """The process route spawns workers that each rebuild the backend.  An
+    accelerator belongs to one process at a time, and this process already
+    holds it, so on a device backend the workers would fail or hang: refuse
+    before anything is scattered."""
+    if getattr(backend, "name", "") != "jax":
+        return
+    import jax
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"shard_impl='process' needs a host-only backend: this process "
+            f"holds the {platform} device and an accelerator serves one "
+            f"process at a time, so spawned shard workers could not use it "
+            f"(use shard_impl='mesh' or 'inline')")
+
+
 def plan_shards(flow, g_tau, requested: int, impl: str, opts,
                 backend) -> Optional[ShardPlan]:
     """Decide the shard layout for one run, or ``None`` for serial.
@@ -160,6 +177,8 @@ def plan_shards(flow, g_tau, requested: int, impl: str, opts,
         return None
     if impl == "auto":
         impl = "mesh" if getattr(backend, "name", "") == "jax" else "inline"
+    if impl == "process":
+        _require_host_device(backend)
     mode, key = _pick_mode(flow, sources)
     cuts = [t.root for t in g_tau.trees
             if flow.component(t.root).ctype.roots_tree]

@@ -73,6 +73,11 @@ class ShardResult:
     pool_stats: Dict[str, int] = field(default_factory=dict)
     streamed_edges: List = field(default_factory=list)
     channel_hwm: int = 0
+    #: per shard pass, the devices its cuts' device-resident input columns
+    #: lived on (empty for host-only passes and the process route)
+    shard_devices: List[List[str]] = field(default_factory=list)
+    #: devices of the mesh the merge reduced over (mesh route only)
+    merge_devices: List[str] = field(default_factory=list)
 
 
 def _sum_stats(*snaps: Dict[str, int]) -> Dict[str, int]:
@@ -139,10 +144,8 @@ class ShardRunner:
         if impl == "mesh":
             from .mesh import make_combiner
             combiner = make_combiner()
-            if combiner is None:
-                faults.record_degradation("shard_impl", "mesh", "inline",
-                                          component=flow.name)
-                impl = "inline"
+            res.merge_devices = [f"{d.platform}:{d.id}"
+                                 for d in combiner.mesh.devices.flat]
         res.impl = impl
         ctx = ShardContext(combiner=combiner)
         cuts = [flow.component(name) for name in plan.cuts]
@@ -205,6 +208,8 @@ class ShardRunner:
                 res.pool_stats = self.pool.stats()
                 self.pool.shutdown()
         res.shuffle_bytes = ctx.shuffle_bytes
+        res.shard_devices = [sorted(ctx.placement.get(k, ()))
+                             for k in range(plan.shards)]
         return res
 
     def _merge_attempt(self, res: ShardResult) -> None:

@@ -1,5 +1,6 @@
-"""Host-side open-addressing build + pure-jnp probe oracle for the hash
-join (the allclose/equality reference).
+"""Host-side open-addressing build + the jnp probe for the hash join (the
+Lookup's device route, jit'd by ``ops.hash_probe`` and inlined by the fused
+segment kernel).
 
 The build runs ONCE per dimension table on the host (numpy) and the probe
 runs per chunk on the device, so the two halves must agree bit-for-bit on

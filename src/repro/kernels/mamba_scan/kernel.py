@@ -65,7 +65,7 @@ def _mamba_scan_kernel(delta_ref, x_ref, b_ref, c_ref, a_ref, h0_ref,
         dBx = d_t * bmat[t][None, :] * x[t][:, None]   # fused outer product
         h = dA * h + dBx
         y_t = jnp.sum(h * cmat[t][None, :], axis=1)    # [d_blk]
-        pl.store(y_ref, (pl.dslice(t, 1), slice(None)), y_t[None, :])
+        y_ref[pl.ds(t, 1), :] = y_t[None, :]
         return h
 
     h = jax.lax.fori_loop(0, chunk, step, h_ref[...])

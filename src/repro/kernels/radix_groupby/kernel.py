@@ -36,7 +36,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _radix_groupby_kernel(ids_ref, val_ref, out_ref, acc_ref, *,
-                          part_groups: int, n_tiles: int):
+                          part_groups: int, n_tiles: int, precision):
     """One grid step: accumulate one row tile into partition p's VMEM
     accumulator.
 
@@ -62,7 +62,7 @@ def _radix_groupby_kernel(ids_ref, val_ref, out_ref, acc_ref, *,
     onehot = ((local == groups) & (local >= 0)
               & (local < part_groups)).astype(vals.dtype)
     acc_ref[...] += jax.lax.dot_general(
-        onehot, vals, (((0,), (0,)), ((), ())),
+        onehot, vals, (((0,), (0,)), ((), ())), precision=precision,
         preferred_element_type=jnp.float32)
 
     @pl.when(t == n_tiles - 1)
@@ -72,11 +72,17 @@ def _radix_groupby_kernel(ids_ref, val_ref, out_ref, acc_ref, *,
 
 def radix_groupby_pallas(ids: jax.Array, values: jax.Array, n_groups: int,
                          part_groups: int = 256, rows_tile: int = 512,
-                         interpret: bool = False
+                         interpret: bool = False,
+                         precision=jax.lax.Precision.HIGHEST
                          ) -> Tuple[jax.Array, jax.Array]:
     """ids: [N] int32 dense group ids in [0, n_groups) (-1 = padding);
     values: [N, C] float32 (C may be 0).  Returns
-    ``(sums [n_groups, C], counts [n_groups])`` float32."""
+    ``(sums [n_groups, C], counts [n_groups])`` float32.
+
+    ``precision`` of the one-hot matmul: at ``DEFAULT`` the TPU rounds the
+    float32 values to bfloat16 (2^-9 relative), which a group of one row
+    cannot average away, so sums may miss ``oracle_rtol``; ``HIGHEST``
+    keeps float32 (``chip_smoke.py`` measures both)."""
     N, C = values.shape
     n_parts = max(1, -(-n_groups // part_groups))
     g_pad = n_parts * part_groups
@@ -90,7 +96,8 @@ def radix_groupby_pallas(ids: jax.Array, values: jax.Array, n_groups: int,
     ids2d = ids[:, None].astype(jnp.int32)
 
     kernel = functools.partial(_radix_groupby_kernel,
-                               part_groups=part_groups, n_tiles=n_tiles)
+                               part_groups=part_groups, n_tiles=n_tiles,
+                               precision=precision)
     out = pl.pallas_call(
         kernel,
         grid=(n_parts, n_tiles),              # tile axis innermost: each

@@ -30,7 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _segment_sum_kernel(seg_ref, val_ref, out_ref, acc_ref, *,
-                        n_groups: int, n_tiles: int):
+                        n_groups: int, n_tiles: int, precision):
     """One grid step: accumulate one row tile into the VMEM accumulator.
 
     seg_ref: [rows_tile, 1]     int32 group ids (-1 = padding row)
@@ -51,7 +51,7 @@ def _segment_sum_kernel(seg_ref, val_ref, out_ref, acc_ref, *,
     onehot = (seg == groups).astype(vals.dtype)
     # MXU: [R, G]^T @ [R, C] -> [G, C] (contract over the row dim)
     acc_ref[...] += jax.lax.dot_general(
-        onehot, vals, (((0,), (0,)), ((), ())),
+        onehot, vals, (((0,), (0,)), ((), ())), precision=precision,
         preferred_element_type=jnp.float32)
 
     @pl.when(t == n_tiles - 1)
@@ -60,10 +60,12 @@ def _segment_sum_kernel(seg_ref, val_ref, out_ref, acc_ref, *,
 
 
 def segment_sum_pallas(seg_ids: jax.Array, values: jax.Array, n_groups: int,
-                       rows_tile: int = 512, interpret: bool = False
-                       ) -> jax.Array:
+                       rows_tile: int = 512, interpret: bool = False,
+                       precision=jax.lax.Precision.HIGHEST) -> jax.Array:
     """seg_ids: [N] int32 in [0, n_groups) (or -1 for padding rows);
-    values: [N, C] float32.  Returns [n_groups, C] float32 sums."""
+    values: [N, C] float32.  Returns [n_groups, C] float32 sums.
+    ``precision`` as in ``radix_groupby_pallas``: ``HIGHEST`` keeps the
+    values float32 on the MXU."""
     N, C = values.shape
     n_tiles = max(1, -(-N // rows_tile))
     pad = n_tiles * rows_tile - N
@@ -73,7 +75,7 @@ def segment_sum_pallas(seg_ids: jax.Array, values: jax.Array, n_groups: int,
     seg2d = seg_ids[:, None].astype(jnp.int32)            # TPU wants >=2D
 
     kernel = functools.partial(_segment_sum_kernel, n_groups=n_groups,
-                               n_tiles=n_tiles)
+                               n_tiles=n_tiles, precision=precision)
     return pl.pallas_call(
         kernel,
         grid=(n_tiles,),

@@ -6,8 +6,6 @@ and only dryrun.py (which sets XLA_FLAGS first) may ask for 512 host devices.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 
 
@@ -26,28 +24,9 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             f"run via launch/dryrun.py (it forces 512 host devices)")
-    from .jax_compat import axis_types_kwargs
-    return jax.make_mesh(shape, axes, devices=devices[:n],
-                         **axis_types_kwargs(len(axes)))
-
-
-def make_host_mesh(data: int = 1, model: Optional[int] = 1):
-    """Small mesh over however many local devices exist (tests/examples).
-
-    ``model=None`` builds a data-only 1-axis ``(data,)`` mesh — the shape the
-    sharded-execution mesh route needs on single-device CPU CI, where asking
-    for a phantom model axis would double the device requirement."""
-    from .jax_compat import make_mesh
-    if model is None:
-        shape, axes = (data,), ("data",)
-    else:
-        shape, axes = (data, model), ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    if n > len(jax.devices()):
-        raise ValueError(f"need {n} devices, have {len(jax.devices())}")
-    return make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices[:n])
 
 
 # TPU v5e hardware constants (roofline denominators)

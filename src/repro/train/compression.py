@@ -67,8 +67,6 @@ def compress_tree_int8(grads, err_state):
 def cross_pod_psum_int8(mesh, grad_specs):
     """Returns fn(grads) that all-reduces over the 'pod' axis with int8
     payload via shard_map (grads assumed pre-divided by pod count)."""
-    from jax.experimental.shard_map import shard_map
-
     def psum_one(g):
         q, scale = int8_quantize(g)
         qsum = jax.lax.psum(q.astype(jnp.int32), "pod")
@@ -78,5 +76,5 @@ def cross_pod_psum_int8(mesh, grad_specs):
     def fn(grads):
         return jax.tree.map(psum_one, grads)
 
-    return shard_map(fn, mesh=mesh, in_specs=(grad_specs,),
-                     out_specs=grad_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(grad_specs,),
+                         out_specs=grad_specs, check_vma=False)

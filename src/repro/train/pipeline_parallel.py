@@ -85,8 +85,7 @@ def gpipe_spmd(stage_fn: Callable[[Any, jax.Array], jax.Array],
         return outs
 
     pspec = jax.tree.map(lambda _: P(axis), jax.tree.structure((0,)))
-    from ..launch.jax_compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

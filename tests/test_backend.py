@@ -357,3 +357,57 @@ def test_numpy_engine_reference_unchanged(ssb_tiny):
     for k in expect:
         np.testing.assert_allclose(got[k], expect[k], rtol=1e-9)
     assert r.h2d_bytes == 0 and r.d2h_bytes == 0
+
+
+# ------------------------------------------------------- compile cache home
+class _StubConfig:
+    def __init__(self, cache_dir):
+        self.jax_compilation_cache_dir = cache_dir
+        self.updates = []
+
+    def update(self, name, value):
+        self.updates.append((name, value))
+
+
+class _StubJax:
+    def __init__(self, cache_dir):
+        self.config = _StubConfig(cache_dir)
+
+
+@pytest.mark.parametrize("env_dir,config_dir,checkout,placed", [
+    (None, None, True, True),                # nothing set: the fixed repo path
+    ("/elsewhere/cache", None, True, False),  # the variable is JAX's to read
+    (None, "/set/in/code", True, False),     # already configured: left alone
+    (None, None, False, False),              # installed package: no default
+])
+def test_place_compile_cache(monkeypatch, env_dir, config_dir, checkout,
+                             placed):
+    from pathlib import Path
+
+    from repro.core.backend import jax_backend
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    repo = Path(__file__).resolve().parents[1]
+    assert jax_backend.COMPILE_CACHE_DIR == repo / ".jax_cache"
+    if not checkout:
+        monkeypatch.setattr(jax_backend, "COMPILE_CACHE_DIR", None)
+    stub = _StubJax(config_dir)
+    jax_backend.place_compile_cache(stub)
+    expect = ([("jax_compilation_cache_dir", str(repo / ".jax_cache"))]
+              if placed else [])
+    assert stub.config.updates == expect
+
+
+def test_compile_cache_default_needs_a_source_checkout(tmp_path,
+                                                       monkeypatch):
+    """Only a ``src/`` tree beside ``pyproject.toml`` gets the in-checkout
+    default; an installed package (no ``pyproject.toml`` four levels up)
+    gets none."""
+    from repro.core.backend import jax_backend
+    module = tmp_path / "a" / "b" / "c" / "d" / "jax_backend.py"
+    monkeypatch.setattr(jax_backend, "__file__", str(module))
+    assert jax_backend._checkout_cache_dir() is None
+    (tmp_path / "pyproject.toml").write_text("")
+    assert jax_backend._checkout_cache_dir() == tmp_path / ".jax_cache"

@@ -37,8 +37,8 @@ def test_restore_with_sharding_placement(tmp_path):
     # "reshard" onto the current (single-device) mesh — the elastic-restart
     # path: restore takes target shardings and device_puts accordingly
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.jax_compat import make_mesh
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), st)
     got, _ = restore_checkpoint(str(tmp_path), st, shardings=sh)
     assert got["params"]["w"].sharding == NamedSharding(mesh, P())

@@ -18,17 +18,16 @@ PROG = textwrap.dedent("""
     from repro.train.optimizer import OptConfig
     from repro.train.train_step import make_train_step
 
-    from repro.launch.jax_compat import axis_types_kwargs, set_mesh
     mesh = jax.make_mesh((2, 2), ("data", "model"),
-                         devices=jax.devices()[:4],
-                         **axis_types_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                         devices=jax.devices()[:4])
     cfg = get_config("mixtral-8x7b", smoke=True).replace(grad_accum=2)
     shape = ShapeConfig("tiny_train", seq_len=32, global_batch=8,
                         kind="train", grad_accum=2)
     specs = cell_specs(cfg, shape, mesh)
     cfg = specs["cfg"]
     step = make_train_step(cfg, OptConfig(), specs["rules"])
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = jax.jit(step,
                      in_shardings=(specs["param_shardings"],
                                    specs["opt_shardings"],

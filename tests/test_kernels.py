@@ -60,11 +60,11 @@ def _probe_oracle(key_rows, probe_rows):
     return idx, found
 
 
-def _probe(built, cols, impl, **kw):
+def _probe(built, cols):
     return hash_probe(tuple(jnp.asarray(k) for k in built["slot_keys"]),
                       jnp.asarray(built["slot_idx"]),
                       tuple(jnp.asarray(c) for c in cols),
-                      built["max_probes"], impl=impl, **kw)
+                      built["max_probes"])
 
 
 def test_hash_keys_host_device_identical():
@@ -78,23 +78,22 @@ def test_hash_keys_host_device_identical():
         np.testing.assert_array_equal(h_np, np.asarray(h_j))
 
 
-@pytest.mark.parametrize("d,n,key_range,tile", [
-    (1, 16, 50, 512),            # tiny table (min size floor)
-    (500, 2_000, 3_000, 512),    # ~17% hit rate, misses exercised
-    (1000, 1_500, 1_000, 256),   # dense: most probes hit
-    (997, 777, 100_000, 128),    # sparse keys, ragged row tile
+@pytest.mark.parametrize("d,n,key_range", [
+    (1, 16, 50),                 # tiny table (min size floor)
+    (500, 2_000, 3_000),         # ~17% hit rate, misses exercised
+    (1000, 1_500, 1_000),        # dense: most probes hit
+    (997, 777, 100_000),         # sparse keys
 ])
-def test_hash_probe_sweep(d, n, key_range, tile):
+def test_hash_probe_sweep(d, n, key_range):
     keys = np.sort(RNG.choice(key_range, size=min(d, key_range),
                               replace=False)).astype(np.int64)
     built = hash_build((keys,))
     probes = RNG.integers(0, key_range + 10, n).astype(np.int64)
     oi, of = _probe_oracle(keys[:, None], probes[:, None])
-    for impl in ("reference", "interpret"):
-        idx, found = _probe(built, (probes,), impl, rows_tile=tile)
-        idx, found = np.asarray(idx), np.asarray(found)
-        np.testing.assert_array_equal(found, of)
-        np.testing.assert_array_equal(idx[of], oi[of])
+    idx, found = _probe(built, (probes,))
+    idx, found = np.asarray(idx), np.asarray(found)
+    np.testing.assert_array_equal(found, of)
+    np.testing.assert_array_equal(idx[of], oi[of])
 
 
 def test_hash_probe_arbitrary_key_order():
@@ -106,7 +105,7 @@ def test_hash_probe_arbitrary_key_order():
     built = hash_build((shuffled,))
     probes = RNG.integers(0, 11_000, 2_500).astype(np.int64)
     oi, of = _probe_oracle(shuffled[:, None], probes[:, None])
-    idx, found = _probe(built, (probes,), "reference")
+    idx, found = _probe(built, (probes,))
     np.testing.assert_array_equal(np.asarray(found), of)
     np.testing.assert_array_equal(np.asarray(idx)[of], oi[of])
 
@@ -121,18 +120,18 @@ def test_hash_probe_duplicate_keys_keep_first():
     probes = np.arange(-5, 520).astype(np.int64)
     ss = np.clip(np.searchsorted(keys, probes), 0, len(keys) - 1)
     hit = keys[ss] == probes
-    idx, found = _probe(built, (probes,), "reference")
+    idx, found = _probe(built, (probes,))
     np.testing.assert_array_equal(np.asarray(found), hit)
     np.testing.assert_array_equal(np.asarray(idx)[hit], ss[hit])
 
 
-@pytest.mark.parametrize("impl", ["reference", "interpret"])
-def test_hash_probe_multi_column(impl):
+@pytest.mark.parametrize("dtype", [np.int64, np.int32])
+def test_hash_probe_multi_column(dtype):
     rows = np.unique(RNG.integers(0, 40, size=(600, 3)), axis=0)
-    built = hash_build(tuple(rows[:, j].astype(np.int64) for j in range(3)))
-    probes = RNG.integers(0, 45, size=(2_000, 3)).astype(np.int64)
+    built = hash_build(tuple(rows[:, j].astype(dtype) for j in range(3)))
+    probes = RNG.integers(0, 45, size=(2_000, 3)).astype(dtype)
     oi, of = _probe_oracle(rows, probes)
-    idx, found = _probe(built, tuple(probes[:, j] for j in range(3)), impl)
+    idx, found = _probe(built, tuple(probes[:, j] for j in range(3)))
     idx, found = np.asarray(idx), np.asarray(found)
     np.testing.assert_array_equal(found, of)
     np.testing.assert_array_equal(idx[of], oi[of])
@@ -142,9 +141,9 @@ def test_hash_probe_all_miss_and_empty_probe():
     keys = np.arange(100, dtype=np.int64) * 7
     built = hash_build((keys,))
     probes = (np.arange(50, dtype=np.int64) * 7) + 3   # never in table
-    idx, found = _probe(built, (probes,), "reference")
+    idx, found = _probe(built, (probes,))
     assert not np.asarray(found).any()
-    idx, found = _probe(built, (np.zeros(0, np.int64),), "reference")
+    idx, found = _probe(built, (np.zeros(0, np.int64),))
     assert np.asarray(idx).shape == (0,) and np.asarray(found).shape == (0,)
 
 

@@ -414,9 +414,9 @@ def test_kernel_impl_routes_byte_identical(spec):
 
 
 def test_kernel_impl_interpret_route_fused():
-    """The Pallas kernel BODIES (interpret mode) behind the same flows, with
-    segment fusion on — the fused runner inlines the hash probe, the
-    Aggregate rides the dense groupby."""
+    """The Pallas group-by kernel BODY (interpret mode) behind the same
+    flows, with segment fusion on — the fused runner inlines the hash
+    probe, the Aggregate rides the dense groupby."""
     if "jax" not in _dsl_backends():      # pragma: no cover
         pytest.skip("jax backend unavailable")
     spec = (7, 4, [("lookup", 3, 0, True),
@@ -425,7 +425,7 @@ def test_kernel_impl_interpret_route_fused():
                    ("agg", 2, 5, "sum"),
                    ("sort", 0)])
     legacy = _run_with_impls(spec, "jax", "searchsorted", "sort")
-    got = _run_with_impls(spec, "jax", "interpret", "interpret", fuse=True)
+    got = _run_with_impls(spec, "jax", "reference", "interpret", fuse=True)
     _assert_tables_equal(got, legacy, "interpret-routes+fusion")
 
 

@@ -29,10 +29,9 @@ GPIPE_PROG = textwrap.dedent("""
     from repro.train.pipeline_parallel import gpipe_spmd, stack_stage_params
 
     n_stages, m, mb, d = 4, 6, 2, 16
-    from repro.launch.jax_compat import axis_types_kwargs
     mesh = jax.make_mesh((n_stages,), ("stage",),
-                         devices=jax.devices()[:n_stages],
-                         **axis_types_kwargs(1))
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=jax.devices()[:n_stages])
 
     def stage_fn(w, h):
         return jnp.tanh(h @ w)
@@ -44,8 +43,7 @@ GPIPE_PROG = textwrap.dedent("""
     xs = jax.random.normal(jax.random.fold_in(key, 99), (m, mb, d))
 
     pipelined = gpipe_spmd(stage_fn, mesh, n_stages, m, axis="stage")
-    from repro.launch.jax_compat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(pipelined)(stacked, xs)
 
     # reference: sequential stage composition per microbatch

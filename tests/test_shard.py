@@ -161,6 +161,30 @@ def test_mesh_route_on_jax_backend():
                     backend="jax")
     assert run.shards == 2
     _assert_tables_equal(got, serial, "mesh route")
+    import jax
+    # placement as the run saw it: the merge mesh spans every device; the
+    # cut's input came straight from a host source, so no device columns
+    assert run.merge_devices == [f"{d.platform}:{d.id}"
+                                 for d in jax.devices()]
+    assert run.shard_devices == [[], []]
+
+
+def test_mesh_combiner_records_its_dtype_refusal():
+    pytest.importorskip("jax")
+    from repro.core.shard.mesh import make_combiner
+    combine = make_combiner()
+    keys = np.array([0, 1, 0], dtype=np.int64)
+    fits = np.array([1, 2, 3], dtype=np.int64)
+    with faults.fault_recorder() as rec:
+        groups, parts = combine({"g": keys, "v": fits}, ["g"], {"v": "sum"})
+        # an int64 partial past int32 cannot reduce on a 32-bit device
+        wide = np.array([1, 2, 1 << 40], dtype=np.int64)
+        assert combine({"g": keys, "v": wide}, ["g"], {"v": "sum"}) is None
+    np.testing.assert_array_equal(groups[0], [0, 1])
+    np.testing.assert_array_equal(parts["v"], [4, 2])
+    assert [(d.kind, d.src, d.dst, d.component)
+            for d in rec.degradations] == [("shard_impl", "mesh",
+                                            "host-merge", "v")]
 
 
 def test_global_aggregate_sharded():

@@ -1,0 +1,126 @@
+"""Compile the device path for a described TPU v5e chip, without a chip.
+
+Each test compiles ahead of time, for one chip of a ``v5e:2x2`` topology,
+a kernel that ``auto`` picks on a TPU, at the largest shapes the SF1 chip
+smoke (``chip_smoke.py``) feeds it:
+
+* the Lookup probe route (XLA, ``hash_probe_ref``) over the 200k-row part
+  table (T = 2^19 slots) at 2^21 probe rows;
+* ``radix_groupby_pallas`` at the serving batch's 2^21 rows and at Q2.1's
+  7000 dense cells;
+* ``segment_sum_pallas`` at Q1.1's 2^17 rows;
+* one fused Q4.1 segment kernel over SF1 dimension tables at a 2^21-row
+  chunk bucket.
+
+A compile that passes is not a chip run: nothing here executes.  The
+topology is described inside a fixture (only the worker that runs these
+tests loads the TPU library), and JAX's persistent compilation cache is
+off around the compiles, since a described chip cannot read entries back.
+"""
+from __future__ import annotations
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+#: usable HBM of one v5e chip (16 GiB), less headroom for the runtime
+V5E_HBM_BYTES = 15 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _fits(compiled) -> None:
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes)
+    assert total < V5E_HBM_BYTES, f"{total} bytes do not fit one v5e chip"
+
+
+def test_probe_route_compiles_at_sf1_part_table(one_chip):
+    from repro.kernels.hash_join import hash_probe
+    T, N = 1 << 19, 1 << 21
+
+    def probe(slot_keys, slot_idx, vals):
+        return hash_probe((slot_keys,), slot_idx, (vals,), 32)
+
+    compiled = jax.jit(probe).lower(
+        _spec(one_chip, (T,), jnp.int32), _spec(one_chip, (T,), jnp.int32),
+        _spec(one_chip, (N,), jnp.int32)).compile()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("n_rows,n_cols,n_groups", [
+    (1 << 21, 2, 25),        # serving batch: 8 ticks x 262144 rows
+    (1 << 19, 1, 7000),      # Q2.1: 7 years x 1000 brands of dense cells
+])
+def test_radix_groupby_pallas_compiles(one_chip, n_rows, n_cols, n_groups):
+    from repro.kernels.radix_groupby.kernel import radix_groupby_pallas
+    compiled = jax.jit(
+        lambda ids, v: radix_groupby_pallas(ids, v, n_groups)).lower(
+        _spec(one_chip, (n_rows,), jnp.int32),
+        _spec(one_chip, (n_rows, n_cols), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_segment_sum_pallas_compiles(one_chip):
+    from repro.kernels.segment_sum.kernel import segment_sum_pallas
+    n_rows = 1 << 17                 # Q1.1's filtered rows at SF1 (~112k)
+    compiled = jax.jit(lambda s, v: segment_sum_pallas(s, v, 1)).lower(
+        _spec(one_chip, (n_rows,), jnp.int32),
+        _spec(one_chip, (n_rows, 1), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_fused_q41_segment_compiles_at_sf1(one_chip):
+    from repro.core.backend.jax_backend import JaxBackend
+    from repro.etl import BUILDERS
+    from repro.etl.components import FusedSegment
+    from repro.etl.ssb import generate
+    data = generate(lineorder_rows=1024, customers=30_000, suppliers=2_000,
+                    parts=200_000, seed=3)
+    qf = BUILDERS["Q4.1"](data)
+    members = ["lookup_customer", "lookup_supplier", "lookup_part",
+               "lookup_date", "filter_unmatched", "project", "profit_expr"]
+    seg = FusedSegment.from_components(
+        [qf.flow.component(m) for m in members])
+    runner = JaxBackend().compile_segment(seg)
+    bucket = 1 << 21
+    entries, total = runner.pack_layout(
+        bucket, [(c, data.lineorder[c].dtype) for c in sorted(runner.inputs)])
+    dims = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                        runner.device_dims())
+    assert max(int(d["slot_idx"].shape[0]) for d in dims) == 1 << 19
+    compiled = runner._jit.lower(
+        (bucket, tuple(entries)), _spec(one_chip, (total,), jnp.uint8), {},
+        dims).compile()
+    _fits(compiled)
