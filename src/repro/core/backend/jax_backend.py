@@ -150,7 +150,8 @@ class JaxBackend(Backend):
         import jax                       # deferred: registry creates lazily
         import jax.numpy as jnp
         place_compile_cache(jax)
-        from ...kernels.hash_join import hash_build, hash_probe, hash_probe_ref
+        from ...kernels.hash_join import (hash_build, hash_probe,
+                                          hash_probe_ref, probe_lengths_np)
         from ...kernels.radix_groupby import radix_groupby
         from ...kernels.segment_sum import segment_sum
         self._jax = jax
@@ -159,6 +160,7 @@ class JaxBackend(Backend):
         self._hash_build = hash_build
         self._hash_probe = hash_probe
         self._hash_probe_ref = hash_probe_ref
+        self._probe_lengths_np = probe_lengths_np
         self._radix_groupby = radix_groupby
         self._segsum_impl = config.segsum_impl()
 
@@ -236,10 +238,9 @@ class JaxBackend(Backend):
             # the next borrower's bytes.  Forcing the copy restores the
             # ownership boundary the h2d accounting already models (real
             # accelerators copy on transfer regardless).
-            t0 = time.perf_counter() if obs_trace.ACTIVE.get() else 0.0
-            out = self._jnp.array(x, copy=True)
-            record_transfer("h2d", x.nbytes,
-                            seconds=(time.perf_counter() - t0) if t0 else 0.0)
+            with obs_trace.annotation("transfer", "h2d") as a:
+                out = self._jnp.array(x, copy=True)
+            record_transfer("h2d", x.nbytes, seconds=a.seconds)
             return out
         if isinstance(x, self._jax.Array):
             return x
@@ -248,10 +249,9 @@ class JaxBackend(Backend):
     def to_host(self, x) -> np.ndarray:
         if isinstance(x, np.ndarray):
             return x
-        t0 = time.perf_counter() if obs_trace.ACTIVE.get() else 0.0
-        out = np.asarray(x)
-        record_transfer("d2h", out.nbytes,
-                        seconds=(time.perf_counter() - t0) if t0 else 0.0)
+        with obs_trace.annotation("transfer", "d2h") as a:
+            out = np.asarray(x)
+        record_transfer("d2h", out.nbytes, seconds=a.seconds)
         return out
 
     def concat(self, parts: Sequence):
@@ -312,7 +312,9 @@ class JaxBackend(Backend):
         host (``kernels/hash_join.hash_build``), slot arrays device_put once,
         cached on the table itself like ``_dim_device``.  ``max_probes`` (the
         static probe-loop bound) stays a Python int — it must never become a
-        tracer."""
+        tracer; ``host`` (the build's own arrays), ``key_range`` (of the
+        sorted keys), ``mean_probes`` and ``table_size`` feed the probe
+        counters."""
         ht = dim.__dict__.get("_jax_hash_cache")
         if ht is None:
             with self._dims_lock:
@@ -327,6 +329,11 @@ class JaxBackend(Backend):
                                            for k in built["slot_keys"]),
                         "slot_idx": self.asarray(built["slot_idx"]),
                         "max_probes": int(built["max_probes"]),
+                        "mean_probes": float(built["mean_probes"]),
+                        "table_size": int(built["table_size"]),
+                        "host": built,
+                        "key_range": ((int(dim.keys[0]), int(dim.keys[-1]))
+                                      if len(dim.keys) else None),
                     }
         return ht
 
@@ -616,28 +623,43 @@ class _JaxSegmentRunner:
         #: via hash_probe_ref — it fuses into the one XLA computation) unless
         #: pinned back to the legacy binary search
         self._join_impl = config.join_impl()
-        self._max_probes: List[int] = []   # python-side: static loop bounds
+        #: per Lookup, in op order: its hash table (``_dim_hash``: the static
+        #: loop bound and probe statistics), or None on the searchsorted route
+        self._tables: List[Optional[dict]] = []
+        #: per Lookup, in op order: the name of its scope and probe counter,
+        #: and its key column
+        self._lookup_names = [op[1].name or op[2] for op in self.ops
+                              if op[0] == "lookup"]
+        self._lookup_keys = [op[2] for op in self.ops if op[0] == "lookup"]
         self._jit = backend._jax.jit(self._kernel, static_argnums=(0,))
         self._layouts: set = set()
+        #: per layout, ``(program, layout key, {ENTRY op: named scope})`` of
+        #: the compiled kernel; built only while a tracer is in scope
+        self._scope_maps: Dict[tuple, tuple] = {}
         self._dims = None            # built once: stable per (segment, backend)
         self.kernel_calls = 0
 
     # ----------------------------------------------------------- the kernel
     def _kernel(self, layout, packed, dev_cols, dims):
+        # every op runs under a stable jax.named_scope (trace-time metadata
+        # only: the compiled program is the same) so the profiler's device
+        # ops can be traced back to the Lookup, filter or expression
         jnp = self._jnp
+        scope = self._jax.named_scope
         bucket, entries = layout
         env: Dict[str, object] = {}
-        for (name, dtype_str, off) in entries:
-            dt = np.dtype(dtype_str)
-            nb = bucket * dt.itemsize
-            raw = packed[off:off + nb]
-            if dt == np.bool_:
-                env[name] = raw != 0
-            elif dt.itemsize == 1:
-                env[name] = self._jax.lax.bitcast_convert_type(raw, dt)
-            else:
-                env[name] = self._jax.lax.bitcast_convert_type(
-                    raw.reshape(bucket, dt.itemsize), dt)
+        with scope("unpack"):
+            for (name, dtype_str, off) in entries:
+                dt = np.dtype(dtype_str)
+                nb = bucket * dt.itemsize
+                raw = packed[off:off + nb]
+                if dt == np.bool_:
+                    env[name] = raw != 0
+                elif dt.itemsize == 1:
+                    env[name] = self._jax.lax.bitcast_convert_type(raw, dt)
+                else:
+                    env[name] = self._jax.lax.bitcast_convert_type(
+                        raw.reshape(bucket, dt.itemsize), dt)
         env.update(dev_cols)
 
         masks = []
@@ -647,41 +669,19 @@ class _JaxSegmentRunner:
             view = SegmentEnv(env.__getitem__, set(env), bucket)
             kind = op[0]
             if kind == "filter":
-                masks.append(jnp.asarray(op[1](view, rows), dtype=bool))
+                with scope(f"filter.{len(masks)}"):
+                    masks.append(jnp.asarray(op[1](view, rows), dtype=bool))
             elif kind == "expr":
-                env[op[1]] = jnp.asarray(op[2](view, rows))
+                with scope(f"expr.{op[1]}"):
+                    env[op[1]] = jnp.asarray(op[2](view, rows))
             elif kind == "lookup":
-                _, dim, key_col, return_cols, default, matched_flag = op
+                _, _, key_col, return_cols, default, matched_flag = op
                 d = dims[dim_i]
-                max_probes = self._max_probes[dim_i]  # static (never traced)
+                table = self._tables[dim_i]   # static (never traced)
+                with scope(f"lookup.{self._lookup_names[dim_i]}"):
+                    self._lookup(env, d, table, key_col, return_cols,
+                                 default, matched_flag)
                 dim_i += 1
-                vals = env[key_col]
-                keys = d["keys"]
-                if keys.shape[0] == 0:        # static: degenerate dim table
-                    matched = jnp.zeros(vals.shape[0], dtype=bool)
-                    for out_name, dim_col in return_cols.items():
-                        env[out_name] = jnp.full(
-                            vals.shape[0], default,
-                            d["payload"][dim_col].dtype)
-                else:
-                    if max_probes:
-                        # hash-probe route, traced inline so the open-
-                        # addressing loop fuses into this one XLA computation
-                        idx, found = self._bk._hash_probe_ref(
-                            d["slot_keys"], d["slot_idx"], (vals,),
-                            max_probes)
-                        matched = found & d["qualifies"][idx]
-                    else:
-                        idx = jnp.clip(jnp.searchsorted(keys, vals),
-                                       0, keys.shape[0] - 1)
-                        matched = (keys[idx] == vals) & d["qualifies"][idx]
-                    for out_name, dim_col in return_cols.items():
-                        payload = d["payload"][dim_col]
-                        env[out_name] = jnp.where(
-                            matched, payload[idx],
-                            jnp.asarray(default, payload.dtype))
-                if matched_flag:
-                    env[matched_flag] = matched
             elif kind == "project":
                 keep = set(op[1])
                 for k in list(env):
@@ -694,10 +694,46 @@ class _JaxSegmentRunner:
                 raise ValueError(f"unknown segment op kind {kind!r}")
 
         keep_mask = None
-        for m in masks:
-            keep_mask = m if keep_mask is None else (keep_mask & m)
+        with scope("mask"):
+            for m in masks:
+                keep_mask = m if keep_mask is None else (keep_mask & m)
         out = {name: env[name] for name in self._written if name in env}
         return out, keep_mask
+
+    def _lookup(self, env, d, table, key_col, return_cols, default,
+                matched_flag) -> None:
+        """One Lookup, traced into the kernel: the probe under ``probe``,
+        the qualifying and payload gathers under ``gather``."""
+        jnp = self._jnp
+        scope = self._jax.named_scope
+        vals = env[key_col]
+        keys = d["keys"]
+        if keys.shape[0] == 0:        # static: degenerate dim table
+            matched = jnp.zeros(vals.shape[0], dtype=bool)
+            for out_name, dim_col in return_cols.items():
+                env[out_name] = jnp.full(
+                    vals.shape[0], default, d["payload"][dim_col].dtype)
+        else:
+            with scope("probe"):
+                if table:
+                    # hash-probe route, traced inline so the open-
+                    # addressing loop fuses into this one XLA computation
+                    idx, found = self._bk._hash_probe_ref(
+                        d["slot_keys"], d["slot_idx"], (vals,),
+                        table["max_probes"])
+                else:
+                    idx = jnp.clip(jnp.searchsorted(keys, vals),
+                                   0, keys.shape[0] - 1)
+                    found = keys[idx] == vals
+            with scope("gather"):
+                matched = found & d["qualifies"][idx]
+                for out_name, dim_col in return_cols.items():
+                    payload = d["payload"][dim_col]
+                    env[out_name] = jnp.where(
+                        matched, payload[idx],
+                        jnp.asarray(default, payload.dtype))
+        if matched_flag:
+            env[matched_flag] = matched
 
     # ------------------------------------------------------------ execution
     def pack_layout(self, bucket: int, columns) -> Tuple[list, int]:
@@ -720,7 +756,7 @@ class _JaxSegmentRunner:
         if self._dims is None:
             bk = self._bk
             dims = []
-            max_probes = []
+            tables = []
             for op in self.ops:
                 if op[0] == "lookup":
                     _, dim, _, return_cols, _, _ = op
@@ -736,11 +772,11 @@ class _JaxSegmentRunner:
                         ht = bk._dim_hash(dim)
                         entry["slot_keys"] = ht["slot_keys"]
                         entry["slot_idx"] = ht["slot_idx"]
-                        max_probes.append(ht["max_probes"])
+                        tables.append(ht)
                     else:
-                        max_probes.append(0)   # 0 => legacy searchsorted
+                        tables.append(None)   # legacy searchsorted
                     dims.append(entry)
-            self._max_probes = max_probes
+            self._tables = tables
             self._dims = dims
         return self._dims
 
@@ -775,16 +811,21 @@ class _JaxSegmentRunner:
             bucket, [(name, v.dtype) for name, v in packable])
         if total:
             staging, root = GLOBAL_ARENA.acquire(np.uint8, (total,))
-            for (name, v), (_, dtype_str, off) in zip(packable, entries):
-                cd = np.dtype(dtype_str)
-                dst = staging[off:off + bucket * cd.itemsize].view(cd)
-                np.copyto(dst[:n], v, casting="same_kind")
-                dst[n:] = 0
+            with obs_trace.span("transfer", "h2d.pack", bytes=total):
+                for (name, v), (_, dtype_str, off) in zip(packable, entries):
+                    cd = np.dtype(dtype_str)
+                    dst = staging[off:off + bucket * cd.itemsize].view(cd)
+                    np.copyto(dst[:n], v, casting="same_kind")
+                    dst[n:] = 0
             # copy=True + block: the device buffer must not alias the
-            # staging memory, which goes straight back to the arena
+            # staging memory, which goes straight back to the arena.  The
+            # h2d transfer covers the upload and the wait, and the wait also
+            # holds the device's queue ahead of the upload
             t0 = time.perf_counter() if obs_trace.ACTIVE.get() else 0.0
-            packed = jnp.array(staging, copy=True)
-            packed.block_until_ready()
+            with obs_trace.span("transfer", "h2d.upload"):
+                packed = jnp.array(staging, copy=True)
+            with obs_trace.span("wait", "h2d.ready"):
+                packed.block_until_ready()
             record_transfer("h2d", total,
                             seconds=(time.perf_counter() - t0) if t0 else 0.0)
             GLOBAL_ARENA.release(root)
@@ -798,13 +839,21 @@ class _JaxSegmentRunner:
             # and compiles a fresh executable for it
             self._layouts.add(layout)
             record_segment_compile()
-        out_cols, keep_mask = self._jit(layout, packed, dev_cols, dims)
-        self.kernel_calls += 1
-
         final_live = self._final_live(self.ops, cache.names)
-        for name in self._written:
-            if name in out_cols and name in final_live:
-                cache.add_column(name, out_cols[name][:n])
+        with obs_trace.span("dispatch", "segment"):
+            out_cols, keep_mask = self._jit(layout, packed, dev_cols, dims)
+            # the live outputs less the bucket's pad rows (dispatches too)
+            out_cols = {name: out_cols[name][:n] for name in self._written
+                        if name in out_cols and name in final_live}
+            if keep_mask is not None:
+                keep_mask = keep_mask[:n]
+        self.kernel_calls += 1
+        if obs_trace.ACTIVE.get():
+            self._trace_call(layout, (packed, dev_cols, dims), n, bucket,
+                             dict(packable))
+
+        for name, col in out_cols.items():
+            cache.add_column(name, col)
         if self.defer_mask:
             # fused-through-Aggregate: the per-chunk compact (this chunk's
             # ONLY d2h) is deferred — the keep-mask rides along as a device
@@ -812,16 +861,77 @@ class _JaxSegmentRunner:
             # merged cache
             from .base import SEGMENT_KEEP_MASK
             if keep_mask is not None:
-                cache.add_column(SEGMENT_KEEP_MASK, keep_mask[:n])
+                cache.add_column(SEGMENT_KEEP_MASK, keep_mask)
                 final_live = final_live | {SEGMENT_KEEP_MASK}
             if final_live != set(cache.names):
                 cache.keep_columns(
                     [k for k in cache.names if k in final_live])
             return
         if keep_mask is not None:
-            cache.compact(keep_mask[:n])
+            cache.compact(keep_mask)
         if final_live != set(cache.names):
             cache.keep_columns([k for k in cache.names if k in final_live])
+
+    def _scope_map(self, layout, args) -> tuple:
+        """``(program, layout key, {ENTRY op: named scope})`` of the kernel
+        compiled for ``layout``, parsed once per layout from the compiled
+        text (a lower and compile that hit jit's caches: XLA compiles
+        nothing)."""
+        got = self._scope_maps.get(layout)
+        if got is None:
+            with obs_trace.span("program", "scopes.build"):
+                text = self._jit.lower(layout, *args).compile().as_text()
+            program, ops = obs_trace.entry_scopes(text)
+            bucket, entries = layout
+            key = f"{bucket}:{','.join(name for name, _, _ in entries)}"
+            got = self._scope_maps[layout] = (program, key, ops)
+        return got
+
+    def _trace_call(self, layout, args, n: int, bucket: int,
+                    host_cols: Dict[str, np.ndarray]) -> None:
+        """A traced call's events: which compiled op belongs to which scope,
+        and per hash-probe Lookup the rows probed, the passes its loop ran
+        over them and, where its key column is a host input of the call,
+        the passes they need (``need``, walked on the host)."""
+        program, key, ops = self._scope_map(layout, args)
+        obs_trace.instant("program", "scopes", program=program, layout=key,
+                          ops=ops)
+        with obs_trace.span("program", "probe.count"):
+            for name, key_col, table in zip(self._lookup_names,
+                                            self._lookup_keys, self._tables):
+                if not table:
+                    continue
+                counts = {}
+                vals = host_cols.get(key_col)
+                if vals is not None and key_col not in self._written:
+                    counts["need"] = self._probe_need(table, vals)
+                obs_trace.counter(
+                    "probe", name, rows=n, padded_rows=bucket,
+                    passes=table["max_probes"],
+                    mean_probes=table["mean_probes"],
+                    slots=table["table_size"], **counts)
+
+    def _probe_need(self, table: dict, vals: np.ndarray) -> int:
+        """The passes the probe loop needs over ``vals``, summed: each key's
+        probe length looked up in a table over the dimension's key range
+        (walked once, on first use, where the range is at most four times
+        the slots); keys outside it are walked row by row."""
+        walk = self._bk._probe_lengths_np
+        cached = table.get("lengths")
+        if cached is None:
+            lo, hi = table["key_range"] or (0, -1)
+            lengths = None
+            if 0 < hi - lo + 1 <= 4 * table["table_size"]:
+                lengths = walk(table["host"], (np.arange(lo, hi + 1),))
+            cached = table["lengths"] = (lo, lengths)
+        lo, lengths = cached
+        if lengths is None:
+            return int(walk(table["host"], (vals,)).sum())
+        inside = (vals >= lo) & (vals < lo + len(lengths))
+        if inside.all():
+            return int(lengths[vals - lo].sum(dtype=np.int64))
+        return int(lengths[vals[inside] - lo].sum(dtype=np.int64)
+                   + walk(table["host"], (vals[~inside],)).sum())
 
     def stats(self) -> Dict[str, int]:
         return {"kernel_calls": self.kernel_calls,

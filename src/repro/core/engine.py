@@ -190,8 +190,6 @@ def _finish_obs(tracer, run: EngineRun,
     m.gauge_set("arena_pooled_bytes", GLOBAL_ARENA.pooled_bytes)
     if pool_stats:
         m.gauge_set("pool_width", pool_stats.get("width", 0))
-        m.gauge_set("pool_threads_hwm", pool_stats.get("threads_hwm", 0))
-        m.gauge_set("pool_tasks_run", pool_stats.get("tasks_run", 0))
         width = pool_stats.get("width") or 0
         if width:
             m.gauge_set("pool_utilization",
@@ -700,7 +698,6 @@ class ServingEngine:
             m.observe("tick_s", wall)
             if watermark_lag is not None:
                 m.gauge_set("watermark_lag_s", watermark_lag)
-                m.gauge_max("watermark_lag_s_max", watermark_lag)
         return {"tick": i, "wall_s": wall, "cache_stats": stats.snapshot()}
 
     # ---------------------------------------------------------------- close

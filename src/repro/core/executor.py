@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import contextvars
 import threading
-import time
 import warnings
 from collections import deque
 from contextlib import contextmanager, nullcontext
@@ -318,8 +317,8 @@ class AdmissionGate:
                 self._inflight += 1
                 return
         ctx = pool.blocking() if pool is not None else nullcontext()
-        t0 = time.perf_counter() if obs_trace.ACTIVE.get() else 0.0
-        with ctx:                              # slow path: managed wait
+        with obs_trace.span("wait", "gate.acquire", limit=self.limit), \
+                ctx:                           # slow path: managed wait
             with self._cond:
                 while self._inflight >= self.limit:
                     if self._abort is not None and self._abort.aborted:
@@ -328,9 +327,6 @@ class AdmissionGate:
                 if self._abort is not None:
                     self._abort.check()
                 self._inflight += 1
-        if t0:
-            obs_trace.on_wait("gate.acquire", t0, time.perf_counter(),
-                              limit=self.limit)
 
     def release(self) -> None:
         with self._cond:
@@ -415,8 +411,8 @@ class ChannelGroup:
                 return
         ctx = (self._pool.blocking() if self._pool is not None
                else nullcontext())
-        t0 = time.perf_counter() if obs_trace.ACTIVE.get() else 0.0
-        with ctx:                              # slow path: backpressure
+        with obs_trace.span("wait", "channel.put", channel=self.name), \
+                ctx:                           # slow path: backpressure
             with self._cond:
                 while len(buf.items) >= buf.capacity:
                     self._check_abort()
@@ -425,9 +421,7 @@ class ChannelGroup:
                 buf.items.append(item)
                 depth = sum(len(b.items) for b in self._buffers.values())
                 self._cond.notify_all()
-        if t0:
-            obs_trace.on_wait("channel.put", t0, time.perf_counter(),
-                              channel=self.name)
+        if obs_trace.ACTIVE.get():
             obs_trace.counter("channel", self.name, depth=depth)
 
     def close(self, key: Tuple[int, int]) -> None:
@@ -462,22 +456,17 @@ class ChannelGroup:
                 return CLOSED
         ctx = (self._pool.blocking() if self._pool is not None
                else nullcontext())
-        t0 = time.perf_counter() if obs_trace.ACTIVE.get() else 0.0
-        try:
-            with ctx:                          # slow path: managed wait
-                with self._cond:
-                    while True:
-                        self._check_abort()
-                        item = self._try_get_locked(keys)
-                        if item is not None:
-                            return item
-                        if all(not b.open for b in self._buffers.values()):
-                            return CLOSED
-                        self._cond.wait(0.2)
-        finally:
-            if t0:
-                obs_trace.on_wait("channel.get", t0, time.perf_counter(),
-                                  channel=self.name)
+        with obs_trace.span("wait", "channel.get", channel=self.name), \
+                ctx:                           # slow path: managed wait
+            with self._cond:
+                while True:
+                    self._check_abort()
+                    item = self._try_get_locked(keys)
+                    if item is not None:
+                        return item
+                    if all(not b.open for b in self._buffers.values()):
+                        return CLOSED
+                    self._cond.wait(0.2)
 
     def __iter__(self) -> Iterator[Delivery]:
         while True:
@@ -495,12 +484,9 @@ class ChannelGroup:
         if not self._closed_evt.is_set():
             ctx = (self._pool.blocking() if self._pool is not None
                    else nullcontext())
-            t0 = time.perf_counter() if obs_trace.ACTIVE.get() else 0.0
-            with ctx:
+            with obs_trace.span("wait", "channel.drain", channel=self.name), \
+                    ctx:
                 self._closed_evt.wait()
-            if t0:
-                obs_trace.on_wait("channel.drain", t0, time.perf_counter(),
-                                  channel=self.name)
         with self._cond:
             self._check_abort()
             items: List[Delivery] = []

@@ -56,8 +56,8 @@ The fault-tolerance layer has three moving parts, all defined here:
 3. **Recording** — every injection, retry and degradation funnels through
    ``record_fault`` / ``record_retry`` / ``record_degradation`` into the
    scoped ``CacheStats`` counters (=> EngineRun / BENCH JSON), the obs trace
-   hooks (instants + metric counters + the retry-backoff histogram), and any
-   open ``fault_recorder`` scope (=> ``EngineRun.degradation_events``).
+   hooks (instants), and any open ``fault_recorder`` scope (=>
+   ``EngineRun.degradation_events``).
 
 ``retry_call`` is the core capped-exponential-backoff helper (the
 generalization of ``train/fault.py:with_retries``): transient failures sleep

@@ -110,17 +110,14 @@ class ActivityRunner:
                 comp.busy = True                # paper line 8
                 return
         ctx = self.pool.blocking() if self.pool is not None else nullcontext()
-        t0 = time.perf_counter() if obs_trace.ACTIVE.get() else 0.0
-        with ctx:
+        with obs_trace.span("wait", "activity.busy", component=comp.name,
+                            split=cache.split_index), ctx:
             with comp.cond:
                 while not self._ready(cache):
                     if self.abort is not None and self.abort.aborted:
                         self.abort.check()
                     comp.cond.wait(0.2)         # paper line 7
                 comp.busy = True                # paper line 8
-        if t0:
-            obs_trace.on_wait("activity.busy", t0, time.perf_counter(),
-                              component=comp.name, split=cache.split_index)
 
     def process(self, cache: SharedCache, shared: bool) -> List[SharedCache]:
         comp = self.comp

@@ -41,7 +41,6 @@ from __future__ import annotations
 import contextvars
 import sys
 import threading
-import time
 from contextlib import contextmanager
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
@@ -64,10 +63,9 @@ def _to_host(v) -> np.ndarray:
     """Materialize on host, recording the d2h transfer for device arrays."""
     if is_host_column(v):
         return v
-    t0 = time.perf_counter() if obs_trace.ACTIVE.get() else 0.0
-    out = np.asarray(v)
-    record_transfer("d2h", out.nbytes,
-                    seconds=(time.perf_counter() - t0) if t0 else 0.0)
+    with obs_trace.annotation("transfer", "d2h") as a:
+        out = np.asarray(v)
+    record_transfer("d2h", out.nbytes, seconds=a.seconds)
     return out
 
 
@@ -714,11 +712,6 @@ class CacheArena:
             self._pools.setdefault(bucket, []).append(root)
             self._pooled_bytes += bucket
             self._pooled_ids.add(id(root))
-        # the trace event only AFTER the buffer is actually accepted into the
-        # pool: a rejected release (double release, over budget, foreign
-        # buffer) must not inflate another run's arena-release accounting
-        if obs_trace.ACTIVE.get():
-            obs_trace.on_arena_release(bucket)
 
     # -------------------------------------------------------------- observe
     @property

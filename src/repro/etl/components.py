@@ -226,10 +226,14 @@ class DimTable:
     """Dimension table for Lookup: key -> payload columns, vectorized via
     sorted keys + searchsorted.  ``row_filter`` marks non-qualifying dim rows
     as unmatched at build time (the paper's `AND c_region='AMERICA'` style
-    join conditions)."""
+    join conditions).  ``name`` names the table's Lookups in traces (the
+    fused segment's ``lookup.<name>`` scopes and probe counters); without
+    one they take the fact table's key column."""
 
     def __init__(self, key: np.ndarray, payload: Dict[str, np.ndarray],
-                 row_filter: Optional[np.ndarray] = None):
+                 row_filter: Optional[np.ndarray] = None,
+                 name: Optional[str] = None):
+        self.name = name
         order = np.argsort(key, kind="stable")
         self.keys = np.asarray(key)[order]
         self.payload = {k: np.asarray(v)[order] for k, v in payload.items()}

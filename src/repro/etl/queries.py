@@ -62,19 +62,20 @@ def _dims(data: SSBData):
     cust = DimTable(data.customer["c_custkey"],
                     {"c_nation": data.customer["c_nation"],
                      "c_region": data.customer["c_region"],
-                     "c_city": data.customer["c_city"]})
+                     "c_city": data.customer["c_city"]}, name="customer")
     supp = DimTable(data.supplier["s_suppkey"],
                     {"s_nation": data.supplier["s_nation"],
                      "s_region": data.supplier["s_region"],
-                     "s_city": data.supplier["s_city"]})
+                     "s_city": data.supplier["s_city"]}, name="supplier")
     part = DimTable(data.part["p_partkey"],
                     {"p_brand1": data.part["p_brand1"],
                      "p_category": data.part["p_category"],
-                     "p_mfgr": data.part["p_mfgr"]})
+                     "p_mfgr": data.part["p_mfgr"]}, name="part")
     date = DimTable(data.date["d_datekey"],
                     {"d_year": data.date["d_year"],
                      "d_yearmonthnum": data.date["d_yearmonthnum"],
-                     "d_weeknuminyear": data.date["d_weeknuminyear"]})
+                     "d_weeknuminyear": data.date["d_weeknuminyear"]},
+                    name="date")
     return cust, supp, part, date
 
 
@@ -133,10 +134,12 @@ def build_q2(data: SSBData, use_dsl: Optional[bool] = None) -> QueryFlow:
     AMERICA = region_id("AMERICA")
     part_f = DimTable(data.part["p_partkey"],
                       {"p_brand1": data.part["p_brand1"]},
-                      row_filter=data.part["p_category"] == CATEGORY)
+                      row_filter=data.part["p_category"] == CATEGORY,
+                      name="part")
     supp_f = DimTable(data.supplier["s_suppkey"],
                       {"s_nation": data.supplier["s_nation"]},
-                      row_filter=data.supplier["s_region"] == AMERICA)
+                      row_filter=data.supplier["s_region"] == AMERICA,
+                      name="supplier")
     flow = Dataflow("ssb-q2.1")
     src = ArraySource("lineorder", data.lineorder)
     lk_part = Lookup("lookup_part", part_f, "lo_partkey",
@@ -182,11 +185,14 @@ def build_q3(data: SSBData, use_dsl: Optional[bool] = None) -> QueryFlow:
     ASIA = region_id("ASIA")
     cust_f = DimTable(data.customer["c_custkey"],
                       {"c_nation": data.customer["c_nation"]},
-                      row_filter=data.customer["c_region"] == ASIA)
+                      row_filter=data.customer["c_region"] == ASIA,
+                      name="customer")
     supp_f = DimTable(data.supplier["s_suppkey"],
                       {"s_nation": data.supplier["s_nation"]},
-                      row_filter=data.supplier["s_region"] == ASIA)
-    date = DimTable(data.date["d_datekey"], {"d_year": data.date["d_year"]})
+                      row_filter=data.supplier["s_region"] == ASIA,
+                      name="supplier")
+    date = DimTable(data.date["d_datekey"], {"d_year": data.date["d_year"]},
+                    name="date")
     flow = Dataflow("ssb-q3.1")
     src = ArraySource("lineorder", data.lineorder)
     lk_cust = Lookup("lookup_customer", cust_f, "lo_custkey",
@@ -242,14 +248,18 @@ def build_q4(data: SSBData, staged: bool = False,
     M1, M2 = mfgr_id("MFGR#1"), mfgr_id("MFGR#2")
     cust_f = DimTable(data.customer["c_custkey"],
                       {"c_nation": data.customer["c_nation"]},
-                      row_filter=data.customer["c_region"] == AMERICA)
+                      row_filter=data.customer["c_region"] == AMERICA,
+                      name="customer")
     supp_f = DimTable(data.supplier["s_suppkey"],
                       {"s_nation": data.supplier["s_nation"]},
-                      row_filter=data.supplier["s_region"] == AMERICA)
+                      row_filter=data.supplier["s_region"] == AMERICA,
+                      name="supplier")
     part_f = DimTable(data.part["p_partkey"], {"p_mfgr": data.part["p_mfgr"]},
                       row_filter=((data.part["p_mfgr"] == M1)
-                                  | (data.part["p_mfgr"] == M2)))
-    date = DimTable(data.date["d_datekey"], {"d_year": data.date["d_year"]})
+                                  | (data.part["p_mfgr"] == M2)),
+                      name="part")
+    date = DimTable(data.date["d_datekey"], {"d_year": data.date["d_year"]},
+                    name="date")
 
     flow = Dataflow("ssb-q4.1")
     src = ArraySource("lineorder", data.lineorder)                    # 1

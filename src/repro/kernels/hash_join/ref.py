@@ -73,10 +73,12 @@ def hash_build(key_cols: Sequence[np.ndarray]) -> Dict[str, object]:
     integer key columns, vectorized on the host.
 
     Returns ``{"slot_keys": tuple_of_[T]_arrays, "slot_idx": int32 [T],
-    "table_size": T, "max_probes": int}`` — ``slot_idx[t] < 0`` marks an
-    empty slot, ``max_probes`` is a static probe-length bound (longest
-    occupied run + 1), so a device probe loop with that trip count always
-    terminates at a hit or an empty slot.
+    "table_size": T, "max_probes": int, "mean_probes": float}`` —
+    ``slot_idx[t] < 0`` marks an empty slot, ``max_probes`` is a static
+    probe-length bound (longest occupied run + 1), so a device probe loop
+    with that trip count always terminates at a hit or an empty slot, and
+    ``mean_probes`` is the mean probe length a lookup of each distinct key
+    needs (the loop runs ``max_probes`` passes for every row all the same).
 
     Insertion processes rows in index order, one probe distance per round,
     so equal keys keep the FIRST row index and colliding distinct keys are
@@ -91,32 +93,32 @@ def hash_build(key_cols: Sequence[np.ndarray]) -> Dict[str, object]:
 
     slot_idx = np.full(size, -1, dtype=np.int32)
     slot_keys = [np.zeros(size, dtype=k.dtype) for k in key_cols]
+    # a key placed in round s is found by a probe of length s + 1
+    probe_sum = n_keys = 0
     if d:
         h0 = hash_keys_np(key_cols)
         live = np.arange(d, dtype=np.int64)     # unplaced rows, index order
         step = np.uint32(0)
         while live.size:
             cand = ((h0[live] + step) & mask).astype(np.int64)
-            occ = slot_idx[cand]
-            # drop duplicates of an already-placed identical key (keep-first)
-            dup = occ >= 0
-            for sk, k in zip(slot_keys, key_cols):
-                dup &= sk[cand] == k[live]
-            placeable = occ < 0
+            placeable = slot_idx[cand] < 0
+            placed = np.zeros(len(live), dtype=bool)
             if placeable.any():
                 # lowest row index wins each contested free slot this round
                 slots = cand[placeable]
                 rows = live[placeable]
                 _, first = np.unique(slots, return_index=True)
                 slot_idx[slots[first]] = rows[first]
-                won = np.zeros(len(rows), dtype=bool)
-                won[first] = True
                 for sk, k in zip(slot_keys, key_cols):
                     sk[slots[first]] = k[rows[first]]
-                placed = np.zeros(len(live), dtype=bool)
-                placed[np.flatnonzero(placeable)[won]] = True
-            else:
-                placed = np.zeros(len(live), dtype=bool)
+                placed[np.flatnonzero(placeable)[first]] = True
+                n_keys += len(first)
+                probe_sum += (int(step) + 1) * len(first)
+            # drop duplicates of an identical key in the slot, placed in an
+            # earlier round or this one (keep-first)
+            dup = ~placed & (slot_idx[cand] >= 0)
+            for sk, k in zip(slot_keys, key_cols):
+                dup &= sk[cand] == k[live]
             live = live[~(placed | dup)]
             step += np.uint32(1)
 
@@ -131,7 +133,35 @@ def hash_build(key_cols: Sequence[np.ndarray]) -> Dict[str, object]:
             max_run = run
     max_probes = int(min(max_run, size) + 1)
     return {"slot_keys": tuple(slot_keys), "slot_idx": slot_idx,
-            "table_size": size, "max_probes": max_probes}
+            "table_size": size, "max_probes": max_probes,
+            "mean_probes": probe_sum / n_keys if n_keys else 0.0}
+
+
+def probe_lengths_np(built: Dict[str, object],
+                     val_cols: Sequence[np.ndarray]) -> np.ndarray:
+    """Per probe row, the passes of :func:`hash_probe_ref`'s loop that
+    settle it: up to the slot holding its key (a hit) or the first empty
+    slot (a miss), walked on the host over ``hash_build``'s table.  Keys
+    compare on their low 32 bits, as on the device."""
+    slot_idx = built["slot_idx"]
+    mask = np.uint32(built["table_size"] - 1)
+    slot_keys = [np.asarray(k).astype(np.uint32) for k in built["slot_keys"]]
+    vals = [np.asarray(v).astype(np.uint32) for v in val_cols]
+    h = hash_keys_np(vals)
+    out = np.zeros(len(h), dtype=np.int32)
+    live = np.arange(len(h))
+    step = np.uint32(0)
+    while live.size:          # ends: the table is at most half full
+        cand = ((h[live] + step) & mask).astype(np.int64)
+        done = slot_idx[cand] < 0
+        hit = ~done
+        for sk, v in zip(slot_keys, vals):
+            hit &= sk[cand] == v[live]
+        done |= hit
+        out[live[done]] = int(step) + 1
+        live = live[~done]
+        step += np.uint32(1)
+    return out
 
 
 def hash_probe_ref(slot_keys: Sequence[jax.Array], slot_idx: jax.Array,

@@ -25,12 +25,13 @@ def radix_groupby(ids: jax.Array, values: jax.Array, n_groups: int,
     """
     if impl == "auto":
         impl = ("pallas" if jax.default_backend() == "tpu" else "reference")
-    if impl == "pallas":
-        return radix_groupby_pallas(ids, values, n_groups,
-                                    part_groups=part_groups,
-                                    rows_tile=rows_tile)
-    if impl == "interpret":
-        return radix_groupby_pallas(ids, values, n_groups,
-                                    part_groups=part_groups,
-                                    rows_tile=rows_tile, interpret=True)
-    return radix_groupby_ref(ids, values, n_groups)
+    with jax.named_scope("groupby.radix"):
+        if impl == "pallas":
+            return radix_groupby_pallas(ids, values, n_groups,
+                                        part_groups=part_groups,
+                                        rows_tile=rows_tile)
+        if impl == "interpret":
+            return radix_groupby_pallas(ids, values, n_groups,
+                                        part_groups=part_groups,
+                                        rows_tile=rows_tile, interpret=True)
+        return radix_groupby_ref(ids, values, n_groups)

@@ -21,10 +21,11 @@ def segment_sum(seg_ids: jax.Array, values: jax.Array, n_groups: int,
     """
     if impl == "auto":
         impl = ("pallas" if jax.default_backend() == "tpu" else "reference")
-    if impl == "pallas":
-        return segment_sum_pallas(seg_ids, values, n_groups,
-                                  rows_tile=rows_tile)
-    if impl == "interpret":
-        return segment_sum_pallas(seg_ids, values, n_groups,
-                                  rows_tile=rows_tile, interpret=True)
-    return segment_sum_ref(seg_ids, values, n_groups)
+    with jax.named_scope("groupby.segment_sum"):
+        if impl == "pallas":
+            return segment_sum_pallas(seg_ids, values, n_groups,
+                                      rows_tile=rows_tile)
+        if impl == "interpret":
+            return segment_sum_pallas(seg_ids, values, n_groups,
+                                      rows_tile=rows_tile, interpret=True)
+        return segment_sum_ref(seg_ids, values, n_groups)

@@ -2,7 +2,7 @@
 
 Each ``trace.Tracer`` owns one ``MetricsRegistry``; the instrumentation
 hooks (``trace.on_transfer`` / ``on_copy`` / ``on_arena`` / ``on_dispatch``
-/ ``on_wait`` / ``on_kernel``) increment it while the tracer's *measuring*
+/ ``on_kernel``) increment it while the tracer's *measuring*
 window is open.  The engines open that window exactly where they open
 ``cache_stats_scope``, so the counter family below reconciles EXACTLY with
 the run's ``CacheStats`` snapshot — the same call sites feed both — and the

@@ -17,12 +17,28 @@ Event model (Chrome trace "traceEvents" array, ts/dur in µs):
   ph="X" complete spans    — engine phases (cat ``phase``), per-component
                              per-chunk dispatches (cat ``compute``), fused
                              kernel launches (cat ``kernel``), h2d/d2h
-                             transfers (cat ``transfer``), blocking waits
-                             (cat ``wait``: channel put/get/drain, admission,
-                             activity busy-wait)
-  ph="i" instant events    — cache copies (cat ``copy``), arena
-                             acquire/release (cat ``arena``)
-  ph="C" counter events    — channel occupancy (cat ``channel``)
+                             transfers (cat ``transfer``: ``h2d``/``d2h``,
+                             and the fused segment's ``h2d.pack`` and
+                             ``h2d.upload`` inside its ``h2d``), the fused
+                             segment's dispatch (``dispatch`` ``segment``),
+                             blocking waits (cat ``wait``: ``h2d.ready``,
+                             channel put/get/drain, admission, activity
+                             busy-wait)
+  ph="i" instant events    — cache copies (cat ``copy``), arena acquires
+                             (cat ``arena``), the fused segment's map from
+                             compiled op to ``jax.named_scope`` (cat
+                             ``program``, name ``scopes``)
+  ph="C" counter events    — channel occupancy (cat ``channel``), each
+                             hash-probe Lookup's rows and passes (cat
+                             ``probe``, named by the dimension table)
+
+Spans opened with ``span`` are also ``jax.profiler.TraceAnnotation``s named
+``repro.<cat>.<name>``, so the profiler's trace shows what the program was
+doing on the device's clock; phase and tick spans (``_OFF_PROFILER``) stay
+off it.  A site whose interval a hook already records as an event (a
+transfer, through ``shared_cache.record_transfer``) opens ``annotation``
+instead: the profiler's half alone, so one interval is one event on each
+clock.
 
 Each run exported by an engine becomes its own Perfetto *process* (pid =
 run ordinal, process_name = flow/engine/backend/run-id) with real thread
@@ -39,7 +55,9 @@ from __future__ import annotations
 import contextvars
 import json
 import os
+import re
 import subprocess
+import sys
 import threading
 import time
 import uuid
@@ -234,6 +252,7 @@ def measured(tracer: Optional[Tracer]):
 class _NullSpan:
     """Reusable no-op context manager returned by ``span`` when disabled."""
     __slots__ = ()
+    seconds = 0.0
 
     def __enter__(self):
         return self
@@ -244,31 +263,64 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: span categories kept off the profiler's trace: phases and ticks last a
+#: whole run or tick, and the trace reduction names a gap in the device's
+#: work after the host event that covers most of it, so they would name
+#: every gap
+_OFF_PROFILER = frozenset({"phase", "tick"})
+
+
+def _annotation(cat: str, name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` for a span, or None for
+    one kept off the profiler, or where JAX was never imported (then no
+    profiler can be running, and ``repro.obs`` imports nothing of JAX)."""
+    jax = sys.modules.get("jax")
+    if jax is None or cat in _OFF_PROFILER:
+        return None
+    ann = jax.profiler.TraceAnnotation(f"repro.{cat}.{name}")
+    ann.__enter__()
+    return ann
+
 
 class _Span:
-    __slots__ = ("cat", "name", "args", "t0")
+    __slots__ = ("cat", "name", "args", "t0", "seconds", "_ann")
 
-    def __init__(self, cat: str, name: str, args: dict):
+    def __init__(self, cat: str, name: str, args: Optional[dict]):
         self.cat = cat
         self.name = name
-        self.args = args
+        self.args = args     # None: the profiler's half only (``annotation``)
 
     def __enter__(self):
+        self._ann = _annotation(self.cat, self.name)
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        complete(self.cat, self.name, self.t0,
-                 time.perf_counter() - self.t0, **self.args)
+        self.seconds = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.args is not None:
+            complete(self.cat, self.name, self.t0, self.seconds, **self.args)
         return False
 
 
 def span(cat: str, name: str, **args):
-    """Context manager recording a complete span on every active tracer;
-    a shared no-op singleton when tracing is off."""
+    """Context manager recording a complete span on every active tracer,
+    and on the profiler's clock unless ``_OFF_PROFILER`` keeps it off; a
+    shared no-op singleton when tracing is off.  ``seconds`` holds its length
+    after exit (0.0 when off)."""
     if not ACTIVE.get():
         return _NULL_SPAN
     return _Span(cat, name, args)
+
+
+def annotation(cat: str, name: str):
+    """``span``'s profiler annotation and ``seconds`` without its event, for
+    a site that hands the interval to a hook that records it (a transfer:
+    ``record_transfer(..., seconds=a.seconds)``)."""
+    if not ACTIVE.get():
+        return _NULL_SPAN
+    return _Span(cat, name, None)
 
 
 def complete(cat: str, name: str, t0: float, dt: float, **args) -> None:
@@ -334,7 +386,6 @@ def on_kernel(name: str, backend: str, t0: float, t1: float,
         tr.emit("X", "kernel", name, t0 * 1e6, dt * 1e6,
                 {"backend": backend, "rows": rows})
         if tr.measuring:
-            tr.metrics.inc("kernel_dispatches")
             tr.metrics.observe("kernel_dispatch_s", dt)
 
 
@@ -353,8 +404,6 @@ def on_transfer(direction: str, nbytes: int, seconds: float = 0.0) -> None:
             m = tr.metrics
             m.inc(f"{direction}_transfers")
             m.inc(f"{direction}_bytes", int(nbytes))
-            if seconds:
-                m.inc(f"{direction}_seconds", seconds)
 
 
 def on_copy(nbytes: int) -> None:
@@ -388,19 +437,6 @@ def on_arena(hit: bool, nbytes: int) -> None:
                 m.inc("arena_misses")
 
 
-def on_arena_release(nbytes: int) -> None:
-    """One buffer returned to the arena pool (event + non-reconciling
-    counter — ``CacheStats`` does not track releases)."""
-    scopes = ACTIVE.get()
-    if not scopes:
-        return
-    ts = time.perf_counter() * 1e6
-    for tr in scopes:
-        tr.emit("i", "arena", "release", ts, args={"bytes": int(nbytes)})
-        if tr.measuring:
-            tr.metrics.inc("arena_releases")
-
-
 def on_fault(site: str, kind: str, component=None) -> None:
     """One injected fault fired (from ``core.faults.record_fault``)."""
     scopes = ACTIVE.get()
@@ -410,13 +446,11 @@ def on_fault(site: str, kind: str, component=None) -> None:
     for tr in scopes:
         tr.emit("i", "fault", f"inject:{site}", ts,
                 args={"kind": kind, "component": component})
-        if tr.measuring:
-            tr.metrics.inc("faults_injected")
 
 
 def on_retry(where: str, attempt: int, delay_s: float) -> None:
     """One transient-failure retry about to back off (from
-    ``core.faults.record_retry``); feeds the retry-latency histogram."""
+    ``core.faults.record_retry``)."""
     scopes = ACTIVE.get()
     if not scopes:
         return
@@ -425,9 +459,6 @@ def on_retry(where: str, attempt: int, delay_s: float) -> None:
         tr.emit("i", "fault", "retry", ts,
                 args={"where": where, "attempt": attempt,
                       "delay_s": delay_s})
-        if tr.measuring:
-            tr.metrics.inc("retries")
-            tr.metrics.observe("retry_backoff_s", delay_s)
 
 
 def on_degrade(kind: str, src: str, dst: str, component=None) -> None:
@@ -440,21 +471,42 @@ def on_degrade(kind: str, src: str, dst: str, component=None) -> None:
     for tr in scopes:
         tr.emit("i", "fault", f"degrade:{kind}", ts,
                 args={"src": src, "dst": dst, "component": component})
-        if tr.measuring:
-            tr.metrics.inc("degradations")
 
 
-def on_wait(kind: str, t0: float, t1: float, **args) -> None:
-    """One blocking wait (channel put/get/drain, admission gate, activity
-    busy-wait).  ``kind`` names the wait site, e.g. ``channel.put``."""
-    scopes = ACTIVE.get()
-    if not scopes:
-        return
-    dt = t1 - t0
-    for tr in scopes:
-        tr.emit("X", "wait", kind, t0 * 1e6, dt * 1e6, args or None)
-        if tr.measuring:
-            tr.metrics.inc(f"wait_s.{kind}", dt)
+#: one instruction of an HLO module's text, and the ``op_name`` of its
+#: metadata (``jit(f)/<named scopes>/<primitive>``)
+_HLO_OP = re.compile(r"^\s*(?:ROOT\s+)?(%[^\s=]+) = ")
+#: instructions that name values and run nothing on the device
+_NO_WORK = re.compile(r"\s(get-tuple-element|tuple|parameter|constant)\(")
+_OP_NAME = re.compile(r'metadata=\{op_name="([^"]*)"')
+#: a transformation's entry in an ``op_name`` (``jit(_where)``), not a scope
+_TRANSFORM = re.compile(r"^\w+\(.*\)$")
+
+
+def entry_scopes(hlo_text: str):
+    """``(module name, {op: scope})`` of a compiled program's text
+    (``compiled.as_text()``): for each op of the ENTRY computation whose
+    metadata names a ``jax.named_scope``, the scope path (``lookup.part/
+    probe``), less the transformations in it (``jit(f)``).  Only ENTRY ops
+    that run on the device: a profiler's device events name them, and a
+    ``while`` or ``fusion`` event already holds the ops inside it."""
+    module = re.search(r"^HloModule ([^\s,]+)", hlo_text, re.M)
+    name = module.group(1) if module else None
+    ops: Dict[str, str] = {}
+    entry = hlo_text.find("\nENTRY ")
+    if entry < 0:
+        return name, ops
+    for line in hlo_text[entry + 1:].splitlines()[1:]:
+        if line.startswith("}"):
+            break
+        op, meta = _HLO_OP.match(line), _OP_NAME.search(line)
+        if op and meta and not _NO_WORK.search(line):
+            # the primitive is the last entry of the path
+            parts = [p for p in meta.group(1).split("/")[:-1]
+                     if not _TRANSFORM.match(p)]
+            if parts:
+                ops[op.group(1)] = "/".join(parts)
+    return name, ops
 
 
 # ---------------------------------------------------------------------------

@@ -7,7 +7,8 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
 from repro.kernels.hash_join import (hash_build, hash_keys, hash_keys_np,
-                                     hash_probe, hash_probe_ref)
+                                     hash_probe, hash_probe_ref,
+                                     probe_lengths_np)
 from repro.kernels.mamba_scan import mamba_scan, mamba_scan_ref
 from repro.kernels.radix_groupby import radix_groupby, radix_groupby_ref
 from repro.kernels.segment_sum import segment_sum, segment_sum_ref
@@ -123,6 +124,72 @@ def test_hash_probe_duplicate_keys_keep_first():
     idx, found = _probe(built, (probes,))
     np.testing.assert_array_equal(np.asarray(found), hit)
     np.testing.assert_array_equal(np.asarray(idx)[hit], ss[hit])
+
+
+def _walk(built, key_cols, start: int, stop) -> int:
+    """Probes of a host-side linear walk from slot ``start`` until
+    ``stop(slot)``."""
+    size = built["table_size"]
+    for step in range(size + 1):
+        if stop((start + step) % size):
+            return step + 1
+    raise AssertionError("walk never stopped")
+
+
+@pytest.mark.parametrize("keys", [
+    np.arange(1, 301, dtype=np.int64),                       # dense, SSB-like
+    RNG.choice(100_000, size=997, replace=False).astype(np.int64),
+    np.array([5, 5, 9, 9, 9, 12], dtype=np.int64),           # duplicates
+    np.array([42], dtype=np.int64),
+])
+def test_hash_build_probe_statistics_match_a_brute_force_walk(keys):
+    """``mean_probes`` is the mean probe length a lookup of each distinct
+    key needs, and ``max_probes`` the longest walk from any slot to an
+    empty one: both as a slot-by-slot walk of the built table finds them."""
+    built = hash_build((keys,))
+    slot_idx, (slot_keys,) = built["slot_idx"], built["slot_keys"]
+    size = built["table_size"]
+    h = hash_keys_np((keys,)).astype(np.int64) & (size - 1)
+    lengths = {int(k): _walk(built, (keys,), int(s),
+                             lambda t, k=k: slot_idx[t] >= 0
+                             and slot_keys[t] == k)
+               for k, s in zip(keys, h)}
+    assert built["mean_probes"] == pytest.approx(
+        sum(lengths.values()) / len(lengths))
+    longest = max(_walk(built, (keys,), t, lambda u: slot_idx[u] < 0)
+                  for t in range(size))
+    assert built["max_probes"] == longest
+
+
+def test_hash_build_of_no_keys_has_no_probe_length():
+    built = hash_build((np.zeros(0, np.int64),))
+    assert built["mean_probes"] == 0.0 and built["max_probes"] == 1
+
+
+@pytest.mark.parametrize("n_cols", [1, 2])
+def test_probe_lengths_match_a_brute_force_walk(n_cols):
+    """``probe_lengths_np`` gives each probe row the passes that settle it:
+    the walk from its home slot to its key (a hit) or to an empty slot (a
+    miss); and the device loop run for that many passes finds every hit."""
+    build = [RNG.choice(5_000, size=700, replace=False).astype(np.int64)
+             for _ in range(n_cols)]
+    probes = [np.concatenate([b[RNG.integers(0, 700, 300)],
+                              RNG.integers(0, 5_000, 200)]) for b in build]
+    built = hash_build(build)
+    slot_idx, slot_keys = built["slot_idx"], built["slot_keys"]
+    size = built["table_size"]
+    home = hash_keys_np(probes).astype(np.int64) & (size - 1)
+    want = [_walk(built, build, int(s),
+                  lambda t, r=r: slot_idx[t] < 0 or all(
+                      sk[t] == p[r] for sk, p in zip(slot_keys, probes)))
+            for r, s in enumerate(home)]
+    got = probe_lengths_np(built, probes)
+    np.testing.assert_array_equal(got, want)
+    assert got.max() <= built["max_probes"]
+    _, found_all = _probe(built, probes)
+    _, found_short = _probe(dict(built, max_probes=int(got.max())), probes)
+    np.testing.assert_array_equal(np.asarray(found_short),
+                                  np.asarray(found_all))
 
 
 @pytest.mark.parametrize("dtype", [np.int64, np.int32])

@@ -64,6 +64,97 @@ def test_trace_scope_disabled_is_null():
     assert s1 is s2                      # shared no-op singleton: no alloc
     with s1:
         pass
+    assert s1.seconds == 0.0
+
+
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation`` and counts entries."""
+    entered: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.entered.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_span_annotates_the_profiler_only_while_traced(monkeypatch):
+    import jax
+    entered = []
+    monkeypatch.setattr(_CountingAnnotation, "entered", entered)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    with obs_trace.span("wait", "h2d.ready"), \
+            obs_trace.annotation("transfer", "d2h") as a:  # no tracer
+        pass
+    assert entered == [] and a.seconds == 0.0
+    with obs_trace.trace_scope() as tr:
+        with obs_trace.span("wait", "h2d.ready") as sp:
+            pass
+        with obs_trace.span("phase", "execute"), obs_trace.span("tick", "t"):
+            pass                             # whole runs stay off it
+        with obs_trace.span("wait", "channel.drain"):
+            pass
+    assert entered == ["repro.wait.h2d.ready", "repro.wait.channel.drain"]
+    assert sp.seconds >= 0.0
+    assert [(e["cat"], e["name"]) for e in tr.events] == [
+        ("wait", "h2d.ready"), ("tick", "t"), ("phase", "execute"),
+        ("wait", "channel.drain")]
+
+
+def test_annotation_is_the_profiler_half_of_a_span(monkeypatch):
+    """A transfer's interval is one ``repro.obs`` event (``record_transfer``
+    records it with the annotation's ``seconds``) and one profiler
+    annotation, not two events."""
+    import jax
+    from repro.core.shared_cache import _to_host
+    entered = []
+    monkeypatch.setattr(_CountingAnnotation, "entered", entered)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    with obs_trace.trace_scope() as tr:
+        with obs_trace.annotation("transfer", "d2h") as a:
+            pass
+        out = _to_host(jax.numpy.arange(4))
+    assert a.seconds >= 0.0 and out.tolist() == [0, 1, 2, 3]
+    assert entered == ["repro.transfer.d2h", "repro.transfer.d2h"]
+    assert [(e["ph"], e["cat"], e["name"]) for e in tr.events] == [
+        ("X", "transfer", "d2h")]
+
+
+def test_span_is_one_interval_on_both_clocks(tmp_path):
+    """A span is a host-plane event of a CPU profiler trace and a
+    ``repro.obs`` event; a phase span is only the latter."""
+    import glob
+    import time
+    import warnings
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.trace_scope() as tr:
+            with obs_trace.span("wait", "channel.get", channel="c"):
+                time.sleep(0.002)
+            with obs_trace.span("phase", "execute"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        data = ProfileData.from_file(path)
+        host = [(e.name, e.duration_ns) for p in data.planes
+                if p.name == "/host:CPU" for line in p.lines
+                for e in line.events]
+    profiled = [d for n, d in host if n == "repro.wait.channel.get"]
+    assert len(profiled) == 1 and profiled[0] >= 2e6
+    assert not any(n.startswith("repro.phase") for n, _ in host)
+    ev = tr.events[0]
+    assert (ev["cat"], ev["name"], ev["args"]) == ("wait", "channel.get",
+                                                  {"channel": "c"})
+    assert ev["dur"] >= 2e3                  # µs, the same interval
 
 
 def test_trace_scope_records_spans_and_nests():
@@ -313,3 +404,94 @@ def test_trace_file_keeps_oversized_newest_run(tmp_path, monkeypatch):
     tf.add_and_flush(big, str(path))
     payload = json.loads(path.read_text())
     assert [m["flow"] for m in payload["otherData"]["runs"]] == ["big"]
+
+
+# ---------------------------------------------------------------------------
+#  The fused segment's named scopes and probe counters
+# ---------------------------------------------------------------------------
+Q41_SEGMENT = ["lookup_customer", "lookup_supplier", "lookup_part",
+               "lookup_date", "filter_unmatched", "project", "profit_expr"]
+
+
+@pytest.fixture(scope="module")
+def q41_runner(data):
+    pytest.importorskip("jax")
+    from repro.core.backend.jax_backend import JaxBackend
+    from repro.etl.components import FusedSegment
+    qf = build_q4(data)
+    seg = FusedSegment.from_components(
+        [qf.flow.component(m) for m in Q41_SEGMENT])
+    return JaxBackend().compile_segment(seg)
+
+
+def _segment_call(runner, data, traced: bool):
+    from repro.core.shared_cache import SharedCache
+    cache = SharedCache({k: v.copy() for k, v in data.lineorder.items()})
+    if not traced:
+        runner(cache)
+        return None
+    with obs_trace.trace_scope() as tr:
+        runner(cache)
+    return tr.events
+
+
+def test_fused_q41_scope_map_names_each_lookups_probe(q41_runner, data):
+    _segment_call(q41_runner, data, traced=False)
+    assert q41_runner._scope_maps == {}      # built only while traced
+    events = _segment_call(q41_runner, data, traced=True)
+    (ev,) = [e for e in events if (e["ph"], e["cat"], e["name"])
+             == ("i", "program", "scopes")]
+    args = ev["args"]
+    assert args["program"] == "jit__kernel"
+    assert args["layout"].startswith("8192:lo_custkey,")
+    scopes = set(args["ops"].values())
+    for dim in ("customer", "supplier", "part", "date"):
+        probe = [op for op, s in args["ops"].items()
+                 if s == f"lookup.{dim}/probe"]
+        assert any(op.startswith("%while") for op in probe), dim
+    # XLA may fuse a gather into the filter: some stay top-level ops
+    assert {"lookup.customer/gather", "filter.0", "expr.profit"} <= scopes
+    # the map is built once per layout and reused by the next call
+    events = _segment_call(q41_runner, data, traced=True)
+    assert not [e for e in events if e["name"] == "scopes.build"]
+
+
+@pytest.mark.parametrize("keys,vals", [
+    (np.arange(100, 400), np.r_[np.arange(100, 400), 99, 400, -7]),  # dense
+    (np.arange(0, 10**6, 997), np.arange(-5, 10**6, 3001)),         # sparse
+    (np.arange(0, 0), np.arange(5)),                                 # empty
+])
+def test_probe_need_is_the_walk_of_every_row(q41_runner, keys, vals):
+    """A traced call's ``need``, whether looked up per key over a dense key
+    range or walked, is the host walk's sum over the rows."""
+    from repro.core.backend.jax_backend import JaxBackend
+    from repro.etl.components import DimTable
+    from repro.kernels.hash_join import probe_lengths_np
+    keys = keys.astype(np.int64)
+    table = JaxBackend()._dim_hash(DimTable(keys, {"v": keys}))
+    want = int(probe_lengths_np(table["host"], (vals,)).sum())
+    assert q41_runner._probe_need(table, vals) == want
+    assert q41_runner._probe_need(table, vals) == want   # cached lengths
+    assert (table["lengths"][1] is None) == (len(keys) != 300)
+
+
+def test_fused_q41_counts_probe_passes_per_lookup(q41_runner, data):
+    events = _segment_call(q41_runner, data, traced=True)
+    probes = {e["name"]: e["args"] for e in events
+              if (e["ph"], e["cat"]) == ("C", "probe")}
+    assert set(probes) == {"customer", "supplier", "part", "date"}
+    from repro.kernels.hash_join import hash_build, probe_lengths_np
+    table = hash_build((data.part["p_partkey"],))
+    need = probe_lengths_np(table, (data.lineorder["lo_partkey"],)).sum()
+    assert probes["part"] == {
+        "rows": 5000, "padded_rows": 8192, "passes": table["max_probes"],
+        "mean_probes": table["mean_probes"], "slots": table["table_size"],
+        "need": need}
+    # each row of each Lookup needs at least one pass, at most all
+    for p in probes.values():
+        assert p["rows"] <= p["need"] <= p["rows"] * p["passes"]
+    # the leaf spans of the call, each on both clocks while traced
+    names = {(e["cat"], e["name"]) for e in events if e["ph"] == "X"}
+    assert {("transfer", "h2d.pack"), ("transfer", "h2d.upload"),
+            ("wait", "h2d.ready"), ("transfer", "h2d"),
+            ("dispatch", "segment"), ("transfer", "d2h")} <= names

@@ -10,7 +10,7 @@ smoke (``chip_smoke.py``) feeds it:
   7000 dense cells;
 * ``segment_sum_pallas`` at Q1.1's 2^17 rows;
 * one fused Q4.1 segment kernel over SF1 dimension tables at a 2^21-row
-  chunk bucket.
+  chunk bucket, with one top-level probe loop under each Lookup's scope.
 
 A compile that passes is not a chip run: nothing here executes.  The
 topology is described inside a fixture (only the worker that runs these
@@ -101,7 +101,10 @@ def test_segment_sum_pallas_compiles(one_chip):
     _fits(compiled)
 
 
-def test_fused_q41_segment_compiles_at_sf1(one_chip):
+@pytest.fixture(scope="module")
+def fused_q41(one_chip):
+    """The fused Q4.1 segment kernel over SF1 dimension tables, compiled at
+    a 2^21-row chunk bucket."""
     from repro.core.backend.jax_backend import JaxBackend
     from repro.etl import BUILDERS
     from repro.etl.components import FusedSegment
@@ -120,7 +123,26 @@ def test_fused_q41_segment_compiles_at_sf1(one_chip):
     dims = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
                         runner.device_dims())
     assert max(int(d["slot_idx"].shape[0]) for d in dims) == 1 << 19
-    compiled = runner._jit.lower(
+    return runner._jit.lower(
         (bucket, tuple(entries)), _spec(one_chip, (total,), jnp.uint8), {},
         dims).compile()
-    _fits(compiled)
+
+
+def test_fused_q41_segment_compiles_at_sf1(fused_q41):
+    _fits(fused_q41)
+
+
+def test_fused_q41_segment_names_one_probe_loop_per_lookup(fused_q41):
+    """On the chip's compiler too, each Lookup's probe is one top-level
+    ``while`` under ``lookup.<dim>/probe``: the op the profiler's device
+    events name."""
+    from repro.obs.trace import entry_scopes
+    program, ops = entry_scopes(fused_q41.as_text())
+    assert program == "jit__kernel"
+    loops = {}
+    for op, scope in ops.items():
+        if op.startswith("%while"):
+            loops.setdefault(scope, []).append(op)
+    assert sorted(loops) == [f"lookup.{d}/probe" for d in
+                             ("customer", "date", "part", "supplier")]
+    assert all(len(v) == 1 for v in loops.values())
