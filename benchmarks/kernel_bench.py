@@ -99,7 +99,7 @@ def run() -> list:
     slot_idx = jnp.asarray(ht["slot_idx"])
     probes = jnp.asarray(rng.integers(0, 1 << 22, Np_).astype(np.int64))
     ms = _time(lambda p: hash_probe(slot_keys, slot_idx, (p,),
-                                    ht["max_probes"]),
+                                    ht["max_probes"], ht["base"]),
                probes)
     mp = ht["max_probes"]
     flops = 1.0 * Np_ * (6 + 4 * mp)     # fmix32 + per-step cmp/mask chain
@@ -138,7 +138,8 @@ def smoke(data=None):
         sk = tuple(jnp.asarray(x) for x in ht["slot_keys"])
         si = jnp.asarray(ht["slot_idx"])
         probes = jnp.asarray(rng.integers(0, 6_000, 3_000).astype(np.int64))
-        i_r, f_r = hash_probe(sk, si, (probes,), ht["max_probes"])
+        i_r, f_r = hash_probe(sk, si, (probes,), ht["max_probes"],
+                              ht["base"])
         # vs the searchsorted oracle (found rows index the leftmost match)
         pv = np.asarray(probes)
         ss = np.clip(np.searchsorted(keys, pv), 0, len(keys) - 1)

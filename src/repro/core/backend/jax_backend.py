@@ -4,7 +4,8 @@ Kernels:
   - ``searchsorted_probe`` / ``lookup_gather`` — probe over a device-cached
     dimension table (keys/qualifies/payload are device_put once per table and
     reused across every chunk).  Default route is the ``kernels/hash_join``
-    open-addressing table (host-built once per DimTable, probed through XLA;
+    slot table (host-built once per DimTable, probed through XLA; slot
+    ``key - min`` for dense integer keys, else fmix32 open addressing;
     probes handle arbitrary key order and multi-column keys);
     ``REPRO_JOIN_IMPL=searchsorted`` selects the legacy jitted binary search
     over the sorted keys.  Both return the same (index, matched) pair
@@ -308,11 +309,15 @@ class JaxBackend(Backend):
         return got
 
     def _dim_hash(self, dim) -> Dict[str, object]:
-        """Open-addressing hash table over the DimTable's keys: built once on
-        host (``kernels/hash_join.hash_build``), slot arrays device_put once,
-        cached on the table itself like ``_dim_device``.  ``max_probes`` (the
-        static probe-loop bound) stays a Python int — it must never become a
-        tracer; ``host`` (the build's own arrays), ``key_range`` (of the
+        """Slot table over the DimTable's keys: built once on host
+        (``kernels/hash_join.hash_build``), slot arrays device_put once,
+        cached on the table itself like ``_dim_device``.  The build picks the
+        slot function from the keys: direct (slot ``key - base``, one pass)
+        for an integer key column whose span fits in 31 bits and whose table
+        is at most ``DIRECT_MAX_RATIO`` times the hashed one, else fmix32
+        linear probing (``base`` None).  ``max_probes`` (the static probe
+        bound) and ``base`` stay Python values — they must never become
+        tracers; ``host`` (the build's own arrays), ``key_range`` (of the
         sorted keys), ``mean_probes`` and ``table_size`` feed the probe
         counters."""
         ht = dim.__dict__.get("_jax_hash_cache")
@@ -329,6 +334,7 @@ class JaxBackend(Backend):
                                            for k in built["slot_keys"]),
                         "slot_idx": self.asarray(built["slot_idx"]),
                         "max_probes": int(built["max_probes"]),
+                        "base": built["base"],
                         "mean_probes": float(built["mean_probes"]),
                         "table_size": int(built["table_size"]),
                         "host": built,
@@ -414,7 +420,7 @@ class JaxBackend(Backend):
                     ht = self._dim_hash(dim)
                     idx, found = self._hash_probe(
                         ht["slot_keys"], ht["slot_idx"], (v,),
-                        ht["max_probes"])
+                        ht["max_probes"], ht["base"])
                     matched = found & dev["qualifies"][idx]
                 break
             except BaseException as e:
@@ -716,11 +722,12 @@ class _JaxSegmentRunner:
         else:
             with scope("probe"):
                 if table:
-                    # hash-probe route, traced inline so the open-
-                    # addressing loop fuses into this one XLA computation
+                    # hash-probe route, traced inline so the probe (one
+                    # pass on a direct table, else the open-addressing
+                    # loop) fuses into this one XLA computation
                     idx, found = self._bk._hash_probe_ref(
                         d["slot_keys"], d["slot_idx"], (vals,),
-                        table["max_probes"])
+                        table["max_probes"], table["base"])
                 else:
                     idx = jnp.clip(jnp.searchsorted(keys, vals),
                                    0, keys.shape[0] - 1)
@@ -891,8 +898,9 @@ class _JaxSegmentRunner:
                     host_cols: Dict[str, np.ndarray]) -> None:
         """A traced call's events: which compiled op belongs to which scope,
         and per hash-probe Lookup the rows probed, the passes its loop ran
-        over them and, where its key column is a host input of the call,
-        the passes they need (``need``, walked on the host)."""
+        over them (1 on a ``direct`` table), and, where its key column is a
+        host input of the call, the passes they need (``need``, walked on
+        the host)."""
         program, key, ops = self._scope_map(layout, args)
         obs_trace.instant("program", "scopes", program=program, layout=key,
                           ops=ops)
@@ -908,6 +916,7 @@ class _JaxSegmentRunner:
                 obs_trace.counter(
                     "probe", name, rows=n, padded_rows=bucket,
                     passes=table["max_probes"],
+                    direct=int(table["base"] is not None),
                     mean_probes=table["mean_probes"],
                     slots=table["table_size"], **counts)
 

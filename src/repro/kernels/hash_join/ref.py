@@ -1,23 +1,32 @@
-"""Host-side open-addressing build + the jnp probe for the hash join (the
-Lookup's device route, jit'd by ``ops.hash_probe`` and inlined by the fused
-segment kernel).
+"""Host-side build + the jnp probe for the hash join (the Lookup's device
+route, jit'd by ``ops.hash_probe`` and inlined by the fused segment kernel).
 
 The build runs ONCE per dimension table on the host (numpy) and the probe
 runs per chunk on the device, so the two halves must agree bit-for-bit on
-the hash function.  Both sides compute a murmur3-style fmix32 finalizer over
-the key's low 32 bits (uint32 wraparound arithmetic — identical in numpy
-and in jnp with x64 disabled, where 64-bit keys canonicalize to 32-bit on
-device anyway).
+the home slot of a key.  The build picks one of two slot functions from
+the keys it is given:
 
-Duplicate keys keep the FIRST occurrence (lowest row index).  Built over a
-``DimTable``'s sorted key column this makes the probe's gather index equal
-to ``searchsorted``'s leftmost-duplicate index, so the hash route is
-byte-compatible with the legacy sorted-probe route; over an arbitrary
-(shuffled) key order it is simply first-occurrence-wins.
+* **direct** (``base`` = the least key): one integer key column whose span
+  ``max - min + 1`` fits in 31 bits and whose table, ``next_pow2(span)``
+  slots, is at most ``DIRECT_MAX_RATIO`` times the hashed one.  Key ``k``
+  sits in slot ``(uint32(k) - uint32(base)) & (size - 1)``, its own, so one
+  pass settles every probe row, hit or miss (``max_probes`` 1).
+* **fmix32** (``base`` None): every other key set — sparse, multi-column
+  or empty.  Open addressing with linear probing from a murmur3-style
+  fmix32 finalizer over the keys' low 32 bits (uint32 wraparound
+  arithmetic, identical in numpy and in jnp with x64 disabled, where
+  64-bit keys canonicalize to 32-bit on device anyway).
+
+Either way keys compare on their low 32 bits.  Duplicate keys keep the
+FIRST occurrence (lowest row index).  Built over a ``DimTable``'s sorted
+key column this makes the probe's gather index equal to ``searchsorted``'s
+leftmost-duplicate index, so the hash route is byte-compatible with the
+legacy sorted-probe route; over an arbitrary (shuffled) key order it is
+simply first-occurrence-wins.
 """
 from __future__ import annotations
 
-from typing import Dict, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -29,6 +38,10 @@ _FMIX_C1 = 0x85EB_CA6B
 _FMIX_C2 = 0xC2B2_AE35
 #: per-key-column mixing multiplier (odd => bijective mod 2^32)
 _COL_MIX = 0x9E37_79B9
+#: a key column is direct-addressed where its table is at most this many
+#: times the size of the fmix32 one (SSB's dates, 2,557 days over 61,131
+#: yyyymmdd values, sit exactly at the edge)
+DIRECT_MAX_RATIO = 8
 
 
 def _fmix32_np(h: np.ndarray) -> np.ndarray:
@@ -68,27 +81,65 @@ def _next_pow2(x: int) -> int:
     return 1 << max(4, (x - 1).bit_length())
 
 
+def _direct_base(key_cols: Sequence[np.ndarray],
+                 hashed_size: int) -> Optional[int]:
+    """The least key where ``key_cols`` is one integer column that direct
+    addressing serves (its span fits in 31 bits and its table is at most
+    ``DIRECT_MAX_RATIO`` times ``hashed_size``), else None."""
+    if len(key_cols) != 1:
+        return None
+    (k,) = key_cols
+    if not len(k) or not np.issubdtype(k.dtype, np.integer):
+        return None
+    lo, hi = int(k.min()), int(k.max())
+    span = hi - lo + 1
+    if span >= 1 << 31 or _next_pow2(span) > DIRECT_MAX_RATIO * hashed_size:
+        return None
+    return lo
+
+
+def _base_u32(base: int) -> np.uint32:
+    return np.uint32(base % (1 << 32))
+
+
 def hash_build(key_cols: Sequence[np.ndarray]) -> Dict[str, object]:
-    """Open-addressing (linear probing) build over ``d`` rows of one or more
-    integer key columns, vectorized on the host.
+    """Slot table over ``d`` rows of one or more integer key columns, built
+    on the host.
 
     Returns ``{"slot_keys": tuple_of_[T]_arrays, "slot_idx": int32 [T],
-    "table_size": T, "max_probes": int, "mean_probes": float}`` —
-    ``slot_idx[t] < 0`` marks an empty slot, ``max_probes`` is a static
-    probe-length bound (longest occupied run + 1), so a device probe loop
-    with that trip count always terminates at a hit or an empty slot, and
-    ``mean_probes`` is the mean probe length a lookup of each distinct key
-    needs (the loop runs ``max_probes`` passes for every row all the same).
+    "table_size": T, "max_probes": int, "mean_probes": float, "base": int |
+    None}`` — ``slot_idx[t] < 0`` marks an empty slot, ``max_probes`` is a
+    static probe-length bound, so a device probe loop with that trip count
+    always settles each row at a hit or a miss, and ``mean_probes`` is the
+    mean probe length a lookup of each distinct key needs (the loop runs
+    ``max_probes`` passes for every row all the same).
 
-    Insertion processes rows in index order, one probe distance per round,
-    so equal keys keep the FIRST row index and colliding distinct keys are
-    placed deterministically (lowest index wins a free slot).  Table size is
-    the next power of two >= 2*d (load factor <= 0.5)."""
+    Direct (``base`` the least key, the rule in the module docstring): slot
+    ``k - base`` holds key ``k``'s first row index, ``T = next_pow2(span)``
+    and ``max_probes`` is 1.  Otherwise fmix32 linear probing (``base``
+    None): insertion processes rows in index order, one probe distance per
+    round, so equal keys keep the FIRST row index and colliding distinct
+    keys are placed deterministically (lowest index wins a free slot);
+    ``T`` is the next power of two >= 2*d (load factor <= 0.5) and
+    ``max_probes`` the longest occupied run + 1."""
     key_cols = [np.asarray(k) for k in key_cols]
     d = len(key_cols[0])
     if any(len(k) != d for k in key_cols):
         raise ValueError("hash_build: key columns must share a length")
     size = _next_pow2(max(2 * max(d, 1), 16))
+    base = _direct_base(key_cols, size)
+    if base is not None:
+        (k,) = key_cols
+        off = (k.astype(np.uint32) - _base_u32(base)).astype(np.int64)
+        size = _next_pow2(int(off.max()) + 1)
+        _, first = np.unique(off, return_index=True)     # keep-first
+        slot_idx = np.full(size, -1, dtype=np.int32)
+        slot_idx[off[first]] = first
+        slot_keys = np.zeros(size, dtype=k.dtype)
+        slot_keys[off[first]] = k[first]
+        return {"slot_keys": (slot_keys,), "slot_idx": slot_idx,
+                "table_size": size, "max_probes": 1, "mean_probes": 1.0,
+                "base": base}
     mask = np.uint32(size - 1)
 
     slot_idx = np.full(size, -1, dtype=np.int32)
@@ -134,44 +185,65 @@ def hash_build(key_cols: Sequence[np.ndarray]) -> Dict[str, object]:
     max_probes = int(min(max_run, size) + 1)
     return {"slot_keys": tuple(slot_keys), "slot_idx": slot_idx,
             "table_size": size, "max_probes": max_probes,
-            "mean_probes": probe_sum / n_keys if n_keys else 0.0}
+            "mean_probes": probe_sum / n_keys if n_keys else 0.0,
+            "base": None}
 
 
 def probe_lengths_np(built: Dict[str, object],
                      val_cols: Sequence[np.ndarray]) -> np.ndarray:
     """Per probe row, the passes of :func:`hash_probe_ref`'s loop that
-    settle it: up to the slot holding its key (a hit) or the first empty
-    slot (a miss), walked on the host over ``hash_build``'s table.  Keys
-    compare on their low 32 bits, as on the device."""
+    settle it: from the row's home slot (the table's own slot function) up
+    to the slot holding its key (a hit) or the first empty slot (a miss),
+    walked on the host over ``hash_build``'s table, and at most
+    ``max_probes``, as the device loop runs.  Keys compare on their low 32
+    bits, as on the device."""
     slot_idx = built["slot_idx"]
     mask = np.uint32(built["table_size"] - 1)
     slot_keys = [np.asarray(k).astype(np.uint32) for k in built["slot_keys"]]
     vals = [np.asarray(v).astype(np.uint32) for v in val_cols]
-    h = hash_keys_np(vals)
-    out = np.zeros(len(h), dtype=np.int32)
+    if built["base"] is None:
+        h = hash_keys_np(vals)
+    else:
+        h = vals[0] - _base_u32(built["base"])
+    out = np.full(len(h), built["max_probes"], dtype=np.int32)
     live = np.arange(len(h))
-    step = np.uint32(0)
-    while live.size:          # ends: the table is at most half full
-        cand = ((h[live] + step) & mask).astype(np.int64)
+    for step in range(built["max_probes"]):
+        if not live.size:
+            break
+        cand = ((h[live] + np.uint32(step)) & mask).astype(np.int64)
         done = slot_idx[cand] < 0
         hit = ~done
         for sk, v in zip(slot_keys, vals):
             hit &= sk[cand] == v[live]
         done |= hit
-        out[live[done]] = int(step) + 1
+        out[live[done]] = step + 1
         live = live[~done]
-        step += np.uint32(1)
     return out
 
 
 def hash_probe_ref(slot_keys: Sequence[jax.Array], slot_idx: jax.Array,
-                   val_cols: Sequence[jax.Array], max_probes: int
-                   ) -> Tuple[jax.Array, jax.Array]:
+                   val_cols: Sequence[jax.Array], max_probes: int,
+                   base: Optional[int]) -> Tuple[jax.Array, jax.Array]:
     """Pure-jnp probe: returns ``(row_idx int32, found bool)`` per probe
     row.  ``row_idx`` is the build's first-occurrence index for found keys
     and 0 for misses (callers gate every gather on ``found``).  Traceable —
-    the fused segment kernel inlines this directly."""
+    the fused segment kernel inlines this directly; ``max_probes`` and
+    ``base`` are static (``hash_build``'s).
+
+    ``base`` None: ``max_probes`` passes of linear probing from the fmix32
+    home slot.  ``base`` an int (a direct table): one pass, a gather of
+    ``slot_idx`` at the home slot ``(uint32(key) - uint32(base)) & (T - 1)``
+    under a range check ``uint32(key) - uint32(base) < T`` in place of the
+    slot-key compare.  It is exact: a key in range finds its own slot (the
+    slots past the span are empty), and a key out of range misses."""
     size = slot_idx.shape[0]
+    if base is not None:
+        (v,) = val_cols
+        off = v.astype(jnp.uint32) - jnp.uint32(base % (1 << 32))
+        home = (off & jnp.uint32(size - 1)).astype(jnp.int32)
+        occ = jnp.take(slot_idx, home, mode="clip")
+        found = (off < jnp.uint32(size)) & (occ >= 0)
+        return jnp.where(found, occ, 0), found
     n = val_cols[0].shape[0]
     h = hash_keys(list(val_cols))
 

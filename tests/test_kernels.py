@@ -65,7 +65,18 @@ def _probe(built, cols):
     return hash_probe(tuple(jnp.asarray(k) for k in built["slot_keys"]),
                       jnp.asarray(built["slot_idx"]),
                       tuple(jnp.asarray(c) for c in cols),
-                      built["max_probes"])
+                      built["max_probes"], built["base"])
+
+
+def _home(built, key_cols):
+    """Each row's home slot under the table's own slot function: ``key -
+    base`` on a direct table, the fmix32 hash otherwise."""
+    if built["base"] is None:
+        h = hash_keys_np(key_cols)
+    else:
+        h = (np.asarray(key_cols[0]).astype(np.uint32)
+             - np.uint32(built["base"] % (1 << 32)))
+    return h.astype(np.int64) & (built["table_size"] - 1)
 
 
 def test_hash_keys_host_device_identical():
@@ -144,20 +155,29 @@ def _walk(built, key_cols, start: int, stop) -> int:
 ])
 def test_hash_build_probe_statistics_match_a_brute_force_walk(keys):
     """``mean_probes`` is the mean probe length a lookup of each distinct
-    key needs, and ``max_probes`` the longest walk from any slot to an
-    empty one: both as a slot-by-slot walk of the built table finds them."""
+    key needs, walked from its home slot; ``max_probes`` the passes that
+    settle any probe: on an fmix32 table the longest walk from any slot to
+    an empty one, on a direct table one (each occupied slot holds its own
+    key, so a probe hits there or misses)."""
     built = hash_build((keys,))
     slot_idx, (slot_keys,) = built["slot_idx"], built["slot_keys"]
     size = built["table_size"]
-    h = hash_keys_np((keys,)).astype(np.int64) & (size - 1)
+    h = _home(built, (keys,))
     lengths = {int(k): _walk(built, (keys,), int(s),
                              lambda t, k=k: slot_idx[t] >= 0
                              and slot_keys[t] == k)
                for k, s in zip(keys, h)}
     assert built["mean_probes"] == pytest.approx(
         sum(lengths.values()) / len(lengths))
-    longest = max(_walk(built, (keys,), t, lambda u: slot_idx[u] < 0)
-                  for t in range(size))
+    if built["base"] is None:
+        longest = max(_walk(built, (keys,), t, lambda u: slot_idx[u] < 0)
+                      for t in range(size))
+    else:
+        occupied = np.flatnonzero(slot_idx >= 0)
+        np.testing.assert_array_equal(slot_keys[occupied],
+                                      built["base"] + occupied)
+        longest = max(lengths.values())
+        assert (longest, built["mean_probes"]) == (1, 1.0)
     assert built["max_probes"] == longest
 
 
@@ -169,19 +189,23 @@ def test_hash_build_of_no_keys_has_no_probe_length():
 @pytest.mark.parametrize("n_cols", [1, 2])
 def test_probe_lengths_match_a_brute_force_walk(n_cols):
     """``probe_lengths_np`` gives each probe row the passes that settle it:
-    the walk from its home slot to its key (a hit) or to an empty slot (a
-    miss); and the device loop run for that many passes finds every hit."""
+    the walk from its home slot (the table's own: one key column here is
+    direct, two are fmix32) to its key (a hit) or to an empty slot (a
+    miss), at most ``max_probes`` as the device loop runs; and the device
+    loop run for that many passes finds every hit."""
     build = [RNG.choice(5_000, size=700, replace=False).astype(np.int64)
              for _ in range(n_cols)]
     probes = [np.concatenate([b[RNG.integers(0, 700, 300)],
-                              RNG.integers(0, 5_000, 200)]) for b in build]
+                              RNG.integers(-100, 5_100, 200)])
+              for b in build]
     built = hash_build(build)
+    assert (built["base"] is not None) == (n_cols == 1)
     slot_idx, slot_keys = built["slot_idx"], built["slot_keys"]
-    size = built["table_size"]
-    home = hash_keys_np(probes).astype(np.int64) & (size - 1)
-    want = [_walk(built, build, int(s),
-                  lambda t, r=r: slot_idx[t] < 0 or all(
-                      sk[t] == p[r] for sk, p in zip(slot_keys, probes)))
+    home = _home(built, probes)
+    want = [min(_walk(built, build, int(s),
+                      lambda t, r=r: slot_idx[t] < 0 or all(
+                          sk[t] == p[r] for sk, p in zip(slot_keys, probes))),
+                built["max_probes"])
             for r, s in enumerate(home)]
     got = probe_lengths_np(built, probes)
     np.testing.assert_array_equal(got, want)
@@ -215,8 +239,8 @@ def test_hash_probe_all_miss_and_empty_probe():
 
 
 def test_hash_probe_ref_traceable():
-    """hash_probe_ref must trace under jit with max_probes static — the
-    fused segment kernel inlines it."""
+    """hash_probe_ref must trace under jit with max_probes and base static
+    — the fused segment kernel inlines it."""
     keys = np.sort(RNG.choice(1_000, 300, replace=False)).astype(np.int64)
     built = hash_build((keys,))
     sk = tuple(jnp.asarray(k) for k in built["slot_keys"])
@@ -225,12 +249,144 @@ def test_hash_probe_ref_traceable():
 
     @jax.jit
     def f(p):
-        return hash_probe_ref(sk, si, (p,), built["max_probes"])
+        return hash_probe_ref(sk, si, (p,), built["max_probes"],
+                              built["base"])
 
     idx, found = f(jnp.asarray(probes))
     oi, of = _probe_oracle(keys[:, None], probes[:, None])
     np.testing.assert_array_equal(np.asarray(found), of)
     np.testing.assert_array_equal(np.asarray(idx)[of], oi[of])
+
+
+def _ssb_dates():
+    """yyyymmdd of every day of 1992-1998: 2,557 keys over 61,131 values,
+    a direct table of 65,536 slots, 8x the fmix32 table's 8,192."""
+    days = np.arange("1992-01-01", "1999-01-01", dtype="datetime64[D]")
+    year = days.astype("datetime64[Y]").astype(np.int64) + 1970
+    month = days.astype("datetime64[M]").astype(np.int64) % 12 + 1
+    dom = (days - days.astype("datetime64[M]")).astype(np.int64) + 1
+    return year * 10_000 + month * 100 + dom
+
+
+_BASE_CASES = {
+    "below_min": (np.arange(100, 400), np.arange(-50, 110)),
+    "above_max": (np.arange(100, 400), np.arange(390, 2_000)),
+    "gaps": (np.sort(RNG.choice(4_000, size=2_500, replace=False)),
+             np.arange(-10, 4_100)),
+    "duplicates": (np.sort(np.r_[np.arange(10, 90), np.arange(10, 90, 3),
+                                 np.arange(40, 50)]), np.arange(0, 100)),
+    "negative_base": (np.arange(-500, 200, 2), np.arange(-700, 400)),
+    "one_key": (np.array([42]), np.arange(-40, 120)),
+    "date_edge": (_ssb_dates(), np.r_[_ssb_dates(),
+                                      RNG.integers(19_911_201, 19_990_201,
+                                                   5_000)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BASE_CASES))
+@pytest.mark.parametrize("dtype", [np.int64, np.int32])
+def test_hash_probe_base_addressed_matches_the_oracle(case, dtype):
+    """A direct table (slot ``key - base``) answers as the first-occurrence
+    oracle in one pass: keys below and above the range, gaps inside it,
+    duplicates, a negative base, one key, and SSB's dates at the 8x edge."""
+    keys, probes = (a.astype(dtype) for a in _BASE_CASES[case])
+    built = hash_build((keys,))
+    assert built["base"] == keys.min() and built["max_probes"] == 1
+    oi, of = _probe_oracle(keys[:, None], probes[:, None])
+    idx, found = _probe(built, (probes,))
+    idx, found = np.asarray(idx), np.asarray(found)
+    np.testing.assert_array_equal(found, of)
+    np.testing.assert_array_equal(idx[of], oi[of])
+    np.testing.assert_array_equal(idx[~of], 0)
+    np.testing.assert_array_equal(probe_lengths_np(built, (probes,)), 1)
+
+
+@pytest.mark.parametrize("key_cols,direct", [
+    # 100 keys: the fmix32 table has 256 slots, so a span of 2,048 is 8x
+    ((np.r_[0, RNG.choice(np.arange(1, 2_047), 98, replace=False), 2_047],),
+     True),
+    ((np.r_[0, RNG.choice(np.arange(1, 2_048), 98, replace=False), 2_048],),
+     False),
+    ((_ssb_dates(),), True),
+    ((np.arange(300), np.arange(300)), False),        # two key columns
+    ((np.arange(300).astype(np.float64),), False),    # not integers
+    ((np.zeros(0, np.int64),), False),                # no keys
+])
+def test_hash_build_picks_the_base_by_the_8x_rule(key_cols, direct):
+    """Direct addressing exactly where ``next_pow2(span)`` is at most 8x
+    the fmix32 table's ``next_pow2(2d)``, for one integer key column."""
+    built = hash_build(key_cols)
+    assert (built["base"] is not None) == direct
+    if direct:
+        (k,) = key_cols
+        assert built["base"] == k.min()
+        assert built["table_size"] == 1 << (int(k.max() - k.min())
+                                            ).bit_length()
+
+
+def _fmix32_placement(key_cols):
+    """The fmix32 table as a plain loop places it: rounds of one probe
+    distance, rows in index order, the lowest row winning a free slot, a
+    row dropped where its slot holds its own key (keep-first)."""
+    rows = list(zip(*(np.asarray(k).tolist() for k in key_cols)))
+    d = len(rows)
+    size = 16
+    while size < 2 * d:
+        size *= 2
+    home = hash_keys_np(key_cols).astype(np.int64)
+    slot_idx = np.full(size, -1, np.int32)
+    live, step, lengths = list(range(d)), 0, []
+    while live:
+        claimed = {}
+        for i in live:
+            t = int(home[i] + step) & (size - 1)
+            if slot_idx[t] < 0 and t not in claimed:
+                claimed[t] = i
+        for t, i in claimed.items():
+            slot_idx[t] = i
+            lengths.append(step + 1)
+        placed = set(claimed.values())
+        live = [i for i in live if i not in placed
+                and rows[slot_idx[int(home[i] + step) & (size - 1)]] != rows[i]]
+        step += 1
+    full = slot_idx >= 0
+    slot_keys = []
+    for k in key_cols:
+        sk = np.zeros(size, np.asarray(k).dtype)
+        sk[full] = np.asarray(k)[slot_idx[full]]
+        slot_keys.append(sk)
+    occ = np.r_[slot_idx >= 0, slot_idx >= 0]
+    run = longest = 0
+    for o in occ:
+        run = run + 1 if o else 0
+        longest = max(longest, run)
+    return {"slot_keys": tuple(slot_keys), "slot_idx": slot_idx,
+            "table_size": size,
+            "max_probes": min(longest, size) + 1,
+            "mean_probes": float(np.mean(lengths)) if lengths else 0.0,
+            "base": None}
+
+
+@pytest.mark.parametrize("key_cols", [
+    (RNG.choice(100_000, size=997, replace=False).astype(np.int64),),
+    (np.r_[RNG.choice(50_000, size=300), np.arange(0, 50_000, 500)
+           ].astype(np.int32),),                      # sparse, duplicates
+    (RNG.integers(0, 40, 600), RNG.integers(0, 40, 600)),   # two columns
+    (np.zeros(0, np.int64),),
+])
+def test_hash_build_fmix32_tables_are_placed_as_before(key_cols):
+    """Sparse and multi-column keys keep the fmix32 table, slot for slot,
+    as the ``hash_keys_np`` placement gives it."""
+    built = hash_build(key_cols)
+    want = _fmix32_placement(key_cols)
+    assert set(built) == set(want)
+    for k in ("table_size", "max_probes", "base"):
+        assert built[k] == want[k], k
+    assert built["mean_probes"] == pytest.approx(want["mean_probes"])
+    np.testing.assert_array_equal(built["slot_idx"], want["slot_idx"])
+    for got, exp in zip(built["slot_keys"], want["slot_keys"]):
+        assert got.dtype == exp.dtype
+        np.testing.assert_array_equal(got, exp)
 
 
 # -------------------------------------------------------------- radix groupby

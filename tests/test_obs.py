@@ -1,5 +1,6 @@
 """Observability: tracer scoping, metric reconciliation, Perfetto export,
 the attribution report, and the zero-cost disabled path."""
+import dataclasses
 import json
 
 import numpy as np
@@ -413,8 +414,7 @@ Q41_SEGMENT = ["lookup_customer", "lookup_supplier", "lookup_part",
                "lookup_date", "filter_unmatched", "project", "profit_expr"]
 
 
-@pytest.fixture(scope="module")
-def q41_runner(data):
+def _q41_runner(data):
     pytest.importorskip("jax")
     from repro.core.backend.jax_backend import JaxBackend
     from repro.etl.components import FusedSegment
@@ -422,6 +422,23 @@ def q41_runner(data):
     seg = FusedSegment.from_components(
         [qf.flow.component(m) for m in Q41_SEGMENT])
     return JaxBackend().compile_segment(seg)
+
+
+@pytest.fixture(scope="module")
+def q41_runner(data):
+    return _q41_runner(data)
+
+
+#: spreads the part keys (in the part table and the fact rows alike) so
+#: far apart that the part table falls back to fmix32
+SPARSE_PART = 1009
+
+
+def _sparse_part(data):
+    part = dict(data.part, p_partkey=data.part["p_partkey"] * SPARSE_PART)
+    lo = dict(data.lineorder,
+              lo_partkey=data.lineorder["lo_partkey"] * SPARSE_PART)
+    return dataclasses.replace(data, part=part, lineorder=lo)
 
 
 def _segment_call(runner, data, traced: bool):
@@ -435,7 +452,17 @@ def _segment_call(runner, data, traced: bool):
     return tr.events
 
 
-def test_fused_q41_scope_map_names_each_lookups_probe(q41_runner, data):
+@pytest.mark.parametrize("part_keys", ["dense", "sparse"])
+def test_fused_q41_scope_map_names_each_lookups_probe(q41_runner, data,
+                                                      part_keys):
+    """The compiled segment's top-level ops carry their scopes.  An fmix32
+    table's probe is a ``while`` under ``lookup.<dim>/probe``; a direct
+    table's one pass has no loop (on the CPU it fuses into the Lookup's
+    gathers; the v5e compile keeps probe ops of its own,
+    ``tests/test_tpu_compile.py``)."""
+    if part_keys == "sparse":
+        data = _sparse_part(data)
+        q41_runner = _q41_runner(data)
     _segment_call(q41_runner, data, traced=False)
     assert q41_runner._scope_maps == {}      # built only while traced
     events = _segment_call(q41_runner, data, traced=True)
@@ -445,10 +472,9 @@ def test_fused_q41_scope_map_names_each_lookups_probe(q41_runner, data):
     assert args["program"] == "jit__kernel"
     assert args["layout"].startswith("8192:lo_custkey,")
     scopes = set(args["ops"].values())
-    for dim in ("customer", "supplier", "part", "date"):
-        probe = [op for op, s in args["ops"].items()
-                 if s == f"lookup.{dim}/probe"]
-        assert any(op.startswith("%while") for op in probe), dim
+    loops = {s for op, s in args["ops"].items() if op.startswith("%while")}
+    assert loops == ({"lookup.part/probe"} if part_keys == "sparse"
+                     else set())
     # XLA may fuse a gather into the filter: some stay top-level ops
     assert {"lookup.customer/gather", "filter.0", "expr.profit"} <= scopes
     # the map is built once per layout and reused by the next call
@@ -475,7 +501,14 @@ def test_probe_need_is_the_walk_of_every_row(q41_runner, keys, vals):
     assert (table["lengths"][1] is None) == (len(keys) != 300)
 
 
-def test_fused_q41_counts_probe_passes_per_lookup(q41_runner, data):
+@pytest.mark.parametrize("part_keys", ["dense", "sparse"])
+def test_fused_q41_counts_probe_passes_per_lookup(q41_runner, data,
+                                                  part_keys):
+    """SSB's dense keys make every table direct: one pass a row, and each
+    row needs it.  A sparse part table keeps fmix32 and its walk."""
+    if part_keys == "sparse":
+        data = _sparse_part(data)
+        q41_runner = _q41_runner(data)
     events = _segment_call(q41_runner, data, traced=True)
     probes = {e["name"]: e["args"] for e in events
               if (e["ph"], e["cat"]) == ("C", "probe")}
@@ -485,8 +518,14 @@ def test_fused_q41_counts_probe_passes_per_lookup(q41_runner, data):
     need = probe_lengths_np(table, (data.lineorder["lo_partkey"],)).sum()
     assert probes["part"] == {
         "rows": 5000, "padded_rows": 8192, "passes": table["max_probes"],
+        "direct": int(part_keys == "dense"),
         "mean_probes": table["mean_probes"], "slots": table["table_size"],
         "need": need}
+    for name, p in probes.items():
+        if name == "part" and part_keys == "sparse":
+            assert p["passes"] > 1 and p["rows"] < p["need"]
+        else:
+            assert (p["passes"], p["direct"], p["need"]) == (1, 1, 5000)
     # each row of each Lookup needs at least one pass, at most all
     for p in probes.values():
         assert p["rows"] <= p["need"] <= p["rows"] * p["passes"]

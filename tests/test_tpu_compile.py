@@ -5,12 +5,13 @@ a kernel that ``auto`` picks on a TPU, at the largest shapes the SF1 chip
 smoke (``chip_smoke.py``) feeds it:
 
 * the Lookup probe route (XLA, ``hash_probe_ref``) over the 200k-row part
-  table (T = 2^19 slots) at 2^21 probe rows;
+  table at 2^21 probe rows: direct (T = 2^18 slots, one pass) and fmix32
+  (T = 2^19 slots, the table sparse part keys would give);
 * ``radix_groupby_pallas`` at the serving batch's 2^21 rows and at Q2.1's
   7000 dense cells;
 * ``segment_sum_pallas`` at Q1.1's 2^17 rows;
 * one fused Q4.1 segment kernel over SF1 dimension tables at a 2^21-row
-  chunk bucket, with one top-level probe loop under each Lookup's scope.
+  chunk bucket, with each Lookup's one-pass probe under its scope.
 
 A compile that passes is not a chip run: nothing here executes.  The
 topology is described inside a fixture (only the worker that runs these
@@ -64,12 +65,17 @@ def _fits(compiled) -> None:
     assert total < V5E_HBM_BYTES, f"{total} bytes do not fit one v5e chip"
 
 
-def test_probe_route_compiles_at_sf1_part_table(one_chip):
+@pytest.mark.parametrize("T,max_probes,base", [
+    (1 << 18, 1, 1),           # direct: part keys 1..200,000
+    (1 << 19, 32, None),       # fmix32
+])
+def test_probe_route_compiles_at_sf1_part_table(one_chip, T, max_probes,
+                                                base):
     from repro.kernels.hash_join import hash_probe
-    T, N = 1 << 19, 1 << 21
+    N = 1 << 21
 
     def probe(slot_keys, slot_idx, vals):
-        return hash_probe((slot_keys,), slot_idx, (vals,), 32)
+        return hash_probe((slot_keys,), slot_idx, (vals,), max_probes, base)
 
     compiled = jax.jit(probe).lower(
         _spec(one_chip, (T,), jnp.int32), _spec(one_chip, (T,), jnp.int32),
@@ -122,7 +128,7 @@ def fused_q41(one_chip):
         bucket, [(c, data.lineorder[c].dtype) for c in sorted(runner.inputs)])
     dims = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
                         runner.device_dims())
-    assert max(int(d["slot_idx"].shape[0]) for d in dims) == 1 << 19
+    assert max(int(d["slot_idx"].shape[0]) for d in dims) == 1 << 18
     return runner._jit.lower(
         (bucket, tuple(entries)), _spec(one_chip, (total,), jnp.uint8), {},
         dims).compile()
@@ -133,16 +139,12 @@ def test_fused_q41_segment_compiles_at_sf1(fused_q41):
 
 
 def test_fused_q41_segment_names_one_probe_loop_per_lookup(fused_q41):
-    """On the chip's compiler too, each Lookup's probe is one top-level
-    ``while`` under ``lookup.<dim>/probe``: the op the profiler's device
-    events name."""
+    """On the chip's compiler, each Lookup's probe over its direct SF1
+    table is one pass with no loop, and has top-level ops under
+    ``lookup.<dim>/probe``: the ops the profiler's device events name."""
     from repro.obs.trace import entry_scopes
     program, ops = entry_scopes(fused_q41.as_text())
     assert program == "jit__kernel"
-    loops = {}
-    for op, scope in ops.items():
-        if op.startswith("%while"):
-            loops.setdefault(scope, []).append(op)
-    assert sorted(loops) == [f"lookup.{d}/probe" for d in
-                             ("customer", "date", "part", "supplier")]
-    assert all(len(v) == 1 for v in loops.values())
+    assert not [op for op in ops if op.startswith("%while")]
+    assert {s for s in ops.values() if s.endswith("/probe")} == {
+        f"lookup.{d}/probe" for d in ("customer", "date", "part", "supplier")}
