@@ -17,7 +17,10 @@ Kernels:
     sparse/non-integer/huge key spaces fall back to the legacy lexsort +
     ``kernels/segment_sum`` route (``REPRO_GROUPBY_IMPL=sort`` forces it;
     ``REPRO_SEGSUM_IMPL=interpret`` exercises the Pallas segment-sum body on
-    CPU).  Sums accumulate in float32 — the MXU-native width — so
+    CPU).  Integer sums and averages are exact: their inputs split into
+    8-bit limbs summed in int32 in the same kernels' programs
+    (``kernels/radix_groupby/exact.py``) and recombined on the host in
+    int64.  Float sums accumulate in float32 — the MXU-native width — so
     engine-vs-oracle checks use ``oracle_rtol`` instead of float64 exactness.
   - ``filter_mask`` / ``eval_expression`` — user lambdas evaluated over a
     device view of the shared cache, so `c.col(...)` hands back jax arrays
@@ -30,7 +33,11 @@ analogue of the paper's §3 scheme for the device tier.
 
 Note: x64 stays disabled (jax default), so 64-bit host columns are
 canonicalized to 32-bit on device; ``dtype_width`` reports the canonical
-width so planner channel sizing matches what actually crosses an edge.
+width so planner channel sizing matches what actually crosses an edge.  A
+host integer column whose values do not fit raises ``IntRangeError``
+naming it, at upload or pack, rather than wrap; an integer expression that
+can leave int32 (bounded from its inputs' observed ranges) is computed in
+``core.wideint``'s two-word form and kept as a ``WideColumn``.
 """
 from __future__ import annotations
 
@@ -44,8 +51,9 @@ from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 import numpy as np
 
 from ...obs import trace as obs_trace
-from .. import config, faults
+from .. import config, faults, wideint
 from ..expr import ColumnsView, Expr
+from ..wideint import Wide, WideColumn
 from ..shared_cache import (GLOBAL_ARENA, is_host_column, record_dim_upload,
                             record_segment_compile, record_transfer)
 from .base import AGG_OPS, Backend, SegmentEnv
@@ -80,6 +88,15 @@ def place_compile_cache(jax) -> None:
     compilation_cache.reset_cache()
 
 
+def _narrow(col):
+    """``col`` for a 32-bit device op: a ``WideColumn`` is refused."""
+    if isinstance(col, WideColumn):
+        raise NotImplementedError(
+            f"min and max of a wide integer column ({col.bound[0]} to "
+            f"{col.bound[1]}) are not supported on the device")
+    return col
+
+
 class _DeviceCacheView:
     """Read-only view of a SharedCache whose ``col`` returns device arrays
     (converted+cached on first touch), so user predicates/expressions written
@@ -110,7 +127,7 @@ class _DeviceCacheView:
                 got = self._cols.get(name)
                 if got is None:
                     got = self._cols[name] = self._backend.asarray(
-                        self._cache.col(name))
+                        self._cache.col(name), name=name)
         return got
 
     def __getattr__(self, name):
@@ -231,8 +248,11 @@ class JaxBackend(Backend):
             return view
 
     # ------------------------------------------------------------ array ops
-    def asarray(self, x):
+    def asarray(self, x, name: Optional[str] = None):
+        """``x`` on the device.  A host integer column narrowed to 32 bits
+        there must fit: else ``IntRangeError`` names it (``name``)."""
         if isinstance(x, np.ndarray):
+            self._check_fits(x, name)
             # copy=True: jax on CPU zero-copies numpy arrays onto the
             # "device", aliasing the host memory — with CacheArena recycling
             # host buffers, an aliased device column would silently observe
@@ -243,9 +263,32 @@ class JaxBackend(Backend):
                 out = self._jnp.array(x, copy=True)
             record_transfer("h2d", x.nbytes, seconds=a.seconds)
             return out
-        if isinstance(x, self._jax.Array):
+        if isinstance(x, (self._jax.Array, WideColumn)):
             return x
         return self._jnp.asarray(x)
+
+    def _check_fits(self, x: np.ndarray, name: Optional[str]) -> None:
+        cd = np.dtype(self._jax.dtypes.canonicalize_dtype(x.dtype))
+        if (x.size and np.issubdtype(x.dtype, np.integer)
+                and cd.itemsize < x.dtype.itemsize):
+            wideint.check_fits(name or "<unnamed>", cd,
+                               *wideint.column_range(x))
+
+    def _ranges(self, arrays: Sequence) -> List[Tuple[int, int]]:
+        """``(min, max)`` of each integer device array, in one d2h."""
+        if not arrays:
+            return []
+        jnp, lax = self._jnp, self._jax.lax
+        rows = []
+        for a in arrays:
+            mm = (jnp.stack([jnp.min(a), jnp.max(a)]) if a.size
+                  else jnp.zeros((2,), a.dtype))
+            rows.append(lax.bitcast_convert_type(mm, jnp.int32)
+                        if a.dtype == jnp.uint32 else mm.astype(jnp.int32))
+        host = self.to_host(jnp.stack(rows))
+        return [tuple(int(v) for v in (h.view(np.uint32)
+                                       if a.dtype == jnp.uint32 else h))
+                for a, h in zip(arrays, host)]
 
     def to_host(self, x) -> np.ndarray:
         if isinstance(x, np.ndarray):
@@ -259,7 +302,18 @@ class JaxBackend(Backend):
         parts = list(parts)
         if len(parts) == 1:
             return self.asarray(parts[0])
+        if any(isinstance(p, WideColumn) for p in parts):
+            return WideColumn.concat([self._widen(p) for p in parts])
         return self._jnp.concatenate([self.asarray(p) for p in parts])
+
+    def _widen(self, col) -> WideColumn:
+        """An integer column as a ``WideColumn`` on the device."""
+        if isinstance(col, WideColumn):
+            return col
+        if isinstance(col, np.ndarray):
+            return wideint.from_host(col)
+        w = wideint.widen(col)
+        return WideColumn(w.lo, w.hi, self._ranges([col])[0])
 
     # --------------------------------------------------------------- sizing
     def dtype_width(self, dtype) -> int:
@@ -366,18 +420,72 @@ class JaxBackend(Backend):
         the backend's batch alignment so jit sees bucketed shapes — without
         this, every post-filter chunk (data-dependent length) would force a
         fresh XLA compile.  Safe because DSL ops are row-local: the zeroed
-        pad rows are sliced off before anyone observes them."""
+        pad rows are sliced off before anyone observes them.  Where a node
+        can leave int32 (``wideint.plan`` over the inputs' observed
+        ranges), the expression runs in the wide form instead."""
         jnp = self._jnp
         names, fn = self._expr_runner(expr)
         view = self._view(cache)
         cols = [view.col(name)[rows] for name in names]
-        n = cols[0].shape[0]
+        n = len(cols[0])
+        bound, flags, _ = self._expr_plan(
+            expr, names, [cache.col(name)[rows] for name in names], cols)
+        if flags is not None:
+            return self._eval_wide(expr, names, cols, flags, bound, n)
         pad = self.bucket_rows(n) - n
         if pad:
             cols = [jnp.concatenate(
                 [c, jnp.zeros((pad,) + c.shape[1:], c.dtype)]) for c in cols]
         out = fn(*cols)
         return out[:n] if pad else out
+
+    def _expr_plan(self, expr: Expr, names, sources, cols) -> wideint.Plan:
+        """``wideint.plan`` of ``expr`` over the observed ranges of its
+        input columns: ``sources`` as the cache holds them (host ranges
+        on the host, one d2h for the device ones), ``cols`` on the
+        device."""
+        ranges, dev = {}, []
+        for name, src, c in zip(names, sources, cols):
+            if isinstance(c, WideColumn):
+                ranges[name] = c.bound
+            elif is_host_column(src):
+                r = wideint.column_range(src)
+                if r is not None:
+                    ranges[name] = r
+            elif c.dtype == np.bool_:
+                ranges[name] = (0, 1)
+            elif c.ndim == 1 and self._jnp.issubdtype(c.dtype,
+                                                      self._jnp.integer):
+                dev.append((name, c))
+        ranges.update(zip([name for name, _ in dev],
+                          self._ranges([c for _, c in dev])))
+        return wideint.plan(expr, ranges, [
+            name for name, c in zip(names, cols)
+            if isinstance(c, WideColumn)])
+
+    def _eval_wide(self, expr: Expr, names, cols, flags, bound, n: int):
+        jnp = self._jnp
+        wide_in = tuple(isinstance(c, WideColumn) for c in cols)
+        key = (flags, wide_in)
+        runners = expr.__dict__.setdefault("_jax_wide", {})
+        fn = runners.get(key)
+        if fn is None:
+            def run(*arrays):
+                return wideint.evaluate(
+                    expr, ColumnsView(dict(zip(names, arrays))),
+                    slice(None), flags)
+            fn = runners[key] = self._jax.jit(run)
+        pad = self.bucket_rows(n) - n
+
+        def padded(a):
+            return (jnp.concatenate([a, jnp.zeros((pad,), a.dtype)])
+                    if pad else a)
+        out = fn(*[Wide(padded(c.lo), padded(c.hi))
+                   if isinstance(c, WideColumn) else padded(c)
+                   for c in cols])
+        if isinstance(out, Wide):
+            return WideColumn(out.lo[:n], out.hi[:n], bound)
+        return out[:n]
 
     # ------------------------------------------------------- operator kernels
     def filter_mask(self, predicate: Callable, cache, rows: slice):
@@ -443,21 +551,28 @@ class JaxBackend(Backend):
         n = int(n_rows)
         if not keys:
             aggs: Dict[str, object] = {}
-            zeros = jnp.zeros((n,), dtype=jnp.int32)
+            cols, pos = self._exact_inputs(values)
+            if cols:
+                exact = self._exact_keyless(cols, n)
             for out, (col, op) in values.items():
                 if op == "count":
                     aggs[out] = np.array([n], dtype=np.int64)
                     continue
+                if out in pos:
+                    s = exact[pos[out]]
+                    aggs[out] = self._avg(s, [n]) if op == "avg" else s
+                    continue
                 vals = self.asarray(col)
                 if op in ("sum", "avg"):
+                    zeros = jnp.zeros((n,), dtype=jnp.int32)
                     s = self._segment_sum(zeros,
                                           vals.astype(jnp.float32)[:, None],
                                           1, impl=self._segsum_impl)[:, 0]
                     aggs[out] = self._avg(s, [n]) if op == "avg" else s
                 elif op == "min":
-                    aggs[out] = jnp.min(vals)[None]
+                    aggs[out] = jnp.min(_narrow(vals))[None]
                 elif op == "max":
-                    aggs[out] = jnp.max(vals)[None]
+                    aggs[out] = jnp.max(_narrow(vals))[None]
             return [], aggs
         keys_d = [self.asarray(k) for k in keys]
         impl = self._resolve_impl("groupby",
@@ -487,12 +602,23 @@ class JaxBackend(Backend):
         counts_h = np.diff(np.append(starts_h, n))
         starts = jnp.asarray(starts_h)
         group_cols = [k[starts] for k in sk]
+        cols, pos = self._exact_inputs(values)
+        if cols:
+            cols = [c[order] for c in cols]
+            _, _, exact = self._exact(
+                self._segment_sum, seg, jnp.zeros((n, 0), jnp.float32),
+                n_groups, cols, self._value_ranges(cols, []),
+                impl=self._segsum_impl)
         aggs = {}
         for out, (col, op) in values.items():
             if op == "count":
                 aggs[out] = counts_h.astype(np.int64)
                 continue
-            vals = self.asarray(col)[order]
+            if out in pos:
+                s = exact[pos[out]]
+                aggs[out] = self._avg(s, counts_h) if op == "avg" else s
+                continue
+            vals = _narrow(self.asarray(col))[order]
             if op in ("sum", "avg"):
                 # the repo's Pallas segment-sum op: one-hot matmul per row
                 # tile on TPU, jnp segment_sum reference on CPU
@@ -507,11 +633,75 @@ class JaxBackend(Backend):
                                                       num_segments=n_groups)
         return group_cols, aggs
 
+    def _exact_inputs(self, values: Mapping[str, Tuple[object, str]]
+                      ) -> Tuple[list, Dict[str, int]]:
+        """The distinct integer columns (by identity) that ``sum``/``avg``
+        outputs read, on the device, and each such output's position among
+        them: these are summed exactly."""
+        cols: list = []
+        seen: Dict[int, int] = {}
+        pos: Dict[str, int] = {}
+        for out, (col, op) in values.items():
+            if op not in ("sum", "avg"):
+                continue
+            if id(col) not in seen:
+                if not (isinstance(col, WideColumn)
+                        or np.issubdtype(col.dtype, np.integer)):
+                    continue
+                seen[id(col)] = len(cols)
+                cols.append(self.asarray(col))
+            pos[out] = seen[id(col)]
+        return cols, pos
+
+    def _value_ranges(self, cols: list, ranges: List[Tuple[int, int]]
+                      ) -> List[Tuple[int, int]]:
+        """Each column's range: a ``WideColumn``'s bound, else the next of
+        ``ranges`` (observed), or of one d2h where ``ranges`` is empty."""
+        narrow = [c for c in cols if not isinstance(c, WideColumn)]
+        it = iter(ranges or self._ranges(narrow))
+        return [c.bound if isinstance(c, WideColumn) else next(it)
+                for c in cols]
+
+    def _exact(self, kernel, ids, vmat, n_groups: int, cols: list,
+               ranges: List[Tuple[int, int]], **kw):
+        """One group-by kernel call that also sums the integer ``cols``
+        exactly, each less its minimum in 8-bit limbs: ``(float sums,
+        counts, [exact int64 sums per column])``, the last two on the host
+        (one d2h)."""
+        offsets = [lo for lo, _ in ranges]
+        limbs = tuple(wideint.limb_count(r) for r in ranges)
+        ints = tuple((c.wide if isinstance(c, WideColumn) else c,
+                      wideint.const(off)) for c, off in zip(cols, offsets))
+        sums, counts, exact = kernel(ids, vmat, n_groups, ints=ints,
+                                     limbs=limbs, **kw)
+        host = self.to_host(self._jnp.concatenate(
+            [counts[..., None], exact], axis=-1))
+        if obs_trace.ACTIVE.get():
+            obs_trace.counter(
+                "exact", "groupby", rows=int(ids.shape[0]), columns=len(cols),
+                limbs=sum(limbs),
+                max_bits=max((hi - lo).bit_length() for lo, hi in ranges))
+        counts_h = host[..., 0].astype(np.int64).sum(axis=0)
+        return sums, counts_h, [
+            wideint.exact_result(t) for t in wideint.recombine(
+                host[..., 1:], host[..., 0], offsets, limbs)]
+
+    def _exact_keyless(self, cols: list, n: int) -> List[np.ndarray]:
+        """Exact int64 totals of the integer ``cols`` over all ``n``
+        rows."""
+        jnp = self._jnp
+        _, _, exact = self._exact(
+            self._segment_sum, jnp.zeros((n,), jnp.int32),
+            jnp.zeros((n, 0), jnp.float32), 1, cols,
+            self._value_ranges(cols, []), impl=self._segsum_impl)
+        return exact
+
     def _avg(self, sums, counts) -> np.ndarray:
         """``sums / counts`` divided on the host, where IEEE division rounds
-        once in the sum's dtype.  The TPU's float32 divide is not correctly
-        rounded, and serving emits and shard merges divide on the host, so
-        this keeps every route's averages bit-identical."""
+        once in the sum's dtype (float64 for the exact int64 sums).  The
+        TPU's float32 divide is not correctly rounded, and serving emits and
+        shard merges divide on the host, so this keeps every route's
+        averages bit-identical."""
         s = self.to_host(sums)
         return s / np.asarray(counts).astype(s.dtype)
 
@@ -522,11 +712,14 @@ class JaxBackend(Backend):
         Each key column is offset to zero and the tuple is flattened into one
         dense int32 id (FIRST key column most significant, so ascending id
         order IS the lexicographic group order the sort route emits).  All
-        sum/avg inputs stack into one [N, C] matrix and reduce in a single
-        ``kernels/radix_groupby`` pass that also yields per-group counts;
-        occupied cells are recovered from the counts (the only extra d2h) and
-        group key columns are reconstructed arithmetically from the cell ids —
-        the row data is never sorted and never leaves the device.
+        float sum/avg inputs stack into one [N, C] matrix and reduce in a
+        single ``kernels/radix_groupby`` pass that also yields per-group
+        counts; integer inputs are summed exactly in the same program, each
+        distinct column once, offset by its minimum (the keys' min/max d2h
+        carries the values' too).  Occupied cells are recovered from the
+        counts (the only extra d2h) and group key columns are reconstructed
+        arithmetically from the cell ids — the row data is never sorted and
+        never leaves the device.
 
         Returns ``None`` when the key space doesn't qualify (empty input,
         non-integer keys, cell count past the VMEM-scaled bound, row count
@@ -539,11 +732,12 @@ class JaxBackend(Backend):
         for k in keys_d:
             if not jnp.issubdtype(k.dtype, jnp.integer):
                 return None
-        # one d2h for every column's min/max (stacked into a single transfer)
-        lo_hi = self.to_host(jnp.stack(
-            [jnp.stack([jnp.min(k), jnp.max(k)]) for k in keys_d]))
-        mins = [int(v) for v in lo_hi[:, 0]]
-        ranges = [int(hi) - int(lo) + 1 for lo, hi in lo_hi]
+        cols, pos = self._exact_inputs(values)
+        # one d2h for every key's and integer input's min/max
+        narrow = [c for c in cols if not isinstance(c, WideColumn)]
+        lo_hi = self._ranges(keys_d + narrow)
+        mins = [lo for lo, _ in lo_hi[:len(keys_d)]]
+        ranges = [hi - lo + 1 for lo, hi in lo_hi[:len(keys_d)]]
         cells = 1
         for r in ranges:
             cells *= r
@@ -557,13 +751,18 @@ class JaxBackend(Backend):
             ids = ids + (k.astype(jnp.int32) - mn) * st
 
         sum_outs = [out for out, (_, op) in values.items()
-                    if op in ("sum", "avg")]
+                    if op in ("sum", "avg") and out not in pos]
         mat = [self.asarray(values[out][0]).astype(jnp.float32)
                for out in sum_outs]
         vmat = (jnp.stack(mat, axis=1) if mat
                 else jnp.zeros((n, 0), jnp.float32))
-        sums, counts = self._radix_groupby(ids, vmat, cells, impl=impl)
-        counts_h = np.rint(self.to_host(counts)).astype(np.int64)  # one d2h
+        if cols:
+            sums, counts_h, exact = self._exact(
+                self._radix_groupby, ids, vmat, cells, cols,
+                self._value_ranges(cols, lo_hi[len(keys_d):]), impl=impl)
+        else:
+            sums, counts = self._radix_groupby(ids, vmat, cells, impl=impl)
+            counts_h = np.rint(self.to_host(counts)).astype(np.int64)  # d2h
         occ = np.flatnonzero(counts_h)
         occ_d = jnp.asarray(occ.astype(np.int32))
         group_cols = [((occ_d // st) % rg + mn).astype(k.dtype)
@@ -572,13 +771,16 @@ class JaxBackend(Backend):
         for out, (col, op) in values.items():
             if op == "count":
                 aggs[out] = counts_h[occ]
+            elif out in pos:
+                s = exact[pos[out]][occ]
+                aggs[out] = self._avg(s, counts_h[occ]) if op == "avg" else s
             elif op in ("sum", "avg"):
                 s = sums[occ_d, sum_outs.index(out)]
                 aggs[out] = self._avg(s, counts_h[occ]) if op == "avg" else s
             else:  # min / max: one segment reduce over the dense ids
                 fn = (self._jax.ops.segment_min if op == "min"
                       else self._jax.ops.segment_max)
-                aggs[out] = fn(self.asarray(col), ids,
+                aggs[out] = fn(_narrow(self.asarray(col)), ids,
                                num_segments=cells)[occ_d]
         return group_cols, aggs
 
@@ -625,6 +827,15 @@ class _JaxSegmentRunner:
         #: terminal Aggregate, skip the per-chunk compact (the chunk's only
         #: d2h) and hand the keep-mask downstream as a sentinel column
         self.defer_mask = bool(getattr(segment, "defer_cols", None))
+        #: the terminal Aggregate's input columns: all a deferring segment
+        #: hands on (its only consumer drops every other column)
+        self._defer_cols = frozenset(getattr(segment, "defer_cols", None)
+                                     or ())
+        #: columns the kernel returns: those it writes, and the Aggregate's
+        #: inputs it only reads, already on the device (so the Aggregate
+        #: merges no host copy of them and uploads none again)
+        self._outputs = self._written + sorted(
+            self._defer_cols - set(self._written))
         #: Lookup route inside the fused kernel: hash-probe (traced inline
         #: via hash_probe_ref — it fuses into the one XLA computation) unless
         #: pinned back to the legacy binary search
@@ -637,12 +848,20 @@ class _JaxSegmentRunner:
         self._lookup_names = [op[1].name or op[2] for op in self.ops
                               if op[0] == "lookup"]
         self._lookup_keys = [op[2] for op in self.ops if op[0] == "lookup"]
+        #: columns the segment's DSL filters and expressions read
+        self._expr_reads = frozenset().union(*(
+            fn.columns() for fn in (op[1] if op[0] == "filter" else op[2]
+                                    for op in self.ops
+                                    if op[0] in ("filter", "expr"))
+            if isinstance(fn, Expr)))
         self._jit = backend._jax.jit(self._kernel, static_argnums=(0,))
         self._layouts: set = set()
         #: per layout, ``(program, layout key, {ENTRY op: named scope})`` of
         #: the compiled kernel; built only while a tracer is in scope
         self._scope_maps: Dict[tuple, tuple] = {}
         self._dims = None            # built once: stable per (segment, backend)
+        #: (DimTable id, payload column) -> the payload's observed range
+        self._payload_ranges: Dict[tuple, Optional[Tuple[int, int]]] = {}
         self.kernel_calls = 0
 
     # ----------------------------------------------------------- the kernel
@@ -652,7 +871,9 @@ class _JaxSegmentRunner:
         # ops can be traced back to the Lookup, filter or expression
         jnp = self._jnp
         scope = self._jax.named_scope
-        bucket, entries = layout
+        bucket, entries = layout[:2]
+        # the ops with a node past int32 (``_wide_plan``): preorder flags
+        wide = dict(layout[2][0]) if len(layout) > 2 else {}
         env: Dict[str, object] = {}
         with scope("unpack"):
             for (name, dtype_str, off) in entries:
@@ -671,15 +892,27 @@ class _JaxSegmentRunner:
         masks = []
         dim_i = 0
         rows = slice(None)
-        for op in self.ops:
+        for i, op in enumerate(self.ops):
             view = SegmentEnv(env.__getitem__, set(env), bucket)
             kind = op[0]
             if kind == "filter":
-                with scope(f"filter.{len(masks)}"):
-                    masks.append(jnp.asarray(op[1](view, rows), dtype=bool))
+                name = f"filter.{len(masks)}"
+                with scope(name):
+                    if i in wide:
+                        with scope(f"wide.{name}"):
+                            m = wideint.evaluate(op[1], view, rows, wide[i])
+                    else:
+                        m = op[1](view, rows)
+                    masks.append(jnp.asarray(m, dtype=bool))
             elif kind == "expr":
                 with scope(f"expr.{op[1]}"):
-                    env[op[1]] = jnp.asarray(op[2](view, rows))
+                    if i in wide:
+                        with scope(f"wide.{op[1]}"):
+                            v = wideint.evaluate(op[2], view, rows, wide[i])
+                        env[op[1]] = v if isinstance(v, Wide) \
+                            else jnp.asarray(v)
+                    else:
+                        env[op[1]] = jnp.asarray(op[2](view, rows))
             elif kind == "lookup":
                 _, _, key_col, return_cols, default, matched_flag = op
                 d = dims[dim_i]
@@ -703,7 +936,7 @@ class _JaxSegmentRunner:
         with scope("mask"):
             for m in masks:
                 keep_mask = m if keep_mask is None else (keep_mask & m)
-        out = {name: env[name] for name in self._written if name in env}
+        out = {name: env[name] for name in self._outputs if name in env}
         return out, keep_mask
 
     def _lookup(self, env, d, table, key_col, return_cols, default,
@@ -797,6 +1030,7 @@ class _JaxSegmentRunner:
                  else sorted(cache.names))
         packable = []              # 1-D host columns -> one staging buffer
         dev_cols: Dict[str, object] = {}
+        wide_in: Dict[str, Tuple[int, int]] = {}
         for name in names:
             v = cache.col(name)
             if is_host_column(v) and v.ndim == 1:
@@ -805,12 +1039,20 @@ class _JaxSegmentRunner:
                 # device-resident (or multi-dim host) input: pad to the
                 # bucket on device so the kernel sees one shape per layout
                 dev = bk.asarray(np.ascontiguousarray(v)
-                                 if is_host_column(v) else v)
+                                 if is_host_column(v) else v, name=name)
                 pad = bucket - n
-                if pad:
-                    dev = jnp.concatenate(
-                        [dev, jnp.zeros((pad,) + dev.shape[1:], dev.dtype)])
-                dev_cols[name] = dev
+
+                def padded(a):
+                    return (jnp.concatenate(
+                        [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)])
+                        if pad else a)
+                if isinstance(dev, WideColumn):
+                    wide_in[name] = dev.bound
+                    dev_cols[name] = Wide(padded(dev.lo), padded(dev.hi))
+                else:
+                    dev_cols[name] = padded(dev)
+        ranges = self._input_ranges(packable, dev_cols, wide_in)
+        wide_ops, wide_out, wide_counts = self._wide_plan(ranges, wide_in)
 
         # pack every 1-D host input into ONE staging buffer (canonical
         # device dtypes, zeroed pad tail) and upload it with a single h2d
@@ -840,6 +1082,8 @@ class _JaxSegmentRunner:
             packed = jnp.zeros((0,), np.uint8)
 
         layout = (bucket, tuple(entries))
+        if wide_ops or wide_in:
+            layout += ((wide_ops, tuple(sorted(wide_in))),)
         dims = self.device_dims()
         if layout not in self._layouts:
             # a layout never seen by this runner => the jit call below traces
@@ -850,14 +1094,18 @@ class _JaxSegmentRunner:
         with obs_trace.span("dispatch", "segment"):
             out_cols, keep_mask = self._jit(layout, packed, dev_cols, dims)
             # the live outputs less the bucket's pad rows (dispatches too)
-            out_cols = {name: out_cols[name][:n] for name in self._written
+            out_cols = {name: (WideColumn(out_cols[name].lo[:n],
+                                          out_cols[name].hi[:n],
+                                          wide_out[name])
+                               if name in wide_out else out_cols[name][:n])
+                        for name in self._outputs
                         if name in out_cols and name in final_live}
             if keep_mask is not None:
                 keep_mask = keep_mask[:n]
         self.kernel_calls += 1
         if obs_trace.ACTIVE.get():
             self._trace_call(layout, (packed, dev_cols, dims), n, bucket,
-                             dict(packable))
+                             dict(packable), wide_counts)
 
         for name, col in out_cols.items():
             cache.add_column(name, col)
@@ -865,8 +1113,10 @@ class _JaxSegmentRunner:
             # fused-through-Aggregate: the per-chunk compact (this chunk's
             # ONLY d2h) is deferred — the keep-mask rides along as a device
             # sentinel column and Aggregate.finish applies it once to the
-            # merged cache
+            # merged cache, and no column the Aggregate does not read goes
+            # into its merge
             from .base import SEGMENT_KEEP_MASK
+            final_live = final_live & self._defer_cols
             if keep_mask is not None:
                 cache.add_column(SEGMENT_KEEP_MASK, keep_mask)
                 final_live = final_live | {SEGMENT_KEEP_MASK}
@@ -879,6 +1129,89 @@ class _JaxSegmentRunner:
         if final_live != set(cache.names):
             cache.keep_columns([k for k in cache.names if k in final_live])
 
+    def _input_ranges(self, packable, dev_cols, wide_in
+                      ) -> Dict[str, Tuple[int, int]]:
+        """Observed ranges of the call's integer inputs: host columns by
+        their min and max (a column narrowed to 32 bits whose values do not
+        fit raises ``IntRangeError`` naming it), wide inputs by their
+        bounds, and the device integer inputs an expression reads by one
+        d2h."""
+        ranges = dict(wide_in)
+        for name, v in packable:
+            if v.dtype == np.bool_:
+                ranges[name] = (0, 1)
+            elif np.issubdtype(v.dtype, np.integer) and v.size:
+                ranges[name] = r = wideint.column_range(v)
+                cd = np.dtype(self._jax.dtypes.canonicalize_dtype(v.dtype))
+                if cd.itemsize < v.dtype.itemsize:
+                    wideint.check_fits(name, cd, *r)
+        dev = [(name, a) for name, a in dev_cols.items()
+               if name in self._expr_reads and not isinstance(a, Wide)
+               and a.ndim == 1 and np.issubdtype(a.dtype, np.integer)]
+        ranges.update(zip([name for name, _ in dev],
+                          self._bk._ranges([a for _, a in dev])))
+        return ranges
+
+    def _payload_range(self, dim, col: str) -> Optional[Tuple[int, int]]:
+        key = (id(dim), col)
+        if key not in self._payload_ranges:
+            self._payload_ranges[key] = wideint.column_range(
+                dim.payload[col])
+        return self._payload_ranges[key]
+
+    def _wide_plan(self, ranges, wide_in):
+        """Which ops compute a node past int32, from the inputs' observed
+        ranges carried through the segment's expressions and Lookups:
+        ``(((op index, wideint flags), ...), {wide output column: bound},
+        [(counter name, bits, limbs)])``.  Empty where every node fits
+        int32: the kernel is then the one compiled before."""
+        ranges, wide = dict(ranges), set(wide_in)
+        flags, bounds, counts = [], {}, []
+        n_filters = 0
+        for i, op in enumerate(self.ops):
+            kind = op[0]
+            if kind in ("filter", "expr"):
+                fn = op[1] if kind == "filter" else op[2]
+                name = f"filter.{n_filters}" if kind == "filter" else op[1]
+                n_filters += kind == "filter"
+                p = (wideint.plan(fn, ranges, wide)
+                     if isinstance(fn, Expr) else None)
+                if p is not None and p.flags:
+                    flags.append((i, p.flags))
+                    counts.append((name, p.bits, -(-p.bits // 8)))
+                if kind == "expr":
+                    ranges.pop(name, None)
+                    wide.discard(name)
+                    bounds.pop(name, None)
+                    if p is not None and p.bound is not None:
+                        ranges[name] = p.bound
+                    if p is not None and p.flags and p.flags[0]:
+                        wide.add(name)
+                        bounds[name] = p.bound
+            elif kind == "lookup":
+                _, dim, key_col, return_cols, default, matched_flag = op
+                if key_col in wide:
+                    raise NotImplementedError(
+                        f"Lookup key {key_col!r} is a wide integer column")
+                for out, dcol in return_cols.items():
+                    r = self._payload_range(dim, dcol)
+                    ranges.pop(out, None)
+                    wide.discard(out)
+                    if r is not None and isinstance(default, (int,
+                                                              np.integer)):
+                        ranges[out] = (min(r[0], int(default)),
+                                       max(r[1], int(default)))
+                if matched_flag:
+                    ranges[matched_flag] = (0, 1)
+                    wide.discard(matched_flag)
+            elif kind == "convert":
+                for col in op[1]:
+                    if col in wide:
+                        raise NotImplementedError(
+                            f"convert of wide integer column {col!r}")
+                    ranges.pop(col, None)
+        return tuple(flags), bounds, counts
+
     def _scope_map(self, layout, args) -> tuple:
         """``(program, layout key, {ENTRY op: named scope})`` of the kernel
         compiled for ``layout``, parsed once per layout from the compiled
@@ -889,21 +1222,26 @@ class _JaxSegmentRunner:
             with obs_trace.span("program", "scopes.build"):
                 text = self._jit.lower(layout, *args).compile().as_text()
             program, ops = obs_trace.entry_scopes(text)
-            bucket, entries = layout
+            bucket, entries = layout[:2]
             key = f"{bucket}:{','.join(name for name, _, _ in entries)}"
             got = self._scope_maps[layout] = (program, key, ops)
         return got
 
     def _trace_call(self, layout, args, n: int, bucket: int,
-                    host_cols: Dict[str, np.ndarray]) -> None:
-        """A traced call's events: which compiled op belongs to which scope,
-        and per hash-probe Lookup the rows probed, the passes its loop ran
+                    host_cols: Dict[str, np.ndarray],
+                    wide_counts: Sequence[tuple] = ()) -> None:
+        """A traced call's events: which compiled op belongs to which scope;
+        per widened expression (or filter) the rows, the two's-complement
+        bits of its widest node and the 8-bit limbs those bits make; and
+        per hash-probe Lookup the rows probed, the passes its loop ran
         over them (1 on a ``direct`` table), and, where its key column is a
         host input of the call, the passes they need (``need``, walked on
         the host)."""
         program, key, ops = self._scope_map(layout, args)
         obs_trace.instant("program", "scopes", program=program, layout=key,
                           ops=ops)
+        for name, bits, limbs in wide_counts:
+            obs_trace.counter("wide", name, rows=n, bits=bits, limbs=limbs)
         with obs_trace.span("program", "probe.count"):
             for name, key_col, table in zip(self._lookup_names,
                                             self._lookup_keys, self._tables):

@@ -12,7 +12,20 @@ from typing import Callable, Dict, List, Mapping, Sequence, Tuple
 
 import numpy as np
 
+from .. import wideint
 from .base import AGG_OPS, Backend
+
+
+def _sum_dtype(vals: np.ndarray):
+    """Integers sum exactly in int64, as the device's exact sums; anything
+    else in float64."""
+    return np.int64 if np.issubdtype(vals.dtype, np.integer) else np.float64
+
+
+def _sum(acc: np.ndarray) -> np.ndarray:
+    """A sum as every backend returns it (``wideint.exact_result`` for
+    integer sums)."""
+    return wideint.exact_result(acc) if acc.dtype == np.int64 else acc
 
 
 class NumpyBackend(Backend):
@@ -64,9 +77,11 @@ class NumpyBackend(Backend):
                 if op == "count":
                     aggs[out] = np.array([n], dtype=np.int64)
                 elif op == "sum":
-                    aggs[out] = np.array([vals.astype(np.float64).sum()])
+                    aggs[out] = _sum(np.array([vals.astype(
+                        _sum_dtype(vals)).sum()]))
                 elif op == "avg":
-                    aggs[out] = np.array([vals.astype(np.float64).mean()])
+                    total = vals.astype(_sum_dtype(vals)).sum()
+                    aggs[out] = np.array([total / n if n else np.nan])
                 elif op == "min":
                     aggs[out] = np.array([vals.min()])
                 elif op == "max":
@@ -89,8 +104,8 @@ class NumpyBackend(Backend):
                 continue
             vals = np.asarray(col)[order]
             if op in ("sum", "avg"):
-                acc = np.add.reduceat(vals.astype(np.float64), starts)
-                aggs[out] = acc / counts if op == "avg" else acc
+                acc = np.add.reduceat(vals.astype(_sum_dtype(vals)), starts)
+                aggs[out] = acc / counts if op == "avg" else _sum(acc)
             elif op == "min":
                 aggs[out] = np.minimum.reduceat(vals, starts)
             elif op == "max":

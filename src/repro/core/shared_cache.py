@@ -211,13 +211,19 @@ class SharedCache:
             raise ValueError("mask shorter than valid rows")
         mask_h = _to_host(mask)[: self.n]
         k = int(mask_h.sum())
+        rows_d = None
         for name, vals in self.columns.items():
             if is_host_column(vals):
                 # write the surviving rows into the head of the SAME buffer
                 vals[:k] = vals[: self.n][mask_h]
             else:
-                # device column: immutable — replace functionally
-                self.columns[name] = vals[: self.n][mask_h]
+                # device column: immutable — replace functionally, by one
+                # index array uploaded once for every device column
+                if rows_d is None:
+                    import jax.numpy as jnp   # deferred: device columns only
+                    rows_d = jnp.asarray(np.flatnonzero(mask_h)
+                                         .astype(np.int32))
+                self.columns[name] = vals[: self.n][rows_d]
         self.n = k
         self.version += 1
 
@@ -297,9 +303,13 @@ class SharedCache:
 
 
 def _concat_column(parts: List):
-    """Concatenate column parts, staying on device if any part lives there."""
+    """Concatenate column parts, staying on device if any part lives there
+    (wide integer parts: as one ``WideColumn``)."""
     if all(is_host_column(p) for p in parts):
         return np.concatenate(parts)
+    from .wideint import WideColumn      # deferred: wideint imports faults
+    if any(isinstance(p, WideColumn) for p in parts):
+        return WideColumn.concat(parts)
     import jax.numpy as jnp              # deferred: only on device columns
     for p in parts:
         if is_host_column(p):

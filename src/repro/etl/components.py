@@ -703,6 +703,15 @@ class _AggServeState:
 _PARTIAL_SEP = "\x00"
 
 
+def _agg_values(merged: SharedCache, aggs: Dict[str, Tuple[str, str]]
+                ) -> Dict[str, tuple]:
+    """``groupby_reduce``'s values: each distinct input column fetched
+    once, so a backend sees one column read by several outputs (``sum`` and
+    ``avg`` of it) as one and sums it once."""
+    cols = {c: merged.col(c) for c, _ in aggs.values()}
+    return {out: (cols[c], op) for out, (c, op) in aggs.items()}
+
+
 class Aggregate(BlockComponent):
     """Group-by aggregation — the paper's canonical block component
     (sum/avg/min/max).  Accumulates all input caches, then reduces.
@@ -802,8 +811,7 @@ class Aggregate(BlockComponent):
         bk = self.get_backend()
         group_cols, part_cols = bk.groupby_reduce(
             [merged.col(g) for g in self.group_by],
-            {p: (merged.col(col), op) for p, (col, op) in plan.items()},
-            n)
+            _agg_values(merged, plan), n)
         group_h = [np.asarray(bk.to_host(c)) for c in group_cols]
         part_h = {p: np.asarray(bk.to_host(c)) for p, c in part_cols.items()}
         merged.recycle()            # tick-loop steady state: buffers pool
@@ -871,8 +879,7 @@ class Aggregate(BlockComponent):
         # sum/avg through the kernels/segment_sum Pallas op
         group_cols, agg_cols = self.get_backend().groupby_reduce(
             [merged.col(g) for g in self.group_by],
-            {out: (merged.col(col), op) for out, (col, op) in self.aggs.items()},
-            n)
+            _agg_values(merged, self.aggs), n)
         cols = dict(zip(self.group_by, group_cols))
         cols.update(agg_cols)
         # degenerate global aggregation with no agg columns: one empty row
@@ -908,8 +915,7 @@ class Aggregate(BlockComponent):
         bk = self.get_backend()
         group_cols, part_cols = bk.groupby_reduce(
             [merged.col(g) for g in self.group_by],
-            {p: (merged.col(col), op) for p, (col, op) in plan.items()},
-            n)
+            _agg_values(merged, plan), n)
         table = {g: np.asarray(bk.to_host(c))
                  for g, c in zip(self.group_by, group_cols)}
         for p, c in part_cols.items():

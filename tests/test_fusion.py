@@ -164,6 +164,24 @@ def test_fuse_segments_flow_defers_mask_to_aggregate():
     partition(flow)
 
 
+def test_deferring_segment_hands_on_only_the_aggregates_columns():
+    """On the jax backend a segment that defers its keep-mask to the
+    Aggregate leaves in the cache only the columns the Aggregate reads
+    and the mask: the others would be merged and compacted for nothing."""
+    pytest.importorskip("jax")
+    from repro.core.backend import SEGMENT_KEEP_MASK, get_backend
+    from repro.core.shared_cache import SharedCache
+    agg = Aggregate("agg", ["k"], {"s": ("a", "sum")})
+    flow = _chain_flow(_src(), _expr("e1", "a"), _filt("f1"), agg,
+                       CollectSink("sink"))
+    fuse_segments_flow(flow)
+    fused = flow.component("fusedseg(e1+f1)")
+    cache = SharedCache({k: v.copy()
+                         for k, v in flow.component("src").columns.items()})
+    get_backend("jax").compile_segment(fused)(cache)
+    assert set(cache.names) == {"k", "a", SEGMENT_KEEP_MASK}
+
+
 def test_fused_segment_provenance_and_spec():
     lk = Lookup("lk", DimTable(np.arange(1, 5, dtype=np.int64),
                                {"p": np.arange(4, dtype=np.int64)}),

@@ -432,6 +432,68 @@ def test_radix_groupby_all_padding():
     np.testing.assert_array_equal(np.asarray(counts), np.zeros(32))
 
 
+# ------------------------------------------------- exact integer group sums
+def _exact_case(n, g, seed):
+    """Group ids (-1 = padding), a signed int32 column and a wide column of
+    products past int32, with their exact int64 values."""
+    from repro.core import wideint
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(-1, g, n).astype(np.int32)
+    small = rng.integers(-2**31, 2**31 - 1, n)
+    big = rng.integers(-2**31, 2**31 - 1, n) * rng.integers(-100, 100, n)
+    wide = wideint.Wide(jnp.asarray((big & 0xFFFFFFFF).astype(np.uint32)),
+                        jnp.asarray((big >> 32).astype(np.int32)))
+    return ids, (jnp.asarray(small, jnp.int32), small), (wide, big)
+
+
+def _exact_expect(ids, vals, g):
+    out = np.zeros(g, dtype=np.int64)
+    keep = ids >= 0
+    np.add.at(out, ids[keep], vals[keep])
+    return out
+
+
+@pytest.mark.parametrize("route", ["radix", "segment_sum"])
+@pytest.mark.parametrize("impl", ["interpret", "reference"])
+@pytest.mark.parametrize("n,g", [(5_000, 7), (1_500, 300), (700, 1)])
+def test_exact_integer_sums_equal_int64(route, impl, n, g):
+    """Signed int32 and wide inputs, each less its minimum in 8-bit limbs,
+    summed per group by the radix and segment-sum wrappers: the recombined
+    sums equal numpy's int64 sums."""
+    from repro.core import wideint
+    ids, (small_d, small), (wide, big) = _exact_case(n, g, seed=n + g)
+    ranges = [(int(small.min()), int(small.max())),
+              (int(big.min()), int(big.max()))]
+    limbs = tuple(wideint.limb_count(r) for r in ranges)
+    ints = ((small_d, wideint.const(ranges[0][0])),
+            (wide, wideint.const(ranges[1][0])))
+    kernel = radix_groupby if route == "radix" else segment_sum
+    _, counts, exact = kernel(jnp.asarray(ids), jnp.zeros((n, 0)), g,
+                              impl=impl, ints=ints, limbs=limbs)
+    got = wideint.recombine(exact, counts, [r[0] for r in ranges], limbs)
+    np.testing.assert_array_equal(np.asarray(counts).sum(0),
+                                  np.bincount(ids[ids >= 0], minlength=g))
+    assert np.array_equal(got[0], _exact_expect(ids, small, g))
+    assert np.array_equal(got[1], _exact_expect(ids, big, g))
+
+
+def test_exact_sums_split_rows_into_int32_blocks(monkeypatch):
+    """Past ``BLOCK_ROWS`` rows the limb sums come per block of rows, each
+    within int32, and add up on the host."""
+    from repro.kernels.radix_groupby import exact
+    monkeypatch.setattr(exact, "BLOCK_ROWS", 1024)
+    ids = np.arange(5_000, dtype=np.int32) % 3
+    limbs = jnp.full((2, 5_000), 255, jnp.int32)
+    for fn in (exact.exact_sums_ref,
+               lambda i, l, g: exact.exact_sums_pallas(i, l, g,
+                                                       interpret=True)):
+        out = np.asarray(fn(jnp.asarray(ids), limbs, 3))
+        assert out.shape[0] == 5                   # ceil(5000 / 1024)
+        np.testing.assert_array_equal(
+            out.sum(0), 255 * np.bincount(ids, minlength=3)[:, None]
+            .repeat(2, 1))
+
+
 # --------------------------------------------------------- flash attention
 @pytest.mark.parametrize("B,Sq,Skv,Kh,G,hd,causal,window,softcap,bq,bk", [
     (1, 64, 64, 1, 1, 32, True, 0, 0.0, 32, 32),

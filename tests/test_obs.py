@@ -534,3 +534,82 @@ def test_fused_q41_counts_probe_passes_per_lookup(q41_runner, data,
     assert {("transfer", "h2d.pack"), ("transfer", "h2d.upload"),
             ("wait", "h2d.ready"), ("transfer", "h2d"),
             ("dispatch", "segment"), ("transfer", "d2h")} <= names
+
+
+# ---------------------------------------------------------------------------
+#  Wide expressions and exact group sums
+# ---------------------------------------------------------------------------
+def _q1_segment_and_rows(n=3000, seed=5):
+    pytest.importorskip("jax")
+    from repro import col
+    from repro.core.backend.jax_backend import JaxBackend
+    from repro.etl.components import Expression, Filter, FusedSegment
+    rng = np.random.default_rng(seed)
+    qty = rng.integers(1, 51, n)
+    table = {"l_extendedprice": qty * rng.integers(90_000, 209_901, n),
+             "l_discount": rng.integers(0, 11, n),
+             "l_tax": rng.integers(0, 9, n),
+             "l_shipdate": rng.integers(19920102, 19981201, n)}
+    disc = col("l_extendedprice") * (100 - col("l_discount"))
+    seg = FusedSegment.from_components([
+        Filter("ship", col("l_shipdate") <= 19980902),
+        Expression("disc", "disc_price", disc),
+        Expression("charge", "charge", disc * (100 + col("l_tax")))])
+    return JaxBackend().compile_segment(seg), table
+
+
+def test_wide_expression_scope_and_counter():
+    """A traced segment call names the widened expression's ops
+    ``wide.<col>`` and counts it: rows, bits of its widest node, and the
+    8-bit limbs those bits make; the expression that fits int32 gets
+    neither."""
+    from repro.core.shared_cache import SharedCache
+    runner, table = _q1_segment_and_rows()
+    cache = SharedCache({k: v.copy() for k, v in table.items()})
+    with obs_trace.trace_scope() as tr:
+        runner(cache)
+    wide = [e for e in tr.events if (e["ph"], e["cat"]) == ("C", "wide")]
+    ext = table["l_extendedprice"]
+    hi = int(ext.max()) * 100 * 108
+    assert [(e["name"], e["args"]) for e in wide] == [
+        ("charge", {"rows": 3000, "bits": hi.bit_length() + 1,
+                    "limbs": -(-(hi.bit_length() + 1) // 8)})]
+    (scopes,) = [e for e in tr.events if e["name"] == "scopes"]
+    names = set(scopes["args"]["ops"].values())
+    assert any(s.startswith("expr.charge/wide.charge") for s in names), names
+    assert not any("wide.disc_price" in s for s in names)
+    # untraced calls emit nothing and build no scope map
+    runner2, _ = _q1_segment_and_rows()
+    runner2(SharedCache({k: v.copy() for k, v in table.items()}))
+    assert runner2._scope_maps == {}
+
+
+def test_exact_groupby_counter_and_scope():
+    """Each group-by call that sums integers exactly counts its rows, its
+    distinct integer inputs (a column read by ``sum`` and ``avg`` once), the
+    limb columns summed and the widest span; its program names the limb
+    split ``groupby.exact``."""
+    pytest.importorskip("jax")
+    import jax.numpy as jnp
+    from repro.core.backend import get_backend
+    from repro.kernels.radix_groupby import radix_groupby
+    bk = get_backend("jax")
+    keys = np.arange(4000) % 6
+    qty = (np.arange(4000) % 50) + 1                 # 1..50: one limb
+    price = qty * 200_000                            # span < 2**24: 3
+    with obs_trace.trace_scope() as tr:
+        bk.groupby_reduce([keys], {"s": (qty, "sum"), "a": (qty, "avg"),
+                                   "p": (price, "sum"),
+                                   "n": (qty, "count")}, 4000)
+    (ev,) = [e for e in tr.events if (e["ph"], e["cat"], e["name"])
+             == ("C", "exact", "groupby")]
+    assert ev["args"] == {"rows": 4000, "columns": 2, "limbs": 4,
+                          "max_bits": int(price.max() - price.min())
+                          .bit_length()}
+    from repro.core import wideint
+    ids = jnp.asarray(keys, jnp.int32)
+    text = radix_groupby.lower(
+        ids, jnp.zeros((4000, 0)), 6, impl="reference",
+        ints=((jnp.asarray(qty, jnp.int32), wideint.const(1)),),
+        limbs=(1,)).as_text(debug_info=True)
+    assert "groupby.exact" in text

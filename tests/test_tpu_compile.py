@@ -10,6 +10,10 @@ smoke (``chip_smoke.py``) feeds it:
 * ``radix_groupby_pallas`` at the serving batch's 2^21 rows and at Q2.1's
   7000 dense cells;
 * ``segment_sum_pallas`` at Q1.1's 2^17 rows;
+* the exact integer sums (``exact_sums_pallas``) at TPC-H Q1's 6M rows of
+  14 limb columns over 6 cells, and at Q1.1's keyless sum;
+* the fused TPC-H Q1 segment at a 2^21-row chunk bucket, with ``charge``
+  computed in two words under ``wide.charge``;
 * one fused Q4.1 segment kernel over SF1 dimension tables at a 2^21-row
   chunk bucket, with each Lookup's one-pass probe under its scope.
 
@@ -105,6 +109,47 @@ def test_segment_sum_pallas_compiles(one_chip):
         _spec(one_chip, (n_rows, 1), jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
+
+
+@pytest.mark.parametrize("n_rows,n_limbs,n_groups", [
+    (6_000_000, 14, 6),      # TPC-H Q1 at SF1: 5 inputs, 4 of 6 cells
+    (1 << 17, 3, 1),         # SSB Q1.1's keyless revenue
+])
+def test_exact_sums_pallas_compiles(one_chip, n_rows, n_limbs, n_groups):
+    from repro.kernels.radix_groupby.exact import exact_sums_pallas
+    compiled = jax.jit(
+        lambda ids, l: exact_sums_pallas(ids, l, n_groups)).lower(
+        _spec(one_chip, (n_rows,), jnp.int32),
+        _spec(one_chip, (n_limbs + 1, n_rows), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_fused_tpch_q1_segment_compiles_with_a_wide_charge(one_chip):
+    import numpy as np
+    from repro import col
+    from repro.core.backend.jax_backend import JaxBackend
+    from repro.etl.components import Expression, Filter, FusedSegment
+    from repro.obs.trace import entry_scopes
+    disc = col("l_extendedprice") * (100 - col("l_discount"))
+    seg = FusedSegment.from_components([
+        Filter("ship", col("l_shipdate") <= 19980902),
+        Expression("disc", "disc_price", disc),
+        Expression("charge", "charge", disc * (100 + col("l_tax")))])
+    runner = JaxBackend().compile_segment(seg)
+    bucket = 1 << 21
+    entries, total = runner.pack_layout(bucket, [
+        (c, np.dtype(np.int64)) for c in sorted(runner.inputs)])
+    ranges = {"l_extendedprice": (90_000, 10_495_000), "l_discount": (0, 10),
+              "l_tax": (0, 8), "l_shipdate": (19920102, 19981201)}
+    wide_ops, bounds, _ = runner._wide_plan(ranges, {})
+    assert bounds == {"charge": (90_000 * 90 * 100, 10_495_000 * 100 * 108)}
+    compiled = runner._jit.lower(
+        (bucket, tuple(entries), (wide_ops, ())),
+        _spec(one_chip, (total,), jnp.uint8), {}, []).compile()
+    _fits(compiled)
+    program, ops = entry_scopes(compiled.as_text())
+    assert "expr.charge/wide.charge" in ops.values()
 
 
 @pytest.fixture(scope="module")
