@@ -6,7 +6,9 @@ Kernels:
     reused across every chunk).  Default route is the ``kernels/hash_join``
     slot table (host-built once per DimTable, probed through XLA; slot
     ``key - min`` for dense integer keys, else fmix32 open addressing;
-    probes handle arbitrary key order and multi-column keys);
+    probes handle arbitrary key order and multi-column keys; inside a fused
+    segment a direct table's Lookup gathers once per returned column from
+    slot-addressed payloads, ``_dim_slots``);
     ``REPRO_JOIN_IMPL=searchsorted`` selects the legacy jitted binary search
     over the sorted keys.  Both return the same (index, matched) pair
     bit-for-bit: the hash build keeps the FIRST occurrence of a duplicate
@@ -396,6 +398,70 @@ class JaxBackend(Backend):
                                       if len(dim.keys) else None),
                     }
         return ht
+
+    def _dim_slots(self, dim, cols: Sequence[str], default,
+                   flagged: bool) -> Tuple[Dict[str, object], Optional[tuple]]:
+        """A Lookup's slot-addressed arrays over a direct table
+        (``_dim_hash`` with a ``base``): ``({"cols": {dim column: [T]
+        array}[, "matched": [T] int32]}, carrier)``.  Slot ``s`` is matched
+        where it holds a row (the first with key ``base + s``) that
+        qualifies.  A column holds its payload where matched and ``default``
+        (in the payload's device dtype) elsewhere, so one gather at ``key -
+        base`` answers the Lookup.  Where ``flagged``, the match bit rides on
+        the carrier: the first integer column whose span ``hi - lo + 1``
+        fits its device dtype holds ``payload - lo + 1`` where matched and 0
+        elsewhere (``carrier`` is ``(column, lo)``); with none, ``matched``
+        is 1 where matched.  Each array is built on the host once and cached
+        on the table."""
+        slot_idx = self._dim_hash(dim)["host"]["slot_idx"]
+        carrier = None
+        if flagged:
+            for col in cols:
+                cd = np.dtype(self._jax.dtypes.canonicalize_dtype(
+                    dim.payload[col].dtype))
+                r = wideint.column_range(dim.payload[col])
+                if (r is not None and cd != np.bool_
+                        and r[1] - r[0] + 1 <= np.iinfo(cd).max):
+                    carrier = (col, r[0])
+                    break
+        out = {"cols": {col: self._dim_slot(
+            dim, slot_idx, col, "offset" if carrier and col == carrier[0]
+            else "value", default) for col in cols}}
+        if flagged and carrier is None:
+            out["matched"] = self._dim_slot(dim, slot_idx, None, "matched")
+        return out, carrier
+
+    def _dim_slot(self, dim, slot_idx: np.ndarray, col: Optional[str],
+                  kind: str, default=None):
+        """One of ``_dim_slots``'s arrays (``kind`` "value", "offset" or
+        "matched"), cached on the table."""
+        key = (kind, col, default if kind == "value" else None)
+        cache = dim.__dict__.setdefault("_jax_slot_cache", {})
+        got = cache.get(key)
+        if got is None:
+            with self._dims_lock:
+                got = cache.get(key)
+                if got is None:
+                    row = np.maximum(slot_idx, 0)
+                    matched = (slot_idx >= 0) & dim.qualifies[row]
+                    if kind == "matched":
+                        host = matched.astype(np.int32)
+                    else:
+                        payload = dim.payload[col]
+                        cd = np.dtype(self._jax.dtypes.canonicalize_dtype(
+                            payload.dtype))
+                        if kind == "offset":
+                            lo = wideint.column_range(payload)[0]
+                            host = np.where(
+                                matched,
+                                payload[row].astype(np.int64) - lo + 1,
+                                0).astype(cd)
+                        else:
+                            fill = np.asarray(self._jnp.asarray(default, cd))
+                            host = np.where(matched, payload[row], fill)
+                    record_dim_upload(host.nbytes)
+                    got = cache[key] = self.asarray(host, name=col)
+        return got
 
     # ---------------------------------------------------- DSL expression jit
     def _expr_runner(self, expr: Expr):
@@ -843,6 +909,11 @@ class _JaxSegmentRunner:
         #: per Lookup, in op order: its hash table (``_dim_hash``: the static
         #: loop bound and probe statistics), or None on the searchsorted route
         self._tables: List[Optional[dict]] = []
+        #: per Lookup, in op order: its slot-addressed plan on a direct
+        #: table (``device_dims``), else None; and the gathers a row makes
+        #: outside the probe loop
+        self._slots: List[Optional[dict]] = []
+        self._gathers: List[int] = []
         #: per Lookup, in op order: the name of its scope and probe counter,
         #: and its key column
         self._lookup_names = [op[1].name or op[2] for op in self.ops
@@ -918,8 +989,13 @@ class _JaxSegmentRunner:
                 d = dims[dim_i]
                 table = self._tables[dim_i]   # static (never traced)
                 with scope(f"lookup.{self._lookup_names[dim_i]}"):
-                    self._lookup(env, d, table, key_col, return_cols,
-                                 default, matched_flag)
+                    if self._slots[dim_i] is not None:
+                        self._lookup_slots(env, d, self._slots[dim_i],
+                                           key_col, return_cols, default,
+                                           matched_flag)
+                    else:
+                        self._lookup(env, d, table, key_col, return_cols,
+                                     default, matched_flag)
                 dim_i += 1
             elif kind == "project":
                 keep = set(op[1])
@@ -941,8 +1017,9 @@ class _JaxSegmentRunner:
 
     def _lookup(self, env, d, table, key_col, return_cols, default,
                 matched_flag) -> None:
-        """One Lookup, traced into the kernel: the probe under ``probe``,
-        the qualifying and payload gathers under ``gather``."""
+        """One Lookup on a hashed table or the searchsorted route, traced
+        into the kernel: the probe under ``probe``, the qualifying and
+        payload gathers under ``gather``."""
         jnp = self._jnp
         scope = self._jax.named_scope
         vals = env[key_col]
@@ -975,6 +1052,38 @@ class _JaxSegmentRunner:
         if matched_flag:
             env[matched_flag] = matched
 
+    def _lookup_slots(self, env, d, plan, key_col, return_cols, default,
+                      matched_flag) -> None:
+        """One Lookup on a direct table, from its slot-addressed arrays
+        (``JaxBackend._dim_slots``): the key's slot and range check under
+        ``probe``, one gather per returned column under ``gather`` (or one
+        of ``matched`` where no column carries the match bit)."""
+        jnp = self._jnp
+        scope = self._jax.named_scope
+        size = plan["size"]
+        with scope("probe"):
+            off = (env[key_col].astype(jnp.uint32)
+                   - jnp.uint32(plan["base"] % (1 << 32)))
+            in_range = off < jnp.uint32(size)
+            slot = (off & jnp.uint32(size - 1)).astype(jnp.int32)
+        with scope("gather"):
+            matched = None
+            if "matched" in d:
+                matched = in_range & (jnp.take(d["matched"], slot,
+                                               mode="clip") != 0)
+            carrier, lo = plan["carrier"] or (None, 0)
+            for out_name, dim_col in return_cols.items():
+                w = jnp.take(d["cols"][dim_col], slot, mode="clip")
+                fill = jnp.asarray(default, w.dtype)
+                if dim_col == carrier:
+                    matched = in_range & (w != 0)
+                    env[out_name] = jnp.where(
+                        matched, (w - 1) + jnp.asarray(lo, w.dtype), fill)
+                else:
+                    env[out_name] = jnp.where(in_range, w, fill)
+        if matched_flag:
+            env[matched_flag] = matched
+
     # ------------------------------------------------------------ execution
     def pack_layout(self, bucket: int, columns) -> Tuple[list, int]:
         """Staging-buffer layout of ``(name, host dtype)`` columns padded to
@@ -992,31 +1101,43 @@ class _JaxSegmentRunner:
         """Device mirrors of every looked-up DimTable, in op order — uploaded
         once per table (cached on the table) and structurally identical per
         call, so the pytree is built once and per-chunk Python cost stays
-        flat.  Also fixes each lookup's static probe bound."""
+        flat.  Also fixes each lookup's static probe bound, and on a direct
+        table its slot-addressed plan (``base``, ``size``, the match bit's
+        ``carrier``) and gathers a row."""
         if self._dims is None:
             bk = self._bk
-            dims = []
-            tables = []
+            dims, tables, slots, gathers = [], [], [], []
             for op in self.ops:
-                if op[0] == "lookup":
-                    _, dim, _, return_cols, _, _ = op
-                    dev = bk._dim_device(dim)
-                    entry = {
-                        "keys": dev["keys"],
-                        "qualifies": dev["qualifies"],
-                        "payload": {dcol: bk._dim_payload(dim, dcol)
-                                    for dcol in return_cols.values()},
-                    }
-                    if (self._join_impl != "searchsorted"
-                            and len(dim.keys) > 0):
-                        ht = bk._dim_hash(dim)
-                        entry["slot_keys"] = ht["slot_keys"]
-                        entry["slot_idx"] = ht["slot_idx"]
-                        tables.append(ht)
-                    else:
-                        tables.append(None)   # legacy searchsorted
+                if op[0] != "lookup":
+                    continue
+                _, dim, _, return_cols, default, matched_flag = op
+                ht = (bk._dim_hash(dim) if self._join_impl != "searchsorted"
+                      and len(dim.keys) > 0 else None)
+                tables.append(ht)         # None: legacy searchsorted
+                if ht is not None and ht["base"] is not None:
+                    entry, carrier = bk._dim_slots(
+                        dim, list(dict.fromkeys(return_cols.values())),
+                        default, bool(matched_flag))
+                    slots.append({"base": ht["base"],
+                                  "size": ht["table_size"],
+                                  "carrier": carrier})
+                    gathers.append(len(return_cols) + ("matched" in entry))
                     dims.append(entry)
-            self._tables = tables
+                    continue
+                dev = bk._dim_device(dim)
+                entry = {
+                    "keys": dev["keys"],
+                    "qualifies": dev["qualifies"],
+                    "payload": {dcol: bk._dim_payload(dim, dcol)
+                                for dcol in return_cols.values()},
+                }
+                if ht is not None:
+                    entry["slot_keys"] = ht["slot_keys"]
+                    entry["slot_idx"] = ht["slot_idx"]
+                slots.append(None)
+                gathers.append(1 + len(return_cols))
+                dims.append(entry)
+            self._tables, self._slots, self._gathers = tables, slots, gathers
             self._dims = dims
         return self._dims
 
@@ -1234,17 +1355,20 @@ class _JaxSegmentRunner:
         per widened expression (or filter) the rows, the two's-complement
         bits of its widest node and the 8-bit limbs those bits make; and
         per hash-probe Lookup the rows probed, the passes its loop ran
-        over them (1 on a ``direct`` table), and, where its key column is a
-        host input of the call, the passes they need (``need``, walked on
-        the host)."""
+        over them (1 on a ``direct`` table), the gathers a row makes
+        outside that loop (``gathers``), whether it read slot-addressed
+        arrays (``slot_addressed``), and, where its key column is a host
+        input of the call, the passes they need (``need``, walked on the
+        host)."""
         program, key, ops = self._scope_map(layout, args)
         obs_trace.instant("program", "scopes", program=program, layout=key,
                           ops=ops)
         for name, bits, limbs in wide_counts:
             obs_trace.counter("wide", name, rows=n, bits=bits, limbs=limbs)
         with obs_trace.span("program", "probe.count"):
-            for name, key_col, table in zip(self._lookup_names,
-                                            self._lookup_keys, self._tables):
+            for name, key_col, table, plan, gathers in zip(
+                    self._lookup_names, self._lookup_keys, self._tables,
+                    self._slots, self._gathers):
                 if not table:
                     continue
                 counts = {}
@@ -1256,7 +1380,8 @@ class _JaxSegmentRunner:
                     passes=table["max_probes"],
                     direct=int(table["base"] is not None),
                     mean_probes=table["mean_probes"],
-                    slots=table["table_size"], **counts)
+                    slots=table["table_size"], gathers=gathers,
+                    slot_addressed=int(plan is not None), **counts)
 
     def _probe_need(self, table: dict, vals: np.ndarray) -> int:
         """The passes the probe loop needs over ``vals``, summed: each key's

@@ -256,6 +256,7 @@ class DimTable:
         state = dict(self.__dict__)
         state.pop("_jax_device_cache", None)
         state.pop("_jax_hash_cache", None)
+        state.pop("_jax_slot_cache", None)
         return state
 
 

@@ -336,6 +336,169 @@ def test_fused_segment_does_not_resurrect_projected_columns():
 
 
 # ---------------------------------------------------------------------------
+#  Slot-addressed Lookups on direct dimension tables (jax backend)
+# ---------------------------------------------------------------------------
+_I32 = np.iinfo(np.int32)
+_SRNG = np.random.default_rng(16)
+
+
+def _slot_case(keys, payload, probes, default=-1, flag="ok", where=None,
+               cols=("v",), carrier="v"):
+    """``carrier``: the column the match bit rides on, None where a
+    ``matched`` array is gathered instead (or no flag is asked for)."""
+    return dict(keys=np.asarray(keys, np.int64), payload=payload,
+                probes=np.asarray(probes, np.int64), default=default,
+                flag=flag, where=where, cols=cols,
+                carrier=carrier if flag else None)
+
+
+_SLOT_CASES = {
+    # keys below, inside and above the span, with and without the flag
+    "span_edges": _slot_case(np.arange(100, 400), _SRNG.integers(0, 50, 300),
+                             np.arange(40, 470)),
+    "span_edges_unflagged": _slot_case(
+        np.arange(100, 400), _SRNG.integers(0, 50, 300), np.arange(40, 470),
+        flag=None),
+    "empty_slots": _slot_case(
+        np.sort(_SRNG.choice(4_000, 2_500, replace=False)),
+        _SRNG.integers(0, 9, 2_500), np.arange(-10, 4_100)),
+    "unqualified_rows": _slot_case(
+        np.arange(0, 600), _SRNG.integers(0, 7, 600), np.arange(-5, 610),
+        where=_SRNG.random(600) < 0.5),
+    "duplicate_keys": _slot_case(
+        np.r_[np.arange(10, 90), np.arange(10, 90, 3), np.arange(40, 50)],
+        np.arange(117), np.arange(0, 100)),
+    "negative_payloads": _slot_case(
+        np.arange(-300, 300, 2), _SRNG.integers(-1_000, 0, 300),
+        np.arange(-320, 320)),
+    "default_inside_range": _slot_case(
+        np.arange(0, 500), _SRNG.integers(0, 50, 500), np.arange(-20, 520),
+        default=5),
+    "float_payload": _slot_case(
+        np.arange(7, 407), _SRNG.normal(size=400), np.arange(0, 420),
+        carrier=None),
+    "float_payload_unflagged": _slot_case(
+        np.arange(7, 407), _SRNG.normal(size=400), np.arange(0, 420),
+        flag=None),
+    "no_returned_column": _slot_case(
+        np.arange(50, 250, 3), np.zeros(67, np.int64), np.arange(0, 300),
+        cols=(), carrier=None),
+    "full_int32_span": _slot_case(
+        np.arange(1, 301),
+        np.r_[_I32.min, _I32.max, _SRNG.integers(-9, 9, 298)],
+        np.arange(-3, 310), carrier=None),
+    "two_columns": _slot_case(
+        np.arange(1, 301), _SRNG.normal(size=300), np.arange(-3, 310),
+        cols=("v", "w"), carrier="w"),
+}
+
+
+def _slot_lookup(case):
+    """The outputs of a fused segment of one Lookup (route as the
+    environment and the build pick it) over ``case``'s probes, and its
+    runner."""
+    from repro.core.backend.jax_backend import JaxBackend
+    payload = {"v": case["payload"],
+               "w": np.arange(len(case["keys"])) * 7 - 500}
+    dim = DimTable(case["keys"], payload, row_filter=case["where"],
+                   name="d")
+    returns = {f"out_{c}": c for c in case["cols"]}
+    lk = Lookup("lk", dim, "fk", returns, default=case["default"],
+                matched_flag=case["flag"])
+    runner = JaxBackend().compile_segment(FusedSegment.from_components([lk]))
+    cache = SharedCache({"fk": case["probes"].copy()})
+    runner(cache)
+    names = sorted(returns) + ([case["flag"]] if case["flag"] else [])
+    return {n: np.asarray(cache.col(n)) for n in names}, runner, dim
+
+
+@pytest.mark.parametrize("case", sorted(_SLOT_CASES))
+def test_slot_addressed_lookup_matches_hash_and_searchsorted(case,
+                                                             monkeypatch):
+    """A Lookup on a direct table gathers from slot-addressed payloads; its
+    outputs and match flag are byte-identical to the fmix32 hash route
+    (the same keys forced off direct addressing), to the legacy
+    ``searchsorted`` route and to ``DimTable.probe`` on the host."""
+    pytest.importorskip("jax")
+    c = _SLOT_CASES[case]
+    got, runner, dim = _slot_lookup(c)
+    assert runner._slots[0] is not None
+    # the match bit rides on an integer column whose span leaves room
+    carrier = runner._slots[0]["carrier"]
+    assert (carrier and carrier[0]) == c["carrier"]
+    with monkeypatch.context() as m:
+        m.setattr("repro.kernels.hash_join.ref.DIRECT_MAX_RATIO", 0)
+        hashed, hrunner, _ = _slot_lookup(c)
+        assert hrunner._slots[0] is None and hrunner._tables[0]["base"] is None
+    with monkeypatch.context() as m:
+        m.setenv("REPRO_JOIN_IMPL", "searchsorted")
+        legacy, lrunner, _ = _slot_lookup(c)
+        assert lrunner._tables[0] is None
+    idx, matched = dim.probe(c["probes"])
+    for name, want in (("hash", hashed), ("searchsorted", legacy)):
+        assert got.keys() == want.keys(), name
+        for col in got:
+            assert got[col].dtype == want[col].dtype, (name, col)
+            assert got[col].tobytes() == want[col].tobytes(), (name, col)
+    if c["flag"]:
+        np.testing.assert_array_equal(got[c["flag"]], matched)
+    for col in c["cols"]:
+        out = got[f"out_{col}"]
+        want = np.where(matched, dim.payload[col][idx],
+                        np.asarray(c["default"])).astype(out.dtype)
+        np.testing.assert_array_equal(out, want, err_msg=col)
+
+
+def _ssb_segment(query, sparse_part=False):
+    """The fused segment of ``query``'s row-synchronized members over SF1
+    dimension tables (small fact table), and its runner."""
+    from repro.core.backend.jax_backend import JaxBackend
+    data = generate(lineorder_rows=1024, customers=30_000, suppliers=2_000,
+                    parts=200_000, seed=3)
+    if sparse_part:
+        # part keys 1009 apart: the part table falls back to fmix32
+        data.part["p_partkey"] = data.part["p_partkey"] * 1009
+        data.lineorder["lo_partkey"] = data.lineorder["lo_partkey"] * 1009
+    members = {"Q4.1": ["lookup_customer", "lookup_supplier", "lookup_part",
+                        "lookup_date", "filter_unmatched", "project",
+                        "profit_expr"],
+               "Q1.1": ["lookup_date", "filter", "revenue_expr"]}[query]
+    qf = BUILDERS[query](data)
+    seg = FusedSegment.from_components(
+        [qf.flow.component(m) for m in members])
+    return data, JaxBackend().compile_segment(seg)
+
+
+@pytest.mark.parametrize("query,sparse_part,gathers", [
+    ("Q4.1", False, 4), ("Q1.1", False, 1), ("Q4.1", True, None)])
+def test_fused_segment_gathers_once_per_returned_column(query, sparse_part,
+                                                        gathers):
+    """Lowered for the CPU, the fused Q4.1 segment over SSB's direct SF1
+    tables holds one gather per Lookup (four payloads) and Q1.1's one (its
+    ``d_ok`` flag rides on ``d_year``): no ``slot_idx`` or ``qualifies``
+    gather.  The tables' sizes all differ, so no two gathers share one
+    outlined function.  A hashed part table keeps its probe loop."""
+    import jax.numpy as jnp
+    data, runner = _ssb_segment(query, sparse_part)
+    bucket = 2048
+    entries, total = runner.pack_layout(
+        bucket, [(c, data.lineorder[c].dtype) for c in sorted(runner.inputs)])
+    dims = runner.device_dims()
+    text = runner._jit.lower((bucket, tuple(entries)),
+                             jnp.zeros((total,), jnp.uint8), {},
+                             dims).as_text()
+    n = text.count('"stablehlo.gather"(')
+    if gathers is not None:
+        assert n == gathers
+        assert "stablehlo.while" not in text
+        assert runner._gathers == [1] * gathers
+    else:
+        assert "stablehlo.while" in text and n > 4
+        assert [p is None for p in runner._slots] == [False, False, True,
+                                                      False]
+
+
+# ---------------------------------------------------------------------------
 #  CacheArena
 # ---------------------------------------------------------------------------
 def test_arena_reuse_hit_miss_counters():

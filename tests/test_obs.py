@@ -516,16 +516,18 @@ def test_fused_q41_counts_probe_passes_per_lookup(q41_runner, data,
     from repro.kernels.hash_join import hash_build, probe_lengths_np
     table = hash_build((data.part["p_partkey"],))
     need = probe_lengths_np(table, (data.lineorder["lo_partkey"],)).sum()
+    dense = part_keys == "dense"
     assert probes["part"] == {
         "rows": 5000, "padded_rows": 8192, "passes": table["max_probes"],
-        "direct": int(part_keys == "dense"),
-        "mean_probes": table["mean_probes"], "slots": table["table_size"],
-        "need": need}
+        "direct": int(dense), "mean_probes": table["mean_probes"],
+        "slots": table["table_size"], "gathers": 1 if dense else 2,
+        "slot_addressed": int(dense), "need": need}
     for name, p in probes.items():
         if name == "part" and part_keys == "sparse":
             assert p["passes"] > 1 and p["rows"] < p["need"]
         else:
             assert (p["passes"], p["direct"], p["need"]) == (1, 1, 5000)
+            assert (p["slot_addressed"], p["gathers"]) == (1, 1)
     # each row of each Lookup needs at least one pass, at most all
     for p in probes.values():
         assert p["rows"] <= p["need"] <= p["rows"] * p["passes"]
@@ -534,6 +536,42 @@ def test_fused_q41_counts_probe_passes_per_lookup(q41_runner, data,
     assert {("transfer", "h2d.pack"), ("transfer", "h2d.upload"),
             ("wait", "h2d.ready"), ("transfer", "h2d"),
             ("dispatch", "segment"), ("transfer", "d2h")} <= names
+
+
+@pytest.mark.parametrize("part_keys", ["dense", "sparse"])
+def test_probe_counter_counts_gathers_and_slot_addressing(data, part_keys):
+    """A Lookup on a direct table reads slot-addressed arrays: one gather a
+    row per returned column, or one where it returns none (Q1.1's date
+    Lookup returns one column and its match bit rides on it).  On a hashed
+    table it gathers ``qualifies`` and each payload after the probe loop."""
+    from repro.etl.components import DimTable, Lookup
+    part = _sparse_part(data) if part_keys == "sparse" else data
+    table = DimTable(part.part["p_partkey"],
+                     {"p_mfgr": part.part["p_mfgr"],
+                      "p_brand1": part.part["p_brand1"]}, name="part")
+    lookups = [
+        Lookup("lk_date", DimTable(data.date["d_datekey"],
+                                   {"d_year": data.date["d_year"]},
+                                   name="date"),
+               "lo_orderdate", {"d_year": "d_year"}, matched_flag="d_ok"),
+        Lookup("lk_part", table, "lo_partkey",
+               {"p_mfgr": "p_mfgr", "p_brand1": "p_brand1"}),
+        Lookup("lk_supp", DimTable(data.supplier["s_suppkey"], {},
+                                   name="supplier"),
+               "lo_suppkey", {}, matched_flag="s_ok"),
+    ]
+    from repro.core.backend.jax_backend import JaxBackend
+    from repro.etl.components import FusedSegment
+    runner = JaxBackend().compile_segment(
+        FusedSegment.from_components(lookups))
+    events = _segment_call(runner, part, traced=True)
+    probes = {e["name"]: e["args"] for e in events
+              if (e["ph"], e["cat"]) == ("C", "probe")}
+    got = {name: (p["slot_addressed"], p["gathers"])
+           for name, p in probes.items()}
+    sparse = part_keys == "sparse"
+    assert got == {"date": (1, 1), "supplier": (1, 1),
+                   "part": (0, 3) if sparse else (1, 2)}
 
 
 # ---------------------------------------------------------------------------

@@ -15,7 +15,8 @@ smoke (``chip_smoke.py``) feeds it:
 * the fused TPC-H Q1 segment at a 2^21-row chunk bucket, with ``charge``
   computed in two words under ``wide.charge``;
 * one fused Q4.1 segment kernel over SF1 dimension tables at a 2^21-row
-  chunk bucket, with each Lookup's one-pass probe under its scope.
+  chunk bucket, each Lookup a gather from slot-addressed payloads under
+  its scope.
 
 A compile that passes is not a chip run: nothing here executes.  The
 topology is described inside a fixture (only the worker that runs these
@@ -173,7 +174,9 @@ def fused_q41(one_chip):
         bucket, [(c, data.lineorder[c].dtype) for c in sorted(runner.inputs)])
     dims = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
                         runner.device_dims())
-    assert max(int(d["slot_idx"].shape[0]) for d in dims) == 1 << 18
+    # every SSB table is direct: the kernel reads slot-addressed payloads
+    assert max(int(a.shape[0]) for d in dims for a in d["cols"].values()
+               ) == 1 << 18
     return runner._jit.lower(
         (bucket, tuple(entries)), _spec(one_chip, (total,), jnp.uint8), {},
         dims).compile()
@@ -184,12 +187,14 @@ def test_fused_q41_segment_compiles_at_sf1(fused_q41):
 
 
 def test_fused_q41_segment_names_one_probe_loop_per_lookup(fused_q41):
-    """On the chip's compiler, each Lookup's probe over its direct SF1
-    table is one pass with no loop, and has top-level ops under
-    ``lookup.<dim>/probe``: the ops the profiler's device events name."""
+    """On the chip's compiler, each Lookup over its direct SF1 table is a
+    range check and one gather from its slot-addressed payload, with no
+    loop, and has top-level ops under ``lookup.<dim>/``: the ops the
+    profiler's device events name."""
     from repro.obs.trace import entry_scopes
     program, ops = entry_scopes(fused_q41.as_text())
     assert program == "jit__kernel"
     assert not [op for op in ops if op.startswith("%while")]
-    assert {s for s in ops.values() if s.endswith("/probe")} == {
-        f"lookup.{d}/probe" for d in ("customer", "date", "part", "supplier")}
+    assert {s.split("/")[0] for s in ops.values()
+            if s.startswith("lookup.")} == {
+        f"lookup.{d}" for d in ("customer", "date", "part", "supplier")}
