@@ -5,9 +5,9 @@ ridge = 197e12 / 819e9 ~ 241 FLOP/byte).
 
 Emits CSV: kernel,shape,ref_ms_cpu,flops,bytes,intensity,v5e_bound
 
-``smoke()`` is the CI part: the hash_join probe against a searchsorted
-oracle, and interpret-vs-reference equality sweeps for radix_groupby and
-segment_sum — the Pallas kernel BODY validated on CPU — plus the full
+``smoke()`` is the CI part: a Lookup over a hash_join table against a
+searchsorted oracle, and interpret-vs-reference equality sweeps for
+radix_groupby and segment_sum — the Pallas kernel BODY validated on CPU — plus the full
 intensity CSV written to ``KERNELS_<tag>.csv`` for upload next to the BENCH
 json.
 """
@@ -21,12 +21,33 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.flash_attention import flash_attention_ref
-from repro.kernels.hash_join import hash_build, hash_probe
+from repro.core.backend.jax_dims import DimLookup, Plan
+from repro.kernels.hash_join import hash_build
 from repro.kernels.mamba_scan import mamba_scan_ref
 from repro.kernels.radix_groupby import radix_groupby
 from repro.kernels.segment_sum import segment_sum, segment_sum_ref
 
 RIDGE = 197e12 / 819e9
+
+
+def _row_lookup(ht):
+    """A jitted Lookup returning each key's build row (-1 where absent)
+    over ``hash_build``'s table ``ht``: its slot-addressed column of row
+    numbers is ``slot_idx``.  The ``DimLookup`` is made from its leaves, so
+    no upload counts as a dimension table's."""
+    si = jnp.asarray(ht["slot_idx"])
+    arrays = {"cols": {"row": si}}
+    if ht["base"] is None:
+        arrays.update(slot_keys=tuple(jnp.asarray(k)
+                                      for k in ht["slot_keys"]),
+                      slot_idx=si)
+    look = DimLookup.tree_unflatten(
+        Plan(ht["base"], ht["table_size"], ht["max_probes"], None,
+             (("row", "row"),), -1, None), (arrays,))
+    return lambda p: _lookup_jit(look, p)["row"]
+
+
+_lookup_jit = jax.jit(lambda look, p: look(p))
 
 
 def _time(fn, *args):
@@ -91,19 +112,17 @@ def run() -> list:
                f"{ms:.1f},{flops:.2e},{byts:.2e},{flops/byts:.0f},"
                f"{'compute' if flops/byts > RIDGE else 'memory'}")
 
-    # hash-join probe: the Lookup component at SSB dimension scale
+    # hash-join probe: the Lookup component at SSB dimension scale, on an
+    # fmix32 table (sparse keys), returning one column
     Dd, Np_ = 1 << 15, 1 << 20
     keys = np.sort(rng.choice(1 << 22, size=Dd, replace=False)).astype(np.int64)
     ht = hash_build((keys,))
-    slot_keys = tuple(jnp.asarray(x) for x in ht["slot_keys"])
-    slot_idx = jnp.asarray(ht["slot_idx"])
     probes = jnp.asarray(rng.integers(0, 1 << 22, Np_).astype(np.int64))
-    ms = _time(lambda p: hash_probe(slot_keys, slot_idx, (p,),
-                                    ht["max_probes"], ht["base"]),
-               probes)
+    ms = _time(_row_lookup(ht), probes)
     mp = ht["max_probes"]
     flops = 1.0 * Np_ * (6 + 4 * mp)     # fmix32 + per-step cmp/mask chain
-    byts = (Np_ + Np_ * mp * 2 + ht["table_size"] * 2) * 4
+    # probe keys in, two gathers a pass, one of the column, the column out
+    byts = (Np_ * (3 + 2 * mp) + ht["table_size"] * 3) * 4
     out.append(f"kernels.hash_join,D{Dd}xN{Np_}xp{mp},"
                f"{ms:.1f},{flops:.2e},{byts:.2e},{flops/byts:.1f},memory")
 
@@ -123,7 +142,7 @@ def run() -> list:
 
 
 def smoke(data=None):
-    """CI part: the hash probe vs a searchsorted oracle, Pallas kernel-body
+    """CI part: a Lookup vs a searchsorted oracle, Pallas kernel-body
     (interpret) vs pure-jnp reference equality for the reduce kernels, then
     the intensity CSV written to ``KERNELS_<tag>.csv`` (uploaded with the
     BENCH json artifacts)."""
@@ -135,17 +154,13 @@ def smoke(data=None):
         keys = np.sort(rng.choice(5_000, size=700, replace=False)
                        ).astype(np.int64)
         ht = hash_build((keys,))
-        sk = tuple(jnp.asarray(x) for x in ht["slot_keys"])
-        si = jnp.asarray(ht["slot_idx"])
         probes = jnp.asarray(rng.integers(0, 6_000, 3_000).astype(np.int64))
-        i_r, f_r = hash_probe(sk, si, (probes,), ht["max_probes"],
-                              ht["base"])
+        rows = np.asarray(_row_lookup(ht)(probes))
         # vs the searchsorted oracle (found rows index the leftmost match)
         pv = np.asarray(probes)
         ss = np.clip(np.searchsorted(keys, pv), 0, len(keys) - 1)
         hit = keys[ss] == pv
-        assert np.array_equal(np.asarray(f_r), hit)
-        assert np.array_equal(np.asarray(i_r)[hit], ss[hit])
+        assert np.array_equal(rows, np.where(hit, ss, -1))
         print(f"smoke.kernels.hash_join,ok,probes={len(pv)},"
               f"hits={int(hit.sum())},max_probes={ht['max_probes']}")
     except Exception:

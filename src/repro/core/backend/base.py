@@ -4,8 +4,8 @@ A ``Backend`` supplies the heavy per-operator kernels the ETL component
 library dispatches through (`etl/components.py`):
 
     filter_mask          row predicate -> boolean keep-mask
-    searchsorted_probe   dimension-table probe (sorted keys + searchsorted)
-    lookup_gather        payload gather with unmatched-default substitution
+    lookup               dimension-table Lookup: returned columns (unmatched
+                         rows get the default) and the match flag
     eval_expression      derived-column computation
     groupby_reduce       group-by aggregation (sum/avg/min/max/count)
     sort_rows            stable multi-key row ordering (lexsort)
@@ -116,13 +116,13 @@ class Backend:
         """Evaluate ``fn(cache_view, rows)`` to a derived column."""
         raise NotImplementedError
 
-    def searchsorted_probe(self, dim, vals) -> Tuple[object, object]:
-        """Probe a ``DimTable``: returns (row_idx, matched_mask)."""
-        raise NotImplementedError
-
-    def lookup_gather(self, dim, dim_col: str, idx, matched, default):
-        """Gather a payload column at ``idx``; unmatched rows get
-        ``default``."""
+    def lookup(self, dim, vals, return_cols: Mapping[str, str], default,
+               matched_flag: Optional[str] = None) -> Dict[str, object]:
+        """Look ``vals`` up in a ``DimTable``: ``{output column: array}`` for
+        each ``return_cols`` entry (output column -> payload column), the
+        payload of the key's first qualifying row and ``default`` where
+        there is none, and, where ``matched_flag`` names one, the match
+        flag."""
         raise NotImplementedError
 
     def groupby_reduce(self, keys: Sequence, values: Mapping[str, Tuple[object, str]],
@@ -288,15 +288,10 @@ def _run_segment_host(bk: Backend, ops, cache) -> None:
             note_written(out_col)
         elif kind == "lookup":
             _, dim, key_col, return_cols, default, matched_flag = op
-            idx, matched = bk.searchsorted_probe(dim, get(key_col))
-            idx, matched = bk.to_host(idx), bk.to_host(matched)
-            for out_name, dim_col in return_cols.items():
-                env[out_name] = bk.to_host(
-                    bk.lookup_gather(dim, dim_col, idx, matched, default))
-                note_written(out_name)
-            if matched_flag:
-                env[matched_flag] = np.asarray(matched, dtype=bool)
-                note_written(matched_flag)
+            for name, v in bk.lookup(dim, get(key_col), return_cols,
+                                     default, matched_flag).items():
+                env[name] = bk.to_host(v)
+                note_written(name)
         elif kind == "project":
             live = live & set(op[1])
             for k in list(env):

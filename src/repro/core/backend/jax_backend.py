@@ -1,19 +1,11 @@
 """Accelerated operator backend: jitted JAX kernels + device-resident columns.
 
 Kernels:
-  - ``searchsorted_probe`` / ``lookup_gather`` — probe over a device-cached
-    dimension table (keys/qualifies/payload are device_put once per table and
-    reused across every chunk).  Default route is the ``kernels/hash_join``
-    slot table (host-built once per DimTable, probed through XLA; slot
-    ``key - min`` for dense integer keys, else fmix32 open addressing;
-    probes handle arbitrary key order and multi-column keys; inside a fused
-    segment a direct table's Lookup gathers once per returned column from
-    slot-addressed payloads, ``_dim_slots``);
-    ``REPRO_JOIN_IMPL=searchsorted`` selects the legacy jitted binary search
-    over the sorted keys.  Both return the same (index, matched) pair
-    bit-for-bit: the hash build keeps the FIRST occurrence of a duplicate
-    key, which over the DimTable's sorted keys is exactly ``searchsorted``'s
-    leftmost index.
+  - ``lookup`` — a dimension table's device form and the one traced read
+    of it live in ``jax_dims``: slot ``key - min`` for dense integer keys,
+    else fmix32 open addressing (``kernels/hash_join``), then one gather per
+    returned column from slot-addressed arrays.  The unfused Lookup jits
+    that read; the fused segment kernel inlines it.
   - ``groupby_reduce`` — dense integer key spaces route through
     ``kernels/radix_groupby`` (radix-partitioned one-hot matmul, no sort);
     sparse/non-integer/huge key spaces fall back to the legacy lexsort +
@@ -56,9 +48,10 @@ from ...obs import trace as obs_trace
 from .. import config, faults, wideint
 from ..expr import ColumnsView, Expr
 from ..wideint import Wide, WideColumn
-from ..shared_cache import (GLOBAL_ARENA, is_host_column, record_dim_upload,
+from ..shared_cache import (GLOBAL_ARENA, is_host_column,
                             record_segment_compile, record_transfer)
 from .base import AGG_OPS, Backend, SegmentEnv
+from .jax_dims import DimLookup, device_form
 
 
 def _checkout_cache_dir() -> Optional[Path]:
@@ -156,57 +149,36 @@ class JaxBackend(Backend):
     #: group-id space the radix kernel partitions)
     _DENSE_MAX_ROWS = 1 << 24
     _DENSE_MAX_CELLS = 1 << 20
-    #: kernel degradation ladders (left = fastest, right = safest): on a
-    #: non-transient kernel failure the route walks ONE rung right and stays
-    #: there for this backend instance's lifetime.  Every rung is
+    #: the group-by's degradation ladder (left = fastest, right = safest):
+    #: on a non-transient kernel failure the route walks ONE rung right and
+    #: stays there for this backend instance's lifetime.  Every rung is
     #: bit-identical to its neighbours by the kernels' own equivalence tests.
     #: Interpret mode is no rung: on a chip it is a silent slowdown of orders
-    #: of magnitude (the group-by keeps it selectable explicitly, for CPU
-    #: tests, and a failing explicit interpret route degrades like pallas).
-    _JOIN_LADDER = ("reference", "searchsorted")
+    #: of magnitude (it stays selectable explicitly, for CPU tests, and a
+    #: failing explicit interpret route degrades like pallas).
     _GROUPBY_LADDER = ("pallas", "reference", "sort")
 
     def __init__(self) -> None:
         import jax                       # deferred: registry creates lazily
         import jax.numpy as jnp
         place_compile_cache(jax)
-        from ...kernels.hash_join import (hash_build, hash_probe,
-                                          hash_probe_ref, probe_lengths_np)
         from ...kernels.radix_groupby import radix_groupby
         from ...kernels.segment_sum import segment_sum
         self._jax = jax
         self._jnp = jnp
         self._segment_sum = segment_sum
-        self._hash_build = hash_build
-        self._hash_probe = hash_probe
-        self._hash_probe_ref = hash_probe_ref
-        self._probe_lengths_np = probe_lengths_np
         self._radix_groupby = radix_groupby
         self._segsum_impl = config.segsum_impl()
-
-        def _probe(keys, qualifies, vals):
-            idx = jnp.searchsorted(keys, vals)
-            idx = jnp.clip(idx, 0, keys.shape[0] - 1)
-            matched = (keys[idx] == vals) & qualifies[idx]
-            return idx, matched
-
-        def _gather(payload, idx, matched, default):
-            return jnp.where(matched, payload[idx],
-                             jnp.asarray(default, payload.dtype))
-
-        self._probe_jit = jax.jit(_probe)
-        self._gather_jit = jax.jit(_gather)
+        self._lookup_jit = jax.jit(lambda look, vals: look(vals))
         # device views keyed by cache, invalidated by cache.version — a
         # stale view (pre-compact/add_column) is never reused
         self._views: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
         self._views_lock = threading.Lock()
-        self._dims_lock = threading.Lock()
-        # sticky degradation-ladder routes; None => follow the env config
-        self._join_route: Optional[str] = None
+        # the sticky group-by rung; None => follow the env config
         self._groupby_route: Optional[str] = None
 
-    def _degraded_impl(self, kind: str, impl: str, exc: BaseException):
-        """Next rung of the ``kind`` kernel ladder after ``impl`` failed with
+    def _degraded_impl(self, impl: str, exc: BaseException):
+        """Next rung of the group-by ladder after ``impl`` failed with
         ``exc``, or ``None`` when the failure must propagate instead:
         transient faults escalate so chunk-level replay retries the SAME
         route; explicitly injected permanent/poison faults abort promptly;
@@ -217,28 +189,23 @@ class JaxBackend(Backend):
                 or isinstance(exc, (faults.PermanentFault, faults.PoisonFault))
                 or not config.degrade_enabled()):
             return None
-        ladder = self._JOIN_LADDER if kind == "join" else self._GROUPBY_LADDER
-        impl = self._resolve_impl(kind, impl)
+        ladder = self._GROUPBY_LADDER
+        impl = self._resolve_impl(impl)
         i = ladder.index(impl) if impl in ladder else 0   # interpret => rung 0
         if i + 1 >= len(ladder):
             return None
         nxt = ladder[i + 1]
-        faults.record_degradation("kernel", src=f"{kind}[{impl}]", dst=nxt,
-                                  component=kind, error=repr(exc))
-        if kind == "join":
-            self._join_route = nxt
-        else:
-            self._groupby_route = nxt
+        faults.record_degradation("kernel", src=f"groupby[{impl}]", dst=nxt,
+                                  component="groupby", error=repr(exc))
+        self._groupby_route = nxt
         return nxt
 
-    def _resolve_impl(self, kind: str, impl: str) -> str:
-        """The rung ``auto`` stands for: the XLA hash probe for the join,
-        the Pallas group-by on a TPU and its jnp reference elsewhere."""
+    def _resolve_impl(self, impl: str) -> str:
+        """The rung ``auto`` stands for: the Pallas group-by on a TPU and
+        its jnp reference elsewhere."""
         if impl != "auto":
             return impl
-        if kind == "join" or self._jax.default_backend() != "tpu":
-            return "reference"
-        return "pallas"
+        return "pallas" if self._jax.default_backend() == "tpu" else "reference"
 
     def _view(self, cache) -> _DeviceCacheView:
         with self._views_lock:
@@ -332,136 +299,6 @@ class JaxBackend(Backend):
         align = max(1, self.batch_align)
         units = max(1, -(-int(n) // align))
         return align * (1 << (units - 1).bit_length())
-
-    # ------------------------------------------------------- dim-table cache
-    def _dim_device(self, dim) -> Dict[str, object]:
-        """Device-resident mirror of a DimTable, device_put once per table
-        (payload columns lazily) and cached on the table itself.  Locked:
-        concurrent §4.3 probes of one table must not duplicate uploads (or
-        double-count h2d bytes)."""
-        dev = dim.__dict__.get("_jax_device_cache")
-        if dev is None:
-            with self._dims_lock:
-                dev = dim.__dict__.get("_jax_device_cache")
-                if dev is None:
-                    record_dim_upload(dim.keys.nbytes)
-                    record_dim_upload(dim.qualifies.nbytes)
-                    dev = dim.__dict__["_jax_device_cache"] = {
-                        "keys": self.asarray(dim.keys),
-                        "qualifies": self.asarray(dim.qualifies),
-                        "payload": {},
-                    }
-        return dev
-
-    def _dim_payload(self, dim, col: str):
-        dev = self._dim_device(dim)
-        got = dev["payload"].get(col)
-        if got is None:
-            with self._dims_lock:
-                got = dev["payload"].get(col)
-                if got is None:
-                    record_dim_upload(dim.payload[col].nbytes)
-                    got = dev["payload"][col] = self.asarray(dim.payload[col])
-        return got
-
-    def _dim_hash(self, dim) -> Dict[str, object]:
-        """Slot table over the DimTable's keys: built once on host
-        (``kernels/hash_join.hash_build``), slot arrays device_put once,
-        cached on the table itself like ``_dim_device``.  The build picks the
-        slot function from the keys: direct (slot ``key - base``, one pass)
-        for an integer key column whose span fits in 31 bits and whose table
-        is at most ``DIRECT_MAX_RATIO`` times the hashed one, else fmix32
-        linear probing (``base`` None).  ``max_probes`` (the static probe
-        bound) and ``base`` stay Python values — they must never become
-        tracers; ``host`` (the build's own arrays), ``key_range`` (of the
-        sorted keys), ``mean_probes`` and ``table_size`` feed the probe
-        counters."""
-        ht = dim.__dict__.get("_jax_hash_cache")
-        if ht is None:
-            with self._dims_lock:
-                ht = dim.__dict__.get("_jax_hash_cache")
-                if ht is None:
-                    built = self._hash_build((np.asarray(dim.keys),))
-                    for k in built["slot_keys"]:
-                        record_dim_upload(np.asarray(k).nbytes)
-                    record_dim_upload(np.asarray(built["slot_idx"]).nbytes)
-                    ht = dim.__dict__["_jax_hash_cache"] = {
-                        "slot_keys": tuple(self.asarray(k)
-                                           for k in built["slot_keys"]),
-                        "slot_idx": self.asarray(built["slot_idx"]),
-                        "max_probes": int(built["max_probes"]),
-                        "base": built["base"],
-                        "mean_probes": float(built["mean_probes"]),
-                        "table_size": int(built["table_size"]),
-                        "host": built,
-                        "key_range": ((int(dim.keys[0]), int(dim.keys[-1]))
-                                      if len(dim.keys) else None),
-                    }
-        return ht
-
-    def _dim_slots(self, dim, cols: Sequence[str], default,
-                   flagged: bool) -> Tuple[Dict[str, object], Optional[tuple]]:
-        """A Lookup's slot-addressed arrays over a direct table
-        (``_dim_hash`` with a ``base``): ``({"cols": {dim column: [T]
-        array}[, "matched": [T] int32]}, carrier)``.  Slot ``s`` is matched
-        where it holds a row (the first with key ``base + s``) that
-        qualifies.  A column holds its payload where matched and ``default``
-        (in the payload's device dtype) elsewhere, so one gather at ``key -
-        base`` answers the Lookup.  Where ``flagged``, the match bit rides on
-        the carrier: the first integer column whose span ``hi - lo + 1``
-        fits its device dtype holds ``payload - lo + 1`` where matched and 0
-        elsewhere (``carrier`` is ``(column, lo)``); with none, ``matched``
-        is 1 where matched.  Each array is built on the host once and cached
-        on the table."""
-        slot_idx = self._dim_hash(dim)["host"]["slot_idx"]
-        carrier = None
-        if flagged:
-            for col in cols:
-                cd = np.dtype(self._jax.dtypes.canonicalize_dtype(
-                    dim.payload[col].dtype))
-                r = wideint.column_range(dim.payload[col])
-                if (r is not None and cd != np.bool_
-                        and r[1] - r[0] + 1 <= np.iinfo(cd).max):
-                    carrier = (col, r[0])
-                    break
-        out = {"cols": {col: self._dim_slot(
-            dim, slot_idx, col, "offset" if carrier and col == carrier[0]
-            else "value", default) for col in cols}}
-        if flagged and carrier is None:
-            out["matched"] = self._dim_slot(dim, slot_idx, None, "matched")
-        return out, carrier
-
-    def _dim_slot(self, dim, slot_idx: np.ndarray, col: Optional[str],
-                  kind: str, default=None):
-        """One of ``_dim_slots``'s arrays (``kind`` "value", "offset" or
-        "matched"), cached on the table."""
-        key = (kind, col, default if kind == "value" else None)
-        cache = dim.__dict__.setdefault("_jax_slot_cache", {})
-        got = cache.get(key)
-        if got is None:
-            with self._dims_lock:
-                got = cache.get(key)
-                if got is None:
-                    row = np.maximum(slot_idx, 0)
-                    matched = (slot_idx >= 0) & dim.qualifies[row]
-                    if kind == "matched":
-                        host = matched.astype(np.int32)
-                    else:
-                        payload = dim.payload[col]
-                        cd = np.dtype(self._jax.dtypes.canonicalize_dtype(
-                            payload.dtype))
-                        if kind == "offset":
-                            lo = wideint.column_range(payload)[0]
-                            host = np.where(
-                                matched,
-                                payload[row].astype(np.int64) - lo + 1,
-                                0).astype(cd)
-                        else:
-                            fill = np.asarray(self._jnp.asarray(default, cd))
-                            host = np.where(matched, payload[row], fill)
-                    record_dim_upload(host.nbytes)
-                    got = cache[key] = self.asarray(host, name=col)
-        return got
 
     # ---------------------------------------------------- DSL expression jit
     def _expr_runner(self, expr: Expr):
@@ -569,44 +406,18 @@ class JaxBackend(Backend):
         out = fn(self._view(cache), rows)
         return out if isinstance(out, np.ndarray) else self._jnp.asarray(out)
 
-    def searchsorted_probe(self, dim, vals):
-        if len(dim.keys) == 0:
-            n = len(vals)
-            return (np.zeros(n, dtype=np.int64),
-                    np.zeros(n, dtype=bool))
-        dev = self._dim_device(dim)
+    def lookup(self, dim, vals, return_cols: Mapping[str, str], default,
+               matched_flag: Optional[str] = None) -> Dict[str, object]:
+        look = DimLookup(device_form(dim, self.asarray), return_cols,
+                         default, matched_flag)
         v = self.asarray(vals)
         n = v.shape[0]
         pad = self.bucket_rows(n) - n          # bound jit retraces per shape
         if pad:
-            v = self._jnp.concatenate([v, self._jnp.full((pad,), dim.keys[0],
-                                                         dtype=v.dtype)])
-        impl = self._resolve_impl("join",
-                                  self._join_route or config.join_impl())
-        while True:
-            try:
-                if faults.active():
-                    faults.inject("kernel", component=f"join[{impl}]")
-                if impl == "searchsorted":
-                    idx, matched = self._probe_jit(dev["keys"],
-                                                   dev["qualifies"], v)
-                else:
-                    ht = self._dim_hash(dim)
-                    idx, found = self._hash_probe(
-                        ht["slot_keys"], ht["slot_idx"], (v,),
-                        ht["max_probes"], ht["base"])
-                    matched = found & dev["qualifies"][idx]
-                break
-            except BaseException as e:
-                nxt = self._degraded_impl("join", impl, e)
-                if nxt is None:
-                    raise
-                impl = nxt
-        return idx[:n], matched[:n]
-
-    def lookup_gather(self, dim, dim_col: str, idx, matched, default):
-        payload = self._dim_payload(dim, dim_col)
-        return self._gather_jit(payload, idx, matched, default)
+            v = self._jnp.concatenate([v, self._jnp.zeros((pad,), v.dtype)])
+        if faults.active():
+            faults.inject("kernel", component="join")
+        return {name: a[:n] for name, a in self._lookup_jit(look, v).items()}
 
     def groupby_reduce(self, keys: Sequence, values: Mapping[str, Tuple[object, str]],
                        n_rows: int) -> Tuple[List[object], Dict[str, object]]:
@@ -641,15 +452,15 @@ class JaxBackend(Backend):
                     aggs[out] = jnp.max(_narrow(vals))[None]
             return [], aggs
         keys_d = [self.asarray(k) for k in keys]
-        impl = self._resolve_impl("groupby",
-                                  self._groupby_route or config.groupby_impl())
+        impl = self._resolve_impl(self._groupby_route
+                                  or config.groupby_impl())
         while impl != "sort":
             try:
                 if faults.active():
                     faults.inject("kernel", component=f"groupby[{impl}]")
                 dense = self._groupby_dense(keys_d, values, n, impl)
             except BaseException as e:
-                nxt = self._degraded_impl("groupby", impl, e)
+                nxt = self._degraded_impl(impl, e)
                 if nxt is None:
                     raise
                 impl = nxt
@@ -902,18 +713,6 @@ class _JaxSegmentRunner:
         #: merges no host copy of them and uploads none again)
         self._outputs = self._written + sorted(
             self._defer_cols - set(self._written))
-        #: Lookup route inside the fused kernel: hash-probe (traced inline
-        #: via hash_probe_ref — it fuses into the one XLA computation) unless
-        #: pinned back to the legacy binary search
-        self._join_impl = config.join_impl()
-        #: per Lookup, in op order: its hash table (``_dim_hash``: the static
-        #: loop bound and probe statistics), or None on the searchsorted route
-        self._tables: List[Optional[dict]] = []
-        #: per Lookup, in op order: its slot-addressed plan on a direct
-        #: table (``device_dims``), else None; and the gathers a row makes
-        #: outside the probe loop
-        self._slots: List[Optional[dict]] = []
-        self._gathers: List[int] = []
         #: per Lookup, in op order: the name of its scope and probe counter,
         #: and its key column
         self._lookup_names = [op[1].name or op[2] for op in self.ops
@@ -930,7 +729,8 @@ class _JaxSegmentRunner:
         #: per layout, ``(program, layout key, {ENTRY op: named scope})`` of
         #: the compiled kernel; built only while a tracer is in scope
         self._scope_maps: Dict[tuple, tuple] = {}
-        self._dims = None            # built once: stable per (segment, backend)
+        #: per Lookup, in op order, its ``DimLookup`` (``device_dims``)
+        self._dims: Optional[List[DimLookup]] = None
         #: (DimTable id, payload column) -> the payload's observed range
         self._payload_ranges: Dict[tuple, Optional[Tuple[int, int]]] = {}
         self.kernel_calls = 0
@@ -985,17 +785,8 @@ class _JaxSegmentRunner:
                     else:
                         env[op[1]] = jnp.asarray(op[2](view, rows))
             elif kind == "lookup":
-                _, _, key_col, return_cols, default, matched_flag = op
-                d = dims[dim_i]
-                table = self._tables[dim_i]   # static (never traced)
                 with scope(f"lookup.{self._lookup_names[dim_i]}"):
-                    if self._slots[dim_i] is not None:
-                        self._lookup_slots(env, d, self._slots[dim_i],
-                                           key_col, return_cols, default,
-                                           matched_flag)
-                    else:
-                        self._lookup(env, d, table, key_col, return_cols,
-                                     default, matched_flag)
+                    env.update(dims[dim_i](env[op[2]]))
                 dim_i += 1
             elif kind == "project":
                 keep = set(op[1])
@@ -1015,75 +806,6 @@ class _JaxSegmentRunner:
         out = {name: env[name] for name in self._outputs if name in env}
         return out, keep_mask
 
-    def _lookup(self, env, d, table, key_col, return_cols, default,
-                matched_flag) -> None:
-        """One Lookup on a hashed table or the searchsorted route, traced
-        into the kernel: the probe under ``probe``, the qualifying and
-        payload gathers under ``gather``."""
-        jnp = self._jnp
-        scope = self._jax.named_scope
-        vals = env[key_col]
-        keys = d["keys"]
-        if keys.shape[0] == 0:        # static: degenerate dim table
-            matched = jnp.zeros(vals.shape[0], dtype=bool)
-            for out_name, dim_col in return_cols.items():
-                env[out_name] = jnp.full(
-                    vals.shape[0], default, d["payload"][dim_col].dtype)
-        else:
-            with scope("probe"):
-                if table:
-                    # hash-probe route, traced inline so the probe (one
-                    # pass on a direct table, else the open-addressing
-                    # loop) fuses into this one XLA computation
-                    idx, found = self._bk._hash_probe_ref(
-                        d["slot_keys"], d["slot_idx"], (vals,),
-                        table["max_probes"], table["base"])
-                else:
-                    idx = jnp.clip(jnp.searchsorted(keys, vals),
-                                   0, keys.shape[0] - 1)
-                    found = keys[idx] == vals
-            with scope("gather"):
-                matched = found & d["qualifies"][idx]
-                for out_name, dim_col in return_cols.items():
-                    payload = d["payload"][dim_col]
-                    env[out_name] = jnp.where(
-                        matched, payload[idx],
-                        jnp.asarray(default, payload.dtype))
-        if matched_flag:
-            env[matched_flag] = matched
-
-    def _lookup_slots(self, env, d, plan, key_col, return_cols, default,
-                      matched_flag) -> None:
-        """One Lookup on a direct table, from its slot-addressed arrays
-        (``JaxBackend._dim_slots``): the key's slot and range check under
-        ``probe``, one gather per returned column under ``gather`` (or one
-        of ``matched`` where no column carries the match bit)."""
-        jnp = self._jnp
-        scope = self._jax.named_scope
-        size = plan["size"]
-        with scope("probe"):
-            off = (env[key_col].astype(jnp.uint32)
-                   - jnp.uint32(plan["base"] % (1 << 32)))
-            in_range = off < jnp.uint32(size)
-            slot = (off & jnp.uint32(size - 1)).astype(jnp.int32)
-        with scope("gather"):
-            matched = None
-            if "matched" in d:
-                matched = in_range & (jnp.take(d["matched"], slot,
-                                               mode="clip") != 0)
-            carrier, lo = plan["carrier"] or (None, 0)
-            for out_name, dim_col in return_cols.items():
-                w = jnp.take(d["cols"][dim_col], slot, mode="clip")
-                fill = jnp.asarray(default, w.dtype)
-                if dim_col == carrier:
-                    matched = in_range & (w != 0)
-                    env[out_name] = jnp.where(
-                        matched, (w - 1) + jnp.asarray(lo, w.dtype), fill)
-                else:
-                    env[out_name] = jnp.where(in_range, w, fill)
-        if matched_flag:
-            env[matched_flag] = matched
-
     # ------------------------------------------------------------ execution
     def pack_layout(self, bucket: int, columns) -> Tuple[list, int]:
         """Staging-buffer layout of ``(name, host dtype)`` columns padded to
@@ -1097,48 +819,15 @@ class _JaxSegmentRunner:
             off += bucket * cd.itemsize
         return entries, off
 
-    def device_dims(self) -> list:
-        """Device mirrors of every looked-up DimTable, in op order — uploaded
-        once per table (cached on the table) and structurally identical per
-        call, so the pytree is built once and per-chunk Python cost stays
-        flat.  Also fixes each lookup's static probe bound, and on a direct
-        table its slot-addressed plan (``base``, ``size``, the match bit's
-        ``carrier``) and gathers a row."""
+    def device_dims(self) -> List[DimLookup]:
+        """Each Lookup's read of its table's device form, in op order: the
+        form is built and uploaded once per table (cached on the table),
+        and the list once per runner, so the kernel's argument pytree is
+        the same every call and per-chunk Python cost stays flat."""
         if self._dims is None:
-            bk = self._bk
-            dims, tables, slots, gathers = [], [], [], []
-            for op in self.ops:
-                if op[0] != "lookup":
-                    continue
-                _, dim, _, return_cols, default, matched_flag = op
-                ht = (bk._dim_hash(dim) if self._join_impl != "searchsorted"
-                      and len(dim.keys) > 0 else None)
-                tables.append(ht)         # None: legacy searchsorted
-                if ht is not None and ht["base"] is not None:
-                    entry, carrier = bk._dim_slots(
-                        dim, list(dict.fromkeys(return_cols.values())),
-                        default, bool(matched_flag))
-                    slots.append({"base": ht["base"],
-                                  "size": ht["table_size"],
-                                  "carrier": carrier})
-                    gathers.append(len(return_cols) + ("matched" in entry))
-                    dims.append(entry)
-                    continue
-                dev = bk._dim_device(dim)
-                entry = {
-                    "keys": dev["keys"],
-                    "qualifies": dev["qualifies"],
-                    "payload": {dcol: bk._dim_payload(dim, dcol)
-                                for dcol in return_cols.values()},
-                }
-                if ht is not None:
-                    entry["slot_keys"] = ht["slot_keys"]
-                    entry["slot_idx"] = ht["slot_idx"]
-                slots.append(None)
-                gathers.append(1 + len(return_cols))
-                dims.append(entry)
-            self._tables, self._slots, self._gathers = tables, slots, gathers
-            self._dims = dims
+            self._dims = [DimLookup(device_form(op[1], self._bk.asarray),
+                                    *op[3:])
+                          for op in self.ops if op[0] == "lookup"]
         return self._dims
 
     def __call__(self, cache) -> None:
@@ -1354,56 +1043,22 @@ class _JaxSegmentRunner:
         """A traced call's events: which compiled op belongs to which scope;
         per widened expression (or filter) the rows, the two's-complement
         bits of its widest node and the 8-bit limbs those bits make; and
-        per hash-probe Lookup the rows probed, the passes its loop ran
-        over them (1 on a ``direct`` table), the gathers a row makes
-        outside that loop (``gathers``), whether it read slot-addressed
-        arrays (``slot_addressed``), and, where its key column is a host
-        input of the call, the passes they need (``need``, walked on the
-        host)."""
+        per Lookup the rows probed and its ``DimLookup.counts``: the passes
+        its probe ran over them (1 on a ``direct`` table), the gathers a row
+        makes outside that loop, and, where its key column is a host input
+        of the call, the passes they need (``need``, walked on the host)."""
         program, key, ops = self._scope_map(layout, args)
         obs_trace.instant("program", "scopes", program=program, layout=key,
                           ops=ops)
         for name, bits, limbs in wide_counts:
             obs_trace.counter("wide", name, rows=n, bits=bits, limbs=limbs)
         with obs_trace.span("program", "probe.count"):
-            for name, key_col, table, plan, gathers in zip(
-                    self._lookup_names, self._lookup_keys, self._tables,
-                    self._slots, self._gathers):
-                if not table:
-                    continue
-                counts = {}
-                vals = host_cols.get(key_col)
-                if vals is not None and key_col not in self._written:
-                    counts["need"] = self._probe_need(table, vals)
-                obs_trace.counter(
-                    "probe", name, rows=n, padded_rows=bucket,
-                    passes=table["max_probes"],
-                    direct=int(table["base"] is not None),
-                    mean_probes=table["mean_probes"],
-                    slots=table["table_size"], gathers=gathers,
-                    slot_addressed=int(plan is not None), **counts)
-
-    def _probe_need(self, table: dict, vals: np.ndarray) -> int:
-        """The passes the probe loop needs over ``vals``, summed: each key's
-        probe length looked up in a table over the dimension's key range
-        (walked once, on first use, where the range is at most four times
-        the slots); keys outside it are walked row by row."""
-        walk = self._bk._probe_lengths_np
-        cached = table.get("lengths")
-        if cached is None:
-            lo, hi = table["key_range"] or (0, -1)
-            lengths = None
-            if 0 < hi - lo + 1 <= 4 * table["table_size"]:
-                lengths = walk(table["host"], (np.arange(lo, hi + 1),))
-            cached = table["lengths"] = (lo, lengths)
-        lo, lengths = cached
-        if lengths is None:
-            return int(walk(table["host"], (vals,)).sum())
-        inside = (vals >= lo) & (vals < lo + len(lengths))
-        if inside.all():
-            return int(lengths[vals - lo].sum(dtype=np.int64))
-        return int(lengths[vals[inside] - lo].sum(dtype=np.int64)
-                   + walk(table["host"], (vals[~inside],)).sum())
+            for name, key_col, look in zip(
+                    self._lookup_names, self._lookup_keys, self.device_dims()):
+                vals = (host_cols.get(key_col)
+                        if key_col not in self._written else None)
+                obs_trace.counter("probe", name, rows=n, padded_rows=bucket,
+                                  **look.counts(vals))
 
     def stats(self) -> Dict[str, int]:
         return {"kernel_calls": self.kernel_calls,

@@ -8,7 +8,7 @@ masks applied eagerly, so a ``FusedSegment`` on this backend is the
 loop-free reference the jitted jax segment kernel is checked against."""
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Mapping, Sequence, Tuple
+from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -56,12 +56,18 @@ class NumpyBackend(Backend):
     def eval_expression(self, fn: Callable, cache, rows: slice) -> np.ndarray:
         return np.asarray(fn(cache, rows))
 
-    def searchsorted_probe(self, dim, vals) -> Tuple[np.ndarray, np.ndarray]:
-        return dim.probe(np.asarray(vals))
-
-    def lookup_gather(self, dim, dim_col: str, idx, matched, default):
-        got = dim.payload[dim_col][np.asarray(idx)]
-        return np.where(np.asarray(matched), got, np.asarray(default, got.dtype))
+    def lookup(self, dim, vals, return_cols: Mapping[str, str], default,
+               matched_flag: Optional[str] = None) -> Dict[str, np.ndarray]:
+        idx, matched = dim.probe(np.asarray(vals))
+        out = {}
+        for out_name, dim_col in return_cols.items():
+            col = dim.payload[dim_col]
+            got = col[idx] if len(col) else np.zeros(len(idx), col.dtype)
+            out[out_name] = np.where(matched, got,
+                                     np.asarray(default, got.dtype))
+        if matched_flag:
+            out[matched_flag] = matched
+        return out
 
     def groupby_reduce(self, keys: Sequence, values: Mapping[str, Tuple[object, str]],
                        n_rows: int) -> Tuple[List[np.ndarray], Dict[str, np.ndarray]]:

@@ -21,7 +21,6 @@ Every setting also has a first-class API equivalent (see the README table):
     REPRO_ARENA_MAX_MB   CacheArena(max_bytes=...)
     REPRO_CACHE_GUARD    debug only (split-overlap checks + buffer poisoning)
     REPRO_SEGSUM_IMPL    kernels.segment_sum(impl=...)
-    REPRO_JOIN_IMPL      kernels.hash_join probe route in JaxBackend lookups
     REPRO_GROUPBY_IMPL   kernels.radix_groupby route in JaxBackend groupbys
     REPRO_OPTEQ_EXAMPLES test harness scale (property-based equivalence)
     REPRO_FLOW_STYLE     etl.queries builders' use_dsl= argument
@@ -54,10 +53,6 @@ ENV_OPTEQ_EXAMPLES = "REPRO_OPTEQ_EXAMPLES"
 #: segment-sum kernel implementation selector ("auto" / "pallas" /
 #: "interpret" / "reference")
 ENV_SEGSUM_IMPL = "REPRO_SEGSUM_IMPL"
-#: Lookup probe route on the jax backend: "auto" / "reference" (the
-#: kernels.hash_join open-addressing probe, through XLA) or "searchsorted"
-#: (legacy binary-search probe over the sorted DimTable)
-ENV_JOIN_IMPL = "REPRO_JOIN_IMPL"
 #: groupby route on the jax backend: radix-groupby kernel impls ("auto" /
 #: "pallas" / "interpret" / "reference") or "sort" (legacy lexsort +
 #: segment-sum route; also the automatic fallback for sparse/non-integer
@@ -121,7 +116,6 @@ DEAD_LETTER_MAX = 256
 DEFAULT_ARENA_MAX_MB = 256
 DEFAULT_OPTEQ_EXAMPLES = 100
 FLOW_STYLES = ("dsl", "lambda")
-JOIN_IMPLS = ("auto", "reference", "searchsorted")
 GROUPBY_IMPLS = ("auto", "pallas", "interpret", "reference", "sort")
 SHARD_IMPLS = ("auto", "process", "mesh", "inline")
 
@@ -176,17 +170,6 @@ def opteq_examples(default: int = DEFAULT_OPTEQ_EXAMPLES) -> int:
 def segsum_impl() -> str:
     """Implementation selector for the segment-sum kernel."""
     return _raw(ENV_SEGSUM_IMPL) or "auto"
-
-
-def join_impl() -> str:
-    """Lookup probe route on the jax backend: the hash-join probe ("auto"
-    or "reference") or "searchsorted" for the legacy binary-search probe."""
-    v = _raw(ENV_JOIN_IMPL) or "auto"
-    if v not in JOIN_IMPLS:
-        raise ValueError(
-            f"{ENV_JOIN_IMPL}={v!r} is not a valid join impl; "
-            f"expected one of {JOIN_IMPLS}")
-    return v
 
 
 def groupby_impl() -> str:
@@ -311,7 +294,6 @@ def snapshot() -> Dict[str, object]:
         "cache_guard": cache_guard_enabled(),
         "opteq_examples": opteq_examples(),
         "segsum_impl": segsum_impl(),
-        "join_impl": join_impl(),
         "groupby_impl": groupby_impl(),
         "flow_style": flow_style(),
         "trace": trace_enabled(),

@@ -230,6 +230,10 @@ class DimTable:
     fused segment's ``lookup.<name>`` scopes and probe counters); without
     one they take the fact table's key column."""
 
+    #: the attribute the jax backend caches the table's device form under
+    #: (``core/backend/jax_dims.py``)
+    DEVICE_FORM = "_device_form"
+
     def __init__(self, key: np.ndarray, payload: Dict[str, np.ndarray],
                  row_filter: Optional[np.ndarray] = None,
                  name: Optional[str] = None):
@@ -251,12 +255,10 @@ class DimTable:
         return idx, matched
 
     def __getstate__(self):
-        # the jax backend caches device arrays on the instance; they don't
-        # pickle (process shard route) and rebuild lazily in the worker
+        # the device form holds device arrays; they don't pickle (process
+        # shard route) and rebuild lazily in the worker
         state = dict(self.__dict__)
-        state.pop("_jax_device_cache", None)
-        state.pop("_jax_hash_cache", None)
-        state.pop("_jax_slot_cache", None)
+        state.pop(self.DEVICE_FORM, None)
         return state
 
 
@@ -293,16 +295,9 @@ class Lookup(RowSyncMT):
                  self.default, self.matched_flag)]
 
     def process_range(self, cache: SharedCache, rows: slice) -> dict:
-        bk = self.get_backend()
-        vals = cache.col(self.key_col)[rows]
-        idx, matched = bk.searchsorted_probe(self.dim, vals)
-        out: Dict[str, np.ndarray] = {}
-        for out_name, dim_col in self.return_cols.items():
-            out[out_name] = bk.lookup_gather(self.dim, dim_col, idx, matched,
-                                             self.default)
-        if self.matched_flag:
-            out[self.matched_flag] = matched
-        return out
+        return self.get_backend().lookup(
+            self.dim, cache.col(self.key_col)[rows], self.return_cols,
+            self.default, self.matched_flag)
 
     def merge_ranges(self, cache: SharedCache, ranges: List[slice],
                      parts: List[dict]) -> List[SharedCache]:

@@ -1,5 +1,5 @@
-"""Host-side build + the jnp probe for the hash join (the Lookup's device
-route, jit'd by ``ops.hash_probe`` and inlined by the fused segment kernel).
+"""Host-side build + the jnp slot functions for the hash join (every jax
+Lookup runs them, ``core/backend/jax_dims.py``).
 
 The build runs ONCE per dimension table on the host (numpy) and the probe
 runs per chunk on the device, so the two halves must agree bit-for-bit on
@@ -19,10 +19,16 @@ the keys it is given:
 
 Either way keys compare on their low 32 bits.  Duplicate keys keep the
 FIRST occurrence (lowest row index).  Built over a ``DimTable``'s sorted
-key column this makes the probe's gather index equal to ``searchsorted``'s
-leftmost-duplicate index, so the hash route is byte-compatible with the
-legacy sorted-probe route; over an arbitrary (shuffled) key order it is
+key column this makes the row a key's slot holds (``slot_idx``) equal to
+``searchsorted``'s leftmost-duplicate index, the one ``DimTable.probe``
+(the host reference) returns; over an arbitrary (shuffled) key order it is
 simply first-occurrence-wins.
+
+There is no Pallas form: the TPU compiler refuses in-kernel gathers from a
+VMEM table (``NotImplementedError: Only 2D gather is supported``, also for
+a lane-dense ``(T/128, 128)`` table and for a 2D ``take_along_axis``), and a
+``(T, 1)`` table of 2^19 slots would pad to 256 MiB of VMEM.  XLA lowers
+the slot functions and the Lookup's gathers to its own gathers from HBM.
 """
 from __future__ import annotations
 
@@ -191,7 +197,7 @@ def hash_build(key_cols: Sequence[np.ndarray]) -> Dict[str, object]:
 
 def probe_lengths_np(built: Dict[str, object],
                      val_cols: Sequence[np.ndarray]) -> np.ndarray:
-    """Per probe row, the passes of :func:`hash_probe_ref`'s loop that
+    """Per probe row, the passes of the device probe's loop that
     settle it: from the row's home slot (the table's own slot function) up
     to the slot holding its key (a hit) or the first empty slot (a miss),
     walked on the host over ``hash_build``'s table, and at most
@@ -221,34 +227,31 @@ def probe_lengths_np(built: Dict[str, object],
     return out
 
 
-def hash_probe_ref(slot_keys: Sequence[jax.Array], slot_idx: jax.Array,
-                   val_cols: Sequence[jax.Array], max_probes: int,
-                   base: Optional[int]) -> Tuple[jax.Array, jax.Array]:
-    """Pure-jnp probe: returns ``(row_idx int32, found bool)`` per probe
-    row.  ``row_idx`` is the build's first-occurrence index for found keys
-    and 0 for misses (callers gate every gather on ``found``).  Traceable —
-    the fused segment kernel inlines this directly; ``max_probes`` and
-    ``base`` are static (``hash_build``'s).
+def direct_slots(vals: jax.Array, base: int,
+                 size: int) -> Tuple[jax.Array, jax.Array]:
+    """A direct table's slot function, traced: ``(slot int32, in_range
+    bool)`` per probe row, slot ``(uint32(key) - uint32(base)) & (size -
+    1)`` and ``in_range`` where ``uint32(key) - uint32(base) < size``.  A
+    key in range owns its slot (the slots past the span are empty), so the
+    slot's contents settle the row; a key out of range misses."""
+    off = vals.astype(jnp.uint32) - jnp.uint32(base % (1 << 32))
+    in_range = off < jnp.uint32(size)
+    slot = (off & jnp.uint32(size - 1)).astype(jnp.int32)
+    return slot, in_range
 
-    ``base`` None: ``max_probes`` passes of linear probing from the fmix32
-    home slot.  ``base`` an int (a direct table): one pass, a gather of
-    ``slot_idx`` at the home slot ``(uint32(key) - uint32(base)) & (T - 1)``
-    under a range check ``uint32(key) - uint32(base) < T`` in place of the
-    slot-key compare.  It is exact: a key in range finds its own slot (the
-    slots past the span are empty), and a key out of range misses."""
+
+def hashed_slots(slot_keys: Sequence[jax.Array], slot_idx: jax.Array,
+                 val_cols: Sequence[jax.Array],
+                 max_probes: int) -> Tuple[jax.Array, jax.Array]:
+    """An fmix32 table's slot function, traced: ``max_probes`` passes of
+    linear probing from each row's home slot.  Returns ``(slot int32,
+    found bool)``: the slot holding the row's key where found, else 0."""
     size = slot_idx.shape[0]
-    if base is not None:
-        (v,) = val_cols
-        off = v.astype(jnp.uint32) - jnp.uint32(base % (1 << 32))
-        home = (off & jnp.uint32(size - 1)).astype(jnp.int32)
-        occ = jnp.take(slot_idx, home, mode="clip")
-        found = (off < jnp.uint32(size)) & (occ >= 0)
-        return jnp.where(found, occ, 0), found
     n = val_cols[0].shape[0]
     h = hash_keys(list(val_cols))
 
     def body(step, carry):
-        idx, found, done = carry
+        slot, found, done = carry
         cand = ((h + jnp.uint32(step)) & jnp.uint32(size - 1)).astype(jnp.int32)
         occ = jnp.take(slot_idx, cand, mode="clip")
         eq = jnp.ones(n, dtype=bool)
@@ -256,11 +259,13 @@ def hash_probe_ref(slot_keys: Sequence[jax.Array], slot_idx: jax.Array,
             eq = eq & (jnp.take(sk, cand, mode="clip") == v)
         hit = (~done) & (occ >= 0) & eq
         miss = (~done) & (occ < 0)
-        idx = jnp.where(hit, occ, idx)
-        return idx, found | hit, done | hit | miss
+        slot = jnp.where(hit, cand, slot)
+        return slot, found | hit, done | hit | miss
 
-    idx = jnp.zeros(n, dtype=jnp.int32)
+    slot = jnp.zeros(n, dtype=jnp.int32)
     found = jnp.zeros(n, dtype=bool)
     done = jnp.zeros(n, dtype=bool)
-    idx, found, _ = jax.lax.fori_loop(0, max_probes, body, (idx, found, done))
-    return idx, found
+    slot, found, _ = jax.lax.fori_loop(0, max_probes, body,
+                                       (slot, found, done))
+    return slot, found
+

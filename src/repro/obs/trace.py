@@ -29,7 +29,7 @@ Event model (Chrome trace "traceEvents" array, ts/dur in µs):
                              compiled op to ``jax.named_scope`` (cat
                              ``program``, name ``scopes``)
   ph="C" counter events    — channel occupancy (cat ``channel``), each
-                             hash-probe Lookup's rows and passes (cat
+                             fused Lookup's rows, passes and gathers (cat
                              ``probe``, named by the dimension table)
 
 Spans opened with ``span`` are also ``jax.profiler.TraceAnnotation``s named

@@ -151,7 +151,7 @@ def test_eval_expression_equivalence(seed):
 
 @given(st.integers(0, 2**31 - 1))
 @settings(max_examples=15, deadline=None)
-def test_searchsorted_probe_and_gather_equivalence(seed):
+def test_lookup_equivalence(seed):
     rng = np.random.default_rng(seed)
     n_dim = int(rng.integers(1, 100))
     keys = np.unique(rng.integers(0, 500, n_dim)).astype(np.int64)
@@ -160,12 +160,11 @@ def test_searchsorted_probe_and_gather_equivalence(seed):
     dim = DimTable(keys, payload, row_filter=qual)
     vals = rng.integers(0, 500, int(rng.integers(1, 300))).astype(np.int64)
 
-    i_np, m_np = _np().searchsorted_probe(dim, vals)
-    g_np = _np().lookup_gather(dim, "v", i_np, m_np, -1)
-    i_j, m_j = _jax().searchsorted_probe(dim, vals)
-    g_j = _host(_jax(), _jax().lookup_gather(dim, "v", i_j, m_j, -1))
-    np.testing.assert_array_equal(m_np, _host(_jax(), m_j))
-    np.testing.assert_array_equal(g_np, g_j)
+    want = _np().lookup(dim, vals, {"g": "v"}, -1, "m")
+    got = _jax().lookup(dim, vals, {"g": "v"}, -1, "m")
+    assert list(got) == list(want) == ["g", "m"]
+    np.testing.assert_array_equal(want["m"], _host(_jax(), got["m"]))
+    np.testing.assert_array_equal(want["g"], _host(_jax(), got["g"]))
 
 
 @given(st.integers(0, 2**31 - 1))

@@ -241,43 +241,38 @@ def _jax_backend():
 def test_degraded_impl_walks_ladder_and_sticks():
     bk = _jax_backend()
     with fault_recorder() as rec:
-        # auto IS the XLA hash probe: a failing auto probe goes straight to
-        # searchsorted, never "degrades" to the same code
-        assert bk._degraded_impl(
-            "join", "auto", ValueError("x")) == "searchsorted"
-        assert bk._join_route == "searchsorted"
-        # ladder floor: nothing below searchsorted
-        assert bk._degraded_impl("join", "searchsorted", ValueError("x")) is None
-        assert bk._join_route == "searchsorted"
         # interpret mode is never a production rung: a failing pallas
         # group-by goes straight to the XLA reference
-        assert bk._degraded_impl(
-            "groupby", "pallas", ValueError("x")) == "reference"
+        assert bk._degraded_impl("pallas", ValueError("x")) == "reference"
+        assert bk._groupby_route == "reference"
         # off the TPU, auto group-by is the reference: it degrades to sort
-        assert bk._degraded_impl("groupby", "auto", ValueError("x")) == "sort"
+        assert bk._degraded_impl("auto", ValueError("x")) == "sort"
+        assert bk._groupby_route == "sort"
+        # ladder floor: nothing below sort
+        assert bk._degraded_impl("sort", ValueError("x")) is None
+        assert bk._groupby_route == "sort"
     assert [(d.src, d.dst) for d in rec.degradations] == [
-        ("join[reference]", "searchsorted"), ("groupby[pallas]", "reference"),
-        ("groupby[reference]", "sort")]
+        ("groupby[pallas]", "reference"), ("groupby[reference]", "sort")]
     assert all(d.kind == "kernel" for d in rec.degradations)
-    assert "interpret" not in bk._JOIN_LADDER + bk._GROUPBY_LADDER
+    assert "interpret" not in bk._GROUPBY_LADDER
 
 
 def test_degraded_impl_propagates_transient_and_injected():
     bk = _jax_backend()
     # transient => replay retries the SAME route instead of degrading
-    assert bk._degraded_impl("join", "auto", TransientFault("t")) is None
-    assert bk._degraded_impl("join", "auto", ConnectionError("t")) is None
+    assert bk._degraded_impl("auto", TransientFault("t")) is None
+    assert bk._degraded_impl("auto", ConnectionError("t")) is None
     # injected permanent/poison faults must abort, not silently degrade
-    assert bk._degraded_impl("groupby", "pallas", PermanentFault("p")) is None
-    assert bk._degraded_impl("groupby", "pallas", PoisonFault("p")) is None
-    assert bk._join_route is None and bk._groupby_route is None
+    assert bk._degraded_impl("pallas", PermanentFault("p")) is None
+    assert bk._degraded_impl("pallas", PoisonFault("p")) is None
+    assert bk._groupby_route is None
 
 
 def test_degrade_disabled_by_env(monkeypatch):
     monkeypatch.setenv("REPRO_DEGRADE", "0")
     bk = _jax_backend()
-    assert bk._degraded_impl("join", "auto", ValueError("x")) is None
-    assert bk._join_route is None
+    assert bk._degraded_impl("pallas", ValueError("x")) is None
+    assert bk._groupby_route is None
 
 
 # ---------------------------------------------------------------------------

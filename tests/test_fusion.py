@@ -7,6 +7,7 @@ Backend follows ``REPRO_BACKEND`` (the CI matrix runs this file under both
 backend.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -336,7 +337,7 @@ def test_fused_segment_does_not_resurrect_projected_columns():
 
 
 # ---------------------------------------------------------------------------
-#  Slot-addressed Lookups on direct dimension tables (jax backend)
+#  Lookups read slot-addressed columns, on direct and hashed tables (jax)
 # ---------------------------------------------------------------------------
 _I32 = np.iinfo(np.int32)
 _SRNG = np.random.default_rng(16)
@@ -394,9 +395,8 @@ _SLOT_CASES = {
 
 
 def _slot_lookup(case):
-    """The outputs of a fused segment of one Lookup (route as the
-    environment and the build pick it) over ``case``'s probes, and its
-    runner."""
+    """The outputs of a fused segment of one Lookup (on the slot function
+    the build picks) over ``case``'s probes, its runner and its table."""
     from repro.core.backend.jax_backend import JaxBackend
     payload = {"v": case["payload"],
                "w": np.arange(len(case["keys"])) * 7 - 500}
@@ -413,29 +413,34 @@ def _slot_lookup(case):
 
 
 @pytest.mark.parametrize("case", sorted(_SLOT_CASES))
-def test_slot_addressed_lookup_matches_hash_and_searchsorted(case,
-                                                             monkeypatch):
-    """A Lookup on a direct table gathers from slot-addressed payloads; its
-    outputs and match flag are byte-identical to the fmix32 hash route
-    (the same keys forced off direct addressing), to the legacy
-    ``searchsorted`` route and to ``DimTable.probe`` on the host."""
+def test_lookup_on_both_table_forms_matches_the_host_probe(case,
+                                                           monkeypatch):
+    """A Lookup reads slot-addressed columns on a direct table and on an
+    fmix32 one (the same keys forced off direct addressing), fused and
+    unfused: the four outputs and match flags are byte-identical, and agree
+    with the host reference's searchsorted probe (``DimTable.probe``)."""
     pytest.importorskip("jax")
+    from repro.core.backend.jax_backend import JaxBackend
     c = _SLOT_CASES[case]
     got, runner, dim = _slot_lookup(c)
-    assert runner._slots[0] is not None
+    (look,) = runner.device_dims()
+    assert look.form.base is not None
     # the match bit rides on an integer column whose span leaves room
-    carrier = runner._slots[0]["carrier"]
-    assert (carrier and carrier[0]) == c["carrier"]
+    assert (look.plan.carrier and look.plan.carrier[0]) == c["carrier"]
     with monkeypatch.context() as m:
         m.setattr("repro.kernels.hash_join.ref.DIRECT_MAX_RATIO", 0)
-        hashed, hrunner, _ = _slot_lookup(c)
-        assert hrunner._slots[0] is None and hrunner._tables[0]["base"] is None
-    with monkeypatch.context() as m:
-        m.setenv("REPRO_JOIN_IMPL", "searchsorted")
-        legacy, lrunner, _ = _slot_lookup(c)
-        assert lrunner._tables[0] is None
+        hashed, hrunner, hdim = _slot_lookup(c)
+        (hlook,) = hrunner.device_dims()
+        assert hlook.form.base is None
+        assert hlook.plan.carrier == look.plan.carrier
+    returns = {f"out_{col}": col for col in c["cols"]}
+    unfused = {}
+    for name, d in (("unfused", dim), ("unfused hash", hdim)):
+        out = JaxBackend().lookup(d, c["probes"], returns, c["default"],
+                                  c["flag"])
+        unfused[name] = {k: np.asarray(v) for k, v in out.items()}
     idx, matched = dim.probe(c["probes"])
-    for name, want in (("hash", hashed), ("searchsorted", legacy)):
+    for name, want in (("hash", hashed), *unfused.items()):
         assert got.keys() == want.keys(), name
         for col in got:
             assert got[col].dtype == want[col].dtype, (name, col)
@@ -447,6 +452,40 @@ def test_slot_addressed_lookup_matches_hash_and_searchsorted(case,
         want = np.where(matched, dim.payload[col][idx],
                         np.asarray(c["default"])).astype(out.dtype)
         np.testing.assert_array_equal(out, want, err_msg=col)
+
+
+@pytest.mark.parametrize("cols,flag", [(("v",), None), ((), "ok"),
+                                       (("v", "w"), "ok")])
+def test_lookup_into_an_empty_table_answers_every_row_unmatched(cols, flag):
+    """A table with no rows answers every probe unmatched, with the
+    Lookup's ``default`` in each returned column: fused and unfused on the
+    jax backend, and on the numpy reference, as ``DimTable.probe`` says."""
+    pytest.importorskip("jax")
+    from repro.core.backend.jax_backend import JaxBackend
+    from repro.core.backend.numpy_backend import NumpyBackend
+    probes = np.arange(-3, 40, dtype=np.int64)
+    empty = np.zeros(0, np.int64)
+    dim = DimTable(empty, {"v": empty, "w": np.zeros(0, np.float32)},
+                   name="d")
+    returns = {f"out_{c}": c for c in cols}
+    lk = Lookup("lk", dim, "fk", returns, default=7, matched_flag=flag)
+    runner = JaxBackend().compile_segment(FusedSegment.from_components([lk]))
+    cache = SharedCache({"fk": probes.copy()})
+    runner(cache)
+    names = sorted(returns) + ([flag] if flag else [])
+    runs = {"fused": {n: np.asarray(cache.col(n)) for n in names}}
+    for bk in (JaxBackend(), NumpyBackend()):
+        out = bk.lookup(dim, probes, returns, 7, flag)
+        runs[type(bk).__name__] = {n: np.asarray(a) for n, a in out.items()}
+    _, matched = dim.probe(probes)
+    assert not matched.any()
+    for name, got in runs.items():
+        assert sorted(got) == sorted(names), name
+        if flag:
+            np.testing.assert_array_equal(got[flag], matched, err_msg=name)
+        for out, col in returns.items():
+            assert got[out].shape == probes.shape, (name, out)
+            assert (got[out] == 7).all(), (name, out)
 
 
 def _ssb_segment(query, sparse_part=False):
@@ -477,25 +516,32 @@ def test_fused_segment_gathers_once_per_returned_column(query, sparse_part,
     tables holds one gather per Lookup (four payloads) and Q1.1's one (its
     ``d_ok`` flag rides on ``d_year``): no ``slot_idx`` or ``qualifies``
     gather.  The tables' sizes all differ, so no two gathers share one
-    outlined function.  A hashed part table keeps its probe loop."""
+    outlined function.  A hashed part table keeps its probe loop (a
+    ``slot_idx`` and a slot-key gather a pass), and after it gathers its
+    one returned column, as a direct one does: the compiled program holds
+    six gathers."""
     import jax.numpy as jnp
     data, runner = _ssb_segment(query, sparse_part)
     bucket = 2048
     entries, total = runner.pack_layout(
         bucket, [(c, data.lineorder[c].dtype) for c in sorted(runner.inputs)])
     dims = runner.device_dims()
-    text = runner._jit.lower((bucket, tuple(entries)),
-                             jnp.zeros((total,), jnp.uint8), {},
-                             dims).as_text()
+    lowered = runner._jit.lower((bucket, tuple(entries)),
+                                jnp.zeros((total,), jnp.uint8), {}, dims)
+    text = lowered.as_text()
     n = text.count('"stablehlo.gather"(')
+    assert [look.gathers for look in dims] == [1] * len(dims)
     if gathers is not None:
-        assert n == gathers
+        assert n == gathers == len(dims)
         assert "stablehlo.while" not in text
-        assert runner._gathers == [1] * gathers
     else:
-        assert "stablehlo.while" in text and n > 4
-        assert [p is None for p in runner._slots] == [False, False, True,
-                                                      False]
+        assert "stablehlo.while" in text
+        # gathers of one table shape share an outlined function: count
+        # them in the compiled program, where each is its own op
+        compiled = lowered.compile().as_text()
+        assert len(re.findall(r"\bgather\(", compiled)) == 2 + len(dims)
+        assert [look.form.base is None for look in dims] == [False, False,
+                                                             True, False]
 
 
 # ---------------------------------------------------------------------------

@@ -6,8 +6,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
-from repro.kernels.hash_join import (hash_build, hash_keys, hash_keys_np,
-                                     hash_probe, hash_probe_ref,
+from repro.kernels.hash_join import (direct_slots, hash_build, hash_keys,
+                                     hash_keys_np, hashed_slots,
                                      probe_lengths_np)
 from repro.kernels.mamba_scan import mamba_scan, mamba_scan_ref
 from repro.kernels.radix_groupby import radix_groupby, radix_groupby_ref
@@ -61,11 +61,28 @@ def _probe_oracle(key_rows, probe_rows):
     return idx, found
 
 
+def _rows(slot_keys, slot_idx, val_cols, max_probes, base):
+    """Each probe row's build row, traced: the table's slot function
+    (``direct_slots`` where ``base`` is an int, else ``hashed_slots``),
+    then one gather of ``slot_idx``, the slot-addressed column of row
+    numbers (-1 in an empty slot).  Returns ``(row, found)``, row 0 on a
+    miss."""
+    if base is None:
+        slot, found = hashed_slots(slot_keys, slot_idx, val_cols, max_probes)
+    else:
+        slot, found = direct_slots(val_cols[0], base, slot_idx.shape[0])
+    row = jnp.where(found, jnp.take(slot_idx, slot, mode="clip"), -1)
+    return jnp.maximum(row, 0), row >= 0
+
+
+_rows_jit = jax.jit(_rows, static_argnames=("max_probes", "base"))
+
+
 def _probe(built, cols):
-    return hash_probe(tuple(jnp.asarray(k) for k in built["slot_keys"]),
-                      jnp.asarray(built["slot_idx"]),
-                      tuple(jnp.asarray(c) for c in cols),
-                      built["max_probes"], built["base"])
+    return _rows_jit(
+        tuple(jnp.asarray(k) for k in built["slot_keys"]),
+        jnp.asarray(built["slot_idx"]), tuple(jnp.asarray(c) for c in cols),
+        max_probes=built["max_probes"], base=built["base"])
 
 
 def _home(built, key_cols):
@@ -239,8 +256,9 @@ def test_hash_probe_all_miss_and_empty_probe():
 
 
 def test_hash_probe_ref_traceable():
-    """hash_probe_ref must trace under jit with max_probes and base static
-    — the fused segment kernel inlines it."""
+    """The slot functions trace under jit over a table closed over as
+    constants, with max_probes and base static — as the fused segment
+    kernel inlines them."""
     keys = np.sort(RNG.choice(1_000, 300, replace=False)).astype(np.int64)
     built = hash_build((keys,))
     sk = tuple(jnp.asarray(k) for k in built["slot_keys"])
@@ -249,8 +267,7 @@ def test_hash_probe_ref_traceable():
 
     @jax.jit
     def f(p):
-        return hash_probe_ref(sk, si, (p,), built["max_probes"],
-                              built["base"])
+        return _rows(sk, si, (p,), built["max_probes"], built["base"])
 
     idx, found = f(jnp.asarray(probes))
     oi, of = _probe_oracle(keys[:, None], probes[:, None])

@@ -487,18 +487,19 @@ def test_fused_q41_scope_map_names_each_lookups_probe(q41_runner, data,
     (np.arange(0, 10**6, 997), np.arange(-5, 10**6, 3001)),         # sparse
     (np.arange(0, 0), np.arange(5)),                                 # empty
 ])
-def test_probe_need_is_the_walk_of_every_row(q41_runner, keys, vals):
+def test_probe_need_is_the_walk_of_every_row(keys, vals):
     """A traced call's ``need``, whether looked up per key over a dense key
     range or walked, is the host walk's sum over the rows."""
     from repro.core.backend.jax_backend import JaxBackend
+    from repro.core.backend.jax_dims import device_form
     from repro.etl.components import DimTable
-    from repro.kernels.hash_join import probe_lengths_np
+    from repro.kernels.hash_join import hash_build, probe_lengths_np
     keys = keys.astype(np.int64)
-    table = JaxBackend()._dim_hash(DimTable(keys, {"v": keys}))
-    want = int(probe_lengths_np(table["host"], (vals,)).sum())
-    assert q41_runner._probe_need(table, vals) == want
-    assert q41_runner._probe_need(table, vals) == want   # cached lengths
-    assert (table["lengths"][1] is None) == (len(keys) != 300)
+    form = device_form(DimTable(keys, {"v": keys}), JaxBackend().asarray)
+    want = int(probe_lengths_np(hash_build((keys,)), (vals,)).sum())
+    assert form.need(vals) == want
+    assert form.need(vals) == want   # cached lengths
+    assert (form._lengths[1] is None) == (len(keys) != 300)
 
 
 @pytest.mark.parametrize("part_keys", ["dense", "sparse"])
@@ -520,14 +521,13 @@ def test_fused_q41_counts_probe_passes_per_lookup(q41_runner, data,
     assert probes["part"] == {
         "rows": 5000, "padded_rows": 8192, "passes": table["max_probes"],
         "direct": int(dense), "mean_probes": table["mean_probes"],
-        "slots": table["table_size"], "gathers": 1 if dense else 2,
-        "slot_addressed": int(dense), "need": need}
+        "slots": table["table_size"], "gathers": 1, "need": need}
     for name, p in probes.items():
+        assert p["gathers"] == 1
         if name == "part" and part_keys == "sparse":
             assert p["passes"] > 1 and p["rows"] < p["need"]
         else:
             assert (p["passes"], p["direct"], p["need"]) == (1, 1, 5000)
-            assert (p["slot_addressed"], p["gathers"]) == (1, 1)
     # each row of each Lookup needs at least one pass, at most all
     for p in probes.values():
         assert p["rows"] <= p["need"] <= p["rows"] * p["passes"]
@@ -540,10 +540,10 @@ def test_fused_q41_counts_probe_passes_per_lookup(q41_runner, data,
 
 @pytest.mark.parametrize("part_keys", ["dense", "sparse"])
 def test_probe_counter_counts_gathers_and_slot_addressing(data, part_keys):
-    """A Lookup on a direct table reads slot-addressed arrays: one gather a
-    row per returned column, or one where it returns none (Q1.1's date
-    Lookup returns one column and its match bit rides on it).  On a hashed
-    table it gathers ``qualifies`` and each payload after the probe loop."""
+    """A Lookup reads slot-addressed arrays, on a direct table and on a
+    hashed one alike: one gather a row per returned column, or one where
+    it returns none (Q1.1's date Lookup returns one column and its match
+    bit rides on it).  On a hashed table these follow the probe loop."""
     from repro.etl.components import DimTable, Lookup
     part = _sparse_part(data) if part_keys == "sparse" else data
     table = DimTable(part.part["p_partkey"],
@@ -567,11 +567,10 @@ def test_probe_counter_counts_gathers_and_slot_addressing(data, part_keys):
     events = _segment_call(runner, part, traced=True)
     probes = {e["name"]: e["args"] for e in events
               if (e["ph"], e["cat"]) == ("C", "probe")}
-    got = {name: (p["slot_addressed"], p["gathers"])
-           for name, p in probes.items()}
+    got = {name: (p["direct"], p["gathers"]) for name, p in probes.items()}
     sparse = part_keys == "sparse"
     assert got == {"date": (1, 1), "supplier": (1, 1),
-                   "part": (0, 3) if sparse else (1, 2)}
+                   "part": (int(not sparse), 2)}
 
 
 # ---------------------------------------------------------------------------

@@ -358,16 +358,14 @@ def test_dsl_vs_lambda_ssb_byte_identical(qname, ssb_dsl_data):
 
 
 # ---------------------------------------------------------------------------
-#  kernel impl routes: the hash-join probe and the dense radix groupby must
-#  be byte-identical to the legacy searchsorted/sort routes AND to the
-#  numpy-backend oracle, across the same property harness
+#  kernel impl routes: the dense radix groupby must be byte-identical to the
+#  legacy sort route AND, with the jax Lookup, to the numpy-backend oracle,
+#  across the same property harness
 # ---------------------------------------------------------------------------
-def _run_with_impls(spec, backend, join_impl, groupby_impl, fuse=False):
+def _run_with_impls(spec, backend, groupby_impl, fuse=False):
     import os
     _, num_splits, _ = spec
-    saved = {k: os.environ.get(k)
-             for k in (config.ENV_JOIN_IMPL, config.ENV_GROUPBY_IMPL)}
-    os.environ[config.ENV_JOIN_IMPL] = join_impl
+    saved = {k: os.environ.get(k) for k in (config.ENV_GROUPBY_IMPL,)}
     os.environ[config.ENV_GROUPBY_IMPL] = groupby_impl
     try:
         flow, sink = build_flow(spec)
@@ -398,14 +396,14 @@ def _assert_tables_equal(got, oracle, label, check_dtype=True):
 @given(flow_spec())
 @settings(max_examples=max(N_EXAMPLES // 4, 10), deadline=None)
 def test_kernel_impl_routes_byte_identical(spec):
-    """For every generated DAG: the jax backend under the hash-probe +
-    dense-groupby routes produces byte-identical sinks to the legacy
-    searchsorted + sort routes and to the numpy-backend oracle."""
+    """For every generated DAG: the jax backend under the dense-groupby
+    route produces byte-identical sinks to the legacy sort route, and both
+    its Lookup and its group-by agree with the numpy-backend oracle."""
     if "jax" not in _dsl_backends():      # pragma: no cover
         pytest.skip("jax backend unavailable")
-    oracle = _run_with_impls(spec, "numpy", "searchsorted", "sort")
-    legacy = _run_with_impls(spec, "jax", "searchsorted", "sort")
-    kernel = _run_with_impls(spec, "jax", "reference", "reference")
+    oracle = _run_with_impls(spec, "numpy", "sort")
+    legacy = _run_with_impls(spec, "jax", "sort")
+    kernel = _run_with_impls(spec, "jax", "reference")
     # within-backend: new routes vs legacy routes, dtypes strict
     _assert_tables_equal(kernel, legacy, f"kernel-vs-legacy (spec={spec})")
     # cross-backend: values vs the numpy oracle (int widths differ by design)
@@ -415,8 +413,8 @@ def test_kernel_impl_routes_byte_identical(spec):
 
 def test_kernel_impl_interpret_route_fused():
     """The Pallas group-by kernel BODY (interpret mode) behind the same
-    flows, with segment fusion on — the fused runner inlines the hash
-    probe, the Aggregate rides the dense groupby."""
+    flows, with segment fusion on — the fused runner inlines the Lookup,
+    the Aggregate rides the dense groupby."""
     if "jax" not in _dsl_backends():      # pragma: no cover
         pytest.skip("jax backend unavailable")
     spec = (7, 4, [("lookup", 3, 0, True),
@@ -424,8 +422,8 @@ def test_kernel_impl_interpret_route_fused():
                    ("filter", 4, 30, True),
                    ("agg", 2, 5, "sum"),
                    ("sort", 0)])
-    legacy = _run_with_impls(spec, "jax", "searchsorted", "sort")
-    got = _run_with_impls(spec, "jax", "reference", "interpret", fuse=True)
+    legacy = _run_with_impls(spec, "jax", "sort")
+    got = _run_with_impls(spec, "jax", "interpret", fuse=True)
     _assert_tables_equal(got, legacy, "interpret-routes+fusion")
 
 

@@ -4,9 +4,10 @@ Each test compiles ahead of time, for one chip of a ``v5e:2x2`` topology,
 a kernel that ``auto`` picks on a TPU, at the largest shapes the SF1 chip
 smoke (``chip_smoke.py``) feeds it:
 
-* the Lookup probe route (XLA, ``hash_probe_ref``) over the 200k-row part
-  table at 2^21 probe rows: direct (T = 2^18 slots, one pass) and fmix32
-  (T = 2^19 slots, the table sparse part keys would give);
+* one Lookup (``DimLookup``: the slot function, then one gather of a
+  slot-addressed column) over the 200k-row part table at 2^21 probe rows:
+  direct (T = 2^18 slots, one pass) and fmix32 (T = 2^19 slots, the table
+  sparse part keys would give);
 * ``radix_groupby_pallas`` at the serving batch's 2^21 rows and at Q2.1's
   7000 dense cells;
 * ``segment_sum_pallas`` at Q1.1's 2^17 rows;
@@ -76,15 +77,17 @@ def _fits(compiled) -> None:
 ])
 def test_probe_route_compiles_at_sf1_part_table(one_chip, T, max_probes,
                                                 base):
-    from repro.kernels.hash_join import hash_probe
+    from repro.core.backend.jax_dims import DimLookup, Plan
     N = 1 << 21
-
-    def probe(slot_keys, slot_idx, vals):
-        return hash_probe((slot_keys,), slot_idx, (vals,), max_probes, base)
-
-    compiled = jax.jit(probe).lower(
-        _spec(one_chip, (T,), jnp.int32), _spec(one_chip, (T,), jnp.int32),
-        _spec(one_chip, (N,), jnp.int32)).compile()
+    arrays = {"cols": {"p_brand1": _spec(one_chip, (T,), jnp.int32)}}
+    if base is None:
+        arrays.update(slot_keys=(_spec(one_chip, (T,), jnp.int32),),
+                      slot_idx=_spec(one_chip, (T,), jnp.int32))
+    look = DimLookup.tree_unflatten(
+        Plan(base, T, max_probes, None, (("brand", "p_brand1"),), -1, None),
+        (arrays,))
+    compiled = jax.jit(lambda look, vals: look(vals)).lower(
+        look, _spec(one_chip, (N,), jnp.int32)).compile()
     _fits(compiled)
 
 
@@ -175,8 +178,9 @@ def fused_q41(one_chip):
     dims = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
                         runner.device_dims())
     # every SSB table is direct: the kernel reads slot-addressed payloads
-    assert max(int(a.shape[0]) for d in dims for a in d["cols"].values()
-               ) == 1 << 18
+    assert max(int(a.shape[0]) for d in dims
+               for a in d.arrays["cols"].values()) == 1 << 18
+    assert all(d.plan.base is not None for d in dims)
     return runner._jit.lower(
         (bucket, tuple(entries)), _spec(one_chip, (total,), jnp.uint8), {},
         dims).compile()
