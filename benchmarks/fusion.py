@@ -87,8 +87,8 @@ def smoke(data):
     active backend, with the reductions ENFORCED — fewer backend dispatch
     calls always; on a mask-deferring backend (jax) fewer h2d transfers,
     STRICTLY fewer d2h transfers, and the per-chunk keep-mask syncs gone:
-    deferral replaces one mask compact per chunk with one at the terminal
-    Aggregate's finish, so unfused_d2h - fused_d2h >= num_splits - 1.
+    deferral replaces one mask compact per chunk with the terminal
+    Aggregate's masked reduce, so unfused_d2h - fused_d2h >= num_splits - 1.
 
     Returns ``(failures, extras)`` where extras carries the per-flow
     transfer counters for the bench JSON (``bench_diff`` locks them in
@@ -124,8 +124,8 @@ def smoke(data):
                     (f"{flow}: fused d2h transfers {r_f.d2h_transfers} !< "
                      f"unfused {r_u.d2h_transfers}")
                 # zero per-chunk keep-mask syncs: the unfused run pays one
-                # mask compact per chunk, the fused run exactly one (at the
-                # Aggregate's finish)
+                # mask compact per chunk, the fused run none (the
+                # Aggregate's reduce applies the mask on the device)
                 saved = r_u.d2h_transfers - r_f.d2h_transfers
                 assert saved >= num_splits - 1, \
                     (f"{flow}: only {saved} d2h syncs eliminated; expected "

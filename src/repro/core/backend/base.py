@@ -43,8 +43,9 @@ AGG_OPS = ("sum", "avg", "min", "max", "count")
 #: sentinel column a deferring fused segment leaves in its output cache: the
 #: segment's combined keep-mask, NOT applied per chunk (that would force a
 #: device->host sync every chunk).  The terminal ``Aggregate`` pops it after
-#: the device-side concat and compacts the merged cache ONCE.  The name is
-#: illegal as a user column (spaces), so it can never shadow real data.
+#: the device-side concat and hands it to ``groupby_reduce``, which reduces
+#: only the rows it keeps.  The name is illegal as a user column (spaces), so
+#: it can never shadow real data.
 SEGMENT_KEEP_MASK = "__segment keep mask__"
 
 #: environment variable naming the default backend for the process
@@ -126,12 +127,18 @@ class Backend:
         raise NotImplementedError
 
     def groupby_reduce(self, keys: Sequence, values: Mapping[str, Tuple[object, str]],
-                       n_rows: int) -> Tuple[List[object], Dict[str, object]]:
+                       n_rows: int, mask=None
+                       ) -> Tuple[List[object], Dict[str, object]]:
         """Group-by aggregation.  ``keys`` are the group-by columns (empty =>
         one global group over ``n_rows`` rows); ``values`` maps output name
         -> (value column, op) with op in AGG_OPS.  Returns (group key
         columns in lexicographic ascending group order, aggregate columns in
-        the same group order)."""
+        the same group order).
+
+        ``mask``, where given, is a boolean keep-mask over the ``n_rows``
+        rows: the result equals compacting every column by it and then
+        reducing.  Where no row is kept there is no group, keyless or not:
+        ``no_groups``."""
         raise NotImplementedError
 
     def sort_rows(self, keys: Sequence, ascending: bool = True):
@@ -165,6 +172,15 @@ class Backend:
 
     def __repr__(self) -> str:
         return f"<{type(self).__name__} {self.name!r}>"
+
+
+def no_groups(keys: Sequence, values: Mapping[str, tuple]
+              ) -> Tuple[List[np.ndarray], Dict[str, np.ndarray]]:
+    """``groupby_reduce``'s result over no rows: every key and aggregate
+    column empty, the keys int64 and the aggregates float64 (the dtypes of
+    an empty ``Aggregate`` output)."""
+    return ([np.zeros(0, np.int64) for _ in keys],
+            {out: np.zeros(0, np.float64) for out in values})
 
 
 # ---------------------------------------------------------------------------

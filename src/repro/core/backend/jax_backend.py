@@ -48,9 +48,9 @@ from ...obs import trace as obs_trace
 from .. import config, faults, wideint
 from ..expr import ColumnsView, Expr
 from ..wideint import Wide, WideColumn
-from ..shared_cache import (GLOBAL_ARENA, is_host_column,
+from ..shared_cache import (GLOBAL_ARENA, SharedCache, is_host_column,
                             record_segment_compile, record_transfer)
-from .base import AGG_OPS, Backend, SegmentEnv
+from .base import AGG_OPS, Backend, SegmentEnv, no_groups
 from .jax_dims import DimLookup, device_form
 
 
@@ -90,6 +90,30 @@ def _narrow(col):
             f"min and max of a wide integer column ({col.bound[0]} to "
             f"{col.bound[1]}) are not supported on the device")
     return col
+
+
+def _extreme(jnp, op: str, vals, mask):
+    """``min`` or ``max`` of ``vals`` over the rows ``mask`` keeps (every
+    row where there is none; at least one is kept)."""
+    fn = jnp.min if op == "min" else jnp.max
+    if mask is None:
+        return fn(vals)
+    if jnp.issubdtype(vals.dtype, jnp.floating):
+        lo, hi = -np.inf, np.inf
+    elif vals.dtype == jnp.bool_:
+        lo, hi = False, True
+    else:
+        lo, hi = jnp.iinfo(vals.dtype).min, jnp.iinfo(vals.dtype).max
+    # typed: 2**32 - 1 does not fit the int32 a bare int would be
+    return fn(vals, where=mask,
+              initial=vals.dtype.type(hi if op == "min" else lo))
+
+
+def _count_mask(rows: int, kept: int) -> None:
+    """The ``groupby`` ``mask`` counter of a masked reduce, while traced:
+    the rows it reduced and the rows the keep-mask kept."""
+    if obs_trace.ACTIVE.get():
+        obs_trace.counter("groupby", "mask", rows=int(rows), kept=int(kept))
 
 
 class _DeviceCacheView:
@@ -141,8 +165,8 @@ class JaxBackend(Backend):
     #: float32 accumulation (MXU width) vs the float64 oracles
     oracle_rtol = 1e-3
     #: fused row-sync chains may defer their combined keep-mask through a
-    #: terminal Aggregate (the per-chunk d2h sync disappears; Aggregate.finish
-    #: applies the mask once after the device-side concat)
+    #: terminal Aggregate (the per-chunk d2h sync disappears; the Aggregate's
+    #: ``groupby_reduce`` applies the mask to the device-side concat)
     supports_segment_defer = True
     #: dense-groupby guards: past either, fall back to the sort route
     #: (float32 counts are exact below 2^24; the dense cell count bounds the
@@ -243,21 +267,35 @@ class JaxBackend(Backend):
             wideint.check_fits(name or "<unnamed>", cd,
                                *wideint.column_range(x))
 
-    def _ranges(self, arrays: Sequence) -> List[Tuple[int, int]]:
-        """``(min, max)`` of each integer device array, in one d2h."""
-        if not arrays:
+    def _ranges(self, arrays: Sequence, mask=None) -> List[Tuple[int, int]]:
+        """``(min, max)`` of each integer device array, in one d2h.  With a
+        keep-mask the pairs are taken over the rows it keeps (``(dtype max,
+        dtype min)`` where it keeps none), and one more pair comes last:
+        ``(kept, n)``, the rows it keeps of its ``n``."""
+        if not arrays and mask is None:
             return []
         jnp, lax = self._jnp, self._jax.lax
         rows = []
         for a in arrays:
-            mm = (jnp.stack([jnp.min(a), jnp.max(a)]) if a.size
-                  else jnp.zeros((2,), a.dtype))
+            if mask is not None:
+                info, typed = jnp.iinfo(a.dtype), a.dtype.type
+                mm = jnp.stack(
+                    [jnp.min(a, where=mask, initial=typed(info.max)),
+                     jnp.max(a, where=mask, initial=typed(info.min))])
+            elif a.size:
+                mm = jnp.stack([jnp.min(a), jnp.max(a)])
+            else:
+                mm = jnp.zeros((2,), a.dtype)
             rows.append(lax.bitcast_convert_type(mm, jnp.int32)
                         if a.dtype == jnp.uint32 else mm.astype(jnp.int32))
+        if mask is not None:
+            rows.append(jnp.stack([jnp.sum(mask, dtype=jnp.int32),
+                                   jnp.int32(mask.shape[0])]))
         host = self.to_host(jnp.stack(rows))
         return [tuple(int(v) for v in (h.view(np.uint32)
-                                       if a.dtype == jnp.uint32 else h))
-                for a, h in zip(arrays, host)]
+                                       if a is not None
+                                       and a.dtype == jnp.uint32 else h))
+                for a, h in zip([*arrays, None], host)]
 
     def to_host(self, x) -> np.ndarray:
         if isinstance(x, np.ndarray):
@@ -420,37 +458,20 @@ class JaxBackend(Backend):
         return {name: a[:n] for name, a in self._lookup_jit(look, v).items()}
 
     def groupby_reduce(self, keys: Sequence, values: Mapping[str, Tuple[object, str]],
-                       n_rows: int) -> Tuple[List[object], Dict[str, object]]:
+                       n_rows: int, mask=None
+                       ) -> Tuple[List[object], Dict[str, object]]:
+        """The dense and keyless routes apply a keep-mask inside the reduce:
+        masked rows take group id -1, which every group-by kernel drops (its
+        padding id), so no row moves.  The sort route compacts first."""
         for out, (col, op) in values.items():
             if op not in AGG_OPS:
                 raise ValueError(f"unknown agg op {op!r} for {out!r}")
         jnp = self._jnp
         n = int(n_rows)
+        if mask is not None:
+            mask = self.asarray(mask)
         if not keys:
-            aggs: Dict[str, object] = {}
-            cols, pos = self._exact_inputs(values)
-            if cols:
-                exact = self._exact_keyless(cols, n)
-            for out, (col, op) in values.items():
-                if op == "count":
-                    aggs[out] = np.array([n], dtype=np.int64)
-                    continue
-                if out in pos:
-                    s = exact[pos[out]]
-                    aggs[out] = self._avg(s, [n]) if op == "avg" else s
-                    continue
-                vals = self.asarray(col)
-                if op in ("sum", "avg"):
-                    zeros = jnp.zeros((n,), dtype=jnp.int32)
-                    s = self._segment_sum(zeros,
-                                          vals.astype(jnp.float32)[:, None],
-                                          1, impl=self._segsum_impl)[:, 0]
-                    aggs[out] = self._avg(s, [n]) if op == "avg" else s
-                elif op == "min":
-                    aggs[out] = jnp.min(_narrow(vals))[None]
-                elif op == "max":
-                    aggs[out] = jnp.max(_narrow(vals))[None]
-            return [], aggs
+            return self._groupby_keyless(values, n, mask)
         keys_d = [self.asarray(k) for k in keys]
         impl = self._resolve_impl(self._groupby_route
                                   or config.groupby_impl())
@@ -458,7 +479,7 @@ class JaxBackend(Backend):
             try:
                 if faults.active():
                     faults.inject("kernel", component=f"groupby[{impl}]")
-                dense = self._groupby_dense(keys_d, values, n, impl)
+                dense = self._groupby_dense(keys_d, values, n, impl, mask)
             except BaseException as e:
                 nxt = self._degraded_impl(impl, e)
                 if nxt is None:
@@ -468,6 +489,10 @@ class JaxBackend(Backend):
             if dense is not None:
                 return dense
             break          # key space disqualified: legacy sort route
+        if mask is not None:
+            keys_d, values, n = self._compact(keys_d, values, mask)
+        if n == 0:
+            return no_groups(keys_d, values)
         order = jnp.lexsort(tuple(keys_d[::-1]))
         sk = [k[order] for k in keys_d]
         boundary = jnp.zeros((n,), dtype=bool).at[0].set(True)
@@ -509,6 +534,64 @@ class JaxBackend(Backend):
                 aggs[out] = self._jax.ops.segment_max(vals, seg,
                                                       num_segments=n_groups)
         return group_cols, aggs
+
+    def _groupby_keyless(self, values: Mapping[str, Tuple[object, str]],
+                         n: int, mask):
+        """One global group over the rows ``mask`` keeps (every row where
+        there is none)."""
+        jnp = self._jnp
+        cols, pos = self._exact_inputs(values)
+        seg = jnp.zeros((n,), jnp.int32)
+        kept, ranges = n, []
+        if mask is not None:
+            # one d2h: the integer inputs' kept ranges and the kept count
+            *ranges, (kept, _) = self._ranges(
+                [c for c in cols if not isinstance(c, WideColumn)], mask)
+            _count_mask(n, kept)
+            if not kept:
+                return no_groups([], values)
+            seg = jnp.where(mask, seg, -1)
+        if cols:
+            _, _, exact = self._exact(
+                self._segment_sum, seg, jnp.zeros((n, 0), jnp.float32), 1,
+                cols, self._value_ranges(cols, ranges),
+                impl=self._segsum_impl)
+        aggs: Dict[str, object] = {}
+        for out, (col, op) in values.items():
+            if op == "count":
+                aggs[out] = np.array([kept], dtype=np.int64)
+                continue
+            if out in pos:
+                s = exact[pos[out]]
+                aggs[out] = self._avg(s, [kept]) if op == "avg" else s
+                continue
+            vals = self.asarray(col)
+            if op in ("sum", "avg"):
+                v = vals.astype(jnp.float32)
+                if mask is not None:     # 0 x NaN in the matmul is NaN
+                    v = jnp.where(mask, v, 0.0)
+                s = self._segment_sum(seg, v[:, None], 1,
+                                      impl=self._segsum_impl)[:, 0]
+                aggs[out] = self._avg(s, [kept]) if op == "avg" else s
+            else:
+                aggs[out] = _extreme(jnp, op, _narrow(vals), mask)[None]
+        return [], aggs
+
+    def _compact(self, keys_d: List, values: Mapping[str, Tuple[object, str]],
+                 mask):
+        """The sort route's input: the keys and each distinct value column
+        at the rows ``mask`` keeps (``SharedCache.compact``: the mask's d2h,
+        one index upload, a gather per column), and their count."""
+        n = int(mask.shape[0])
+        cache = SharedCache(
+            {**{f"k{i}": k for i, k in enumerate(keys_d)},
+             **{f"v{id(col)}": self.asarray(col)
+                for col, _ in values.values()}}, n)
+        cache.compact(mask)
+        _count_mask(n, cache.n)
+        return ([cache.col(f"k{i}") for i in range(len(keys_d))],
+                {out: (cache.col(f"v{id(col)}"), op)
+                 for out, (col, op) in values.items()}, cache.n)
 
     def _exact_inputs(self, values: Mapping[str, Tuple[object, str]]
                       ) -> Tuple[list, Dict[str, int]]:
@@ -563,16 +646,6 @@ class JaxBackend(Backend):
             wideint.exact_result(t) for t in wideint.recombine(
                 host[..., 1:], host[..., 0], offsets, limbs)]
 
-    def _exact_keyless(self, cols: list, n: int) -> List[np.ndarray]:
-        """Exact int64 totals of the integer ``cols`` over all ``n``
-        rows."""
-        jnp = self._jnp
-        _, _, exact = self._exact(
-            self._segment_sum, jnp.zeros((n,), jnp.int32),
-            jnp.zeros((n, 0), jnp.float32), 1, cols,
-            self._value_ranges(cols, []), impl=self._segsum_impl)
-        return exact
-
     def _avg(self, sums, counts) -> np.ndarray:
         """``sums / counts`` divided on the host, where IEEE division rounds
         once in the sum's dtype (float64 for the exact int64 sums).  The
@@ -583,7 +656,7 @@ class JaxBackend(Backend):
         return s / np.asarray(counts).astype(s.dtype)
 
     def _groupby_dense(self, keys_d: List, values: Mapping[str, Tuple[object, str]],
-                       n: int, impl: str):
+                       n: int, impl: str, mask=None):
         """Radix-partitioned groupby over a dense composite key id — no sort.
 
         Each key column is offset to zero and the tuple is flattened into one
@@ -596,7 +669,9 @@ class JaxBackend(Backend):
         carries the values' too).  Occupied cells are recovered from the
         counts (the only extra d2h) and group key columns are reconstructed
         arithmetically from the cell ids — the row data is never sorted and
-        never leaves the device.
+        never leaves the device.  Rows a keep-mask drops take id -1 and count
+        in no min/max: their keys may lie anywhere (an unmatched Lookup's
+        default) without widening the key space.
 
         Returns ``None`` when the key space doesn't qualify (empty input,
         non-integer keys, cell count past the VMEM-scaled bound, row count
@@ -610,9 +685,16 @@ class JaxBackend(Backend):
             if not jnp.issubdtype(k.dtype, jnp.integer):
                 return None
         cols, pos = self._exact_inputs(values)
-        # one d2h for every key's and integer input's min/max
+        # one d2h for every key's and integer input's min/max (and the kept
+        # count)
         narrow = [c for c in cols if not isinstance(c, WideColumn)]
-        lo_hi = self._ranges(keys_d + narrow)
+        lo_hi = self._ranges(keys_d + narrow, mask)
+        kept = n
+        if mask is not None:
+            *lo_hi, (kept, _) = lo_hi
+            if not kept:
+                _count_mask(n, 0)
+                return no_groups(keys_d, values)
         mins = [lo for lo, _ in lo_hi[:len(keys_d)]]
         ranges = [hi - lo + 1 for lo, hi in lo_hi[:len(keys_d)]]
         cells = 1
@@ -626,6 +708,8 @@ class JaxBackend(Backend):
         ids = jnp.zeros((n,), jnp.int32)
         for k, mn, st in zip(keys_d, mins, strides):
             ids = ids + (k.astype(jnp.int32) - mn) * st
+        if mask is not None:
+            ids = jnp.where(mask, ids, -1)
 
         sum_outs = [out for out, (_, op) in values.items()
                     if op in ("sum", "avg") and out not in pos]
@@ -633,6 +717,8 @@ class JaxBackend(Backend):
                for out in sum_outs]
         vmat = (jnp.stack(mat, axis=1) if mat
                 else jnp.zeros((n, 0), jnp.float32))
+        if mat and mask is not None:     # 0 x NaN in the matmul is NaN
+            vmat = jnp.where(mask[:, None], vmat, 0.0)
         if cols:
             sums, counts_h, exact = self._exact(
                 self._radix_groupby, ids, vmat, cells, cols,
@@ -659,6 +745,8 @@ class JaxBackend(Backend):
                       else self._jax.ops.segment_max)
                 aggs[out] = fn(_narrow(self.asarray(col)), ids,
                                num_segments=cells)[occ_d]
+        if mask is not None:
+            _count_mask(n, kept)
         return group_cols, aggs
 
     def sort_rows(self, keys: Sequence, ascending: bool = True):
@@ -922,7 +1010,7 @@ class _JaxSegmentRunner:
         if self.defer_mask:
             # fused-through-Aggregate: the per-chunk compact (this chunk's
             # ONLY d2h) is deferred — the keep-mask rides along as a device
-            # sentinel column and Aggregate.finish applies it once to the
+            # sentinel column and the Aggregate's reduce applies it to the
             # merged cache, and no column the Aggregate does not read goes
             # into its merge
             from .base import SEGMENT_KEEP_MASK

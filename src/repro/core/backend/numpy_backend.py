@@ -13,7 +13,7 @@ from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import wideint
-from .base import AGG_OPS, Backend
+from .base import AGG_OPS, Backend, no_groups
 
 
 def _sum_dtype(vals: np.ndarray):
@@ -70,11 +70,20 @@ class NumpyBackend(Backend):
         return out
 
     def groupby_reduce(self, keys: Sequence, values: Mapping[str, Tuple[object, str]],
-                       n_rows: int) -> Tuple[List[np.ndarray], Dict[str, np.ndarray]]:
+                       n_rows: int, mask=None
+                       ) -> Tuple[List[np.ndarray], Dict[str, np.ndarray]]:
         for out, (col, op) in values.items():
             if op not in AGG_OPS:
                 raise ValueError(f"unknown agg op {op!r} for {out!r}")
         n = int(n_rows)
+        if mask is not None:
+            keep = np.asarray(mask, dtype=bool)
+            keys = [np.asarray(k)[keep] for k in keys]
+            values = {out: (np.asarray(col)[keep], op)
+                      for out, (col, op) in values.items()}
+            n = int(keep.sum())
+        if n == 0:
+            return no_groups(keys, values)
         if not keys:
             # global aggregation: one group over all rows
             aggs: Dict[str, np.ndarray] = {}
@@ -87,7 +96,7 @@ class NumpyBackend(Backend):
                         _sum_dtype(vals)).sum()]))
                 elif op == "avg":
                     total = vals.astype(_sum_dtype(vals)).sum()
-                    aggs[out] = np.array([total / n if n else np.nan])
+                    aggs[out] = np.array([total / n])
                 elif op == "min":
                     aggs[out] = np.array([vals.min()])
                 elif op == "max":

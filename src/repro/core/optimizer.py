@@ -270,7 +270,7 @@ def fuse_segments_flow(flow: Dataflow) -> List[Rewrite]:
     joins the fused kernel, but its presence lets the segment defer its
     combined keep-mask (``FusedSegment.defer_mask_to``) — deferral-capable
     backends then skip the per-chunk compact, the mask rides downstream as a
-    device column, and ``Aggregate.finish`` applies it once after the merge.
+    device column, and the Aggregate's reduce applies it after the merge.
     """
     from ..etl.components import FusedSegment   # deferred (layering)
     out: List[Rewrite] = []

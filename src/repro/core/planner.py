@@ -63,9 +63,9 @@ def discover_segments(flow: Dataflow,
     ``segment_terminal_aggregate`` (the ``Aggregate`` block component): the
     aggregate then appears as the chain's LAST member.  It does not join the
     fused kernel — the optimizer strips it before collapsing — but marks the
-    segment for keep-mask deferral: the per-chunk compact moves into
-    ``Aggregate.finish``, applied once after the merge (the d2h mask sync a
-    device backend would otherwise pay per chunk disappears)."""
+    segment for keep-mask deferral: the per-chunk compact gives way to the
+    Aggregate's reduce, which applies the mask after the merge (the d2h
+    mask sync a device backend would otherwise pay per chunk disappears)."""
     chains: List[List[str]] = []
     seen: set = set()
     for name in flow.topo_order():

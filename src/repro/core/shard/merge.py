@@ -179,7 +179,7 @@ class ShardContext:
                     (self.pass_k, part))
                 self.shuffle_bytes += sum(
                     np.asarray(v).nbytes for v in part.values())
-        return root.shard_empty()
+        return root.empty_output()
 
     def _partial_generic(self, root, state: List[SharedCache],
                          tags: List[Tuple[int, int]]) -> SharedCache:

@@ -720,8 +720,8 @@ class Aggregate(BlockComponent):
     the group's previously emitted value)."""
 
     #: segment fusion may extend a row-sync chain through this component:
-    #: the fused segment defers its keep-mask (no per-chunk d2h) and finish()
-    #: applies it once to the merged cache before reducing
+    #: the fused segment defers its keep-mask (no per-chunk d2h) and the
+    #: reduce of the merged cache applies it (``_reduce``)
     segment_terminal_aggregate = True
 
     def __init__(self, name: str, group_by: Sequence[ColumnRef],
@@ -793,21 +793,34 @@ class Aggregate(BlockComponent):
         st.keys = list(snap[1])
         st.partials = {p: list(v) for p, v in snap[2].items()}
 
+    def _reduce(self, merged: SharedCache, aggs: Dict[str, Tuple[str, str]]):
+        """``groupby_reduce`` of the merged input: ``(group key columns,
+        aggregate columns)``, or ``None`` where no row is left.  A keep-mask
+        an upstream fused segment deferred goes into the reduce, which
+        applies it in place of compacting every column."""
+        mask = None
+        if SEGMENT_KEEP_MASK in merged.names:
+            mask = merged.col(SEGMENT_KEEP_MASK)
+            merged.keep_columns(
+                [c for c in merged.names if c != SEGMENT_KEEP_MASK])
+        if merged.n == 0:
+            return None
+        group_cols, agg_cols = self.get_backend().groupby_reduce(
+            [merged.col(g) for g in self.group_by],
+            _agg_values(merged, aggs), merged.n, mask=mask)
+        if any(len(c) == 0 for c in (*group_cols, *agg_cols.values())):
+            return None               # the mask kept no row
+        return group_cols, agg_cols
+
     def _serving_finish(self, merged: SharedCache) -> SharedCache:
         st = self._serving
         plan = self._partial_plan()
-        n = merged.n
-        if n == 0:
-            # empty tick: nothing merges, the delta is empty (same dtype
-            # conventions as the batch empty path)
-            cols = {g: np.array([], dtype=np.int64) for g in self.group_by}
-            for out in self.aggs:
-                cols[out] = np.array([], dtype=np.float64)
-            return SharedCache(cols, 0)
+        got = self._reduce(merged, plan)
+        if got is None:
+            # empty tick: nothing merges, the delta is empty
+            return self.empty_output()
         bk = self.get_backend()
-        group_cols, part_cols = bk.groupby_reduce(
-            [merged.col(g) for g in self.group_by],
-            _agg_values(merged, plan), n)
+        group_cols, part_cols = got
         group_h = [np.asarray(bk.to_host(c)) for c in group_cols]
         part_h = {p: np.asarray(bk.to_host(c)) for p, c in part_cols.items()}
         merged.recycle()            # tick-loop steady state: buffers pool
@@ -855,27 +868,14 @@ class Aggregate(BlockComponent):
     # ------------------------------------------------------------ batch
     def finish(self, state: List[SharedCache]) -> SharedCache:
         merged = concat_caches(state, ordered=True, recycle_inputs=True)
-        if SEGMENT_KEEP_MASK in merged.names:
-            # an upstream fused segment deferred its keep-mask: drop the
-            # sentinel and compact the MERGED cache once — on device backends
-            # this is the single d2h mask sync that replaced one per chunk
-            mask = merged.col(SEGMENT_KEEP_MASK)
-            merged.keep_columns(
-                [c for c in merged.names if c != SEGMENT_KEEP_MASK])
-            merged.compact(mask)
         if self._serving is not None:
             return self._serving_finish(merged)
-        n = merged.n
-        if n == 0:
-            cols = {g: np.array([], dtype=np.int64) for g in self.group_by}
-            for out in self.aggs:
-                cols[out] = np.array([], dtype=np.float64)
-            return SharedCache(cols, 0)
         # groupby_reduce is the backend's block kernel: the jax backend routes
-        # sum/avg through the kernels/segment_sum Pallas op
-        group_cols, agg_cols = self.get_backend().groupby_reduce(
-            [merged.col(g) for g in self.group_by],
-            _agg_values(merged, self.aggs), n)
+        # sums through the radix group-by and segment-sum Pallas ops
+        got = self._reduce(merged, self.aggs)
+        if got is None:
+            return self.empty_output()
+        group_cols, agg_cols = got
         cols = dict(zip(self.group_by, group_cols))
         cols.update(agg_cols)
         # degenerate global aggregation with no agg columns: one empty row
@@ -897,21 +897,12 @@ class Aggregate(BlockComponent):
         table ``{group col / partial name: np.ndarray}``; ``None`` when the
         shard delivered no rows (nothing to merge)."""
         merged = concat_caches(state, ordered=True, recycle_inputs=True)
-        if SEGMENT_KEEP_MASK in merged.names:
-            # same deferred-keep-mask compaction as finish(): one d2h sync
-            mask = merged.col(SEGMENT_KEEP_MASK)
-            merged.keep_columns(
-                [c for c in merged.names if c != SEGMENT_KEEP_MASK])
-            merged.compact(mask)
-        n = merged.n
-        if n == 0:
+        got = self._reduce(merged, self._partial_plan())
+        if got is None:
             merged.recycle()
             return None
-        plan = self._partial_plan()
         bk = self.get_backend()
-        group_cols, part_cols = bk.groupby_reduce(
-            [merged.col(g) for g in self.group_by],
-            _agg_values(merged, plan), n)
+        group_cols, part_cols = got
         table = {g: np.asarray(bk.to_host(c))
                  for g, c in zip(self.group_by, group_cols)}
         for p, c in part_cols.items():
@@ -919,9 +910,10 @@ class Aggregate(BlockComponent):
         merged.recycle()
         return table
 
-    def shard_empty(self) -> SharedCache:
-        """Schema-shaped empty output a shard pass emits downstream — the
-        same dtype conventions as the batch empty path."""
+    def empty_output(self) -> SharedCache:
+        """The output of a reduce that finds no row, schema-shaped (int64
+        keys, float64 aggregates, all empty): a batch run's, an empty
+        serving tick's, and what a shard pass emits downstream."""
         cols = {g: np.array([], dtype=np.int64) for g in self.group_by}
         for out in self.aggs:
             cols[out] = np.array([], dtype=np.float64)
@@ -940,7 +932,7 @@ class Aggregate(BlockComponent):
         if own is not None:
             tables.append(own)
         if not tables:
-            return self.shard_empty()
+            return self.empty_output()
         plan = self._partial_plan()
         second = {p: ("sum" if op == "count" else op)
                   for p, (_, op) in plan.items()}

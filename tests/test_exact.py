@@ -1,7 +1,9 @@
 """Exact integer arithmetic on the jax backend: wide expressions past int32
 in the fused segment and unfused, exact integer group sums on the dense,
 keyless and sort routes, and the numpy backend's int64 sums, each compared
-with numpy int64 by ``==``."""
+with numpy int64 by ``==``; and the group-by that applies a deferred
+keep-mask inside the reduce, on every route, against the numpy backend's
+compacted reduce."""
 from __future__ import annotations
 
 import numpy as np
@@ -295,3 +297,199 @@ def test_an_int64_column_past_int32_raises_at_pack(fuse):
     with pytest.raises(Exception, match="l_quantity"):
         repro.Session(backend="jax", metadata=None).run(
             flow, engine="streaming", fuse=fuse)
+
+
+# ---------------------------------------------------------------------------
+#  A deferred keep-mask applied inside the reduce
+# ---------------------------------------------------------------------------
+#: every op on an exact integer input, float sums of values a masked row
+#: leaves as NaN or inf, and an exact wide sum past 2**53 per group
+MASKED_AGGS = {"s": ("small", "sum"), "a": ("small", "avg"),
+               "mn": ("small", "min"), "mx": ("small", "max"),
+               "n": ("small", "count"), "w": ("w", "sum"),
+               "y": ("y", "sum"), "ya": ("y", "avg"), "ymx": ("y", "max")}
+#: the routes a masked reduce takes: ``far_keys`` gives every masked row a
+#: key far outside the kept rows' range (as an unmatched Lookup's default
+#: does), which must not widen the dense key space; ``sort`` spans more
+#: kept keys than the dense route takes; ``none_kept`` masks every row
+MASK_ROUTES = ["dense", "dense_pallas", "far_keys", "sort", "keyless",
+               "none_kept"]
+
+
+def _masked_table(n: int, seed: int, route: str) -> dict:
+    """A table whose rows with ``d == 0`` the flow's filter drops: there
+    ``y = x / d`` is inf or NaN; ``w = price * price`` is past int32 a row,
+    and its group sums past 2**53."""
+    rng = np.random.default_rng(seed)
+    t = {"k1": rng.integers(0, 4, n), "k2": rng.integers(0, 3, n),
+         "small": rng.integers(-40_000, 40_000, n),
+         "price": rng.integers(2**23, 2**24, n),
+         "x": rng.normal(size=n), "d": rng.integers(1, 9, n)}
+    dropped = rng.random(n) < 0.4
+    t["d"][dropped] = 0
+    t["x"][dropped[::2].nonzero()[0] * 2] = 0.0       # 0 / 0: NaN
+    if route == "far_keys":
+        t["k1"][dropped] = np.where(np.arange(dropped.sum()) % 2,
+                                    2**31 - 1, -2**31)
+    elif route == "sort":
+        t["k1"] = rng.integers(0, 2**21, n)
+    elif route == "none_kept":
+        t["d"][:] = 0
+    return t
+
+
+def _masked_keys(route: str) -> list:
+    return [] if route == "keyless" else ["k1", "k2"]
+
+
+def _check_masked(got: dict, want: dict) -> None:
+    """Keys, counts, integer sums, averages and extremes equal, the exact
+    sums in the same dtype (float64, or int64 past 2**53); float sums
+    within float32 accumulation of the float64 reference."""
+    assert list(got) == list(want)
+    for name, w in want.items():
+        g = np.asarray(got[name])
+        assert len(g) == len(w), name
+        if name in ("y", "ya", "ymx"):
+            np.testing.assert_allclose(g, w, rtol=1e-4, err_msg=name)
+            continue
+        if name in ("s", "a", "w"):
+            assert g.dtype == w.dtype, (name, g.dtype, w.dtype)
+        assert np.array_equal(g, w), (name, g, w)
+
+
+def _masked_flow(t: dict, route: str):
+    return (repro.flow("masked").source(t)
+            .filter(col("d") != 0)
+            .derive("y", col("x") / col("d"))
+            .derive("w", col("price") * col("price"))
+            .aggregate(_masked_keys(route), MASKED_AGGS).sink())
+
+
+def _no_compact(*_a, **_k):
+    raise AssertionError("the masked reduce compacted its input")
+
+
+@pytest.mark.parametrize("route", MASK_ROUTES)
+def test_masked_reduce_equals_the_compacted_reduce(route, monkeypatch):
+    """``groupby_reduce(mask=)`` on the jax backend equals the numpy
+    backend's reduce of the compacted columns on every route; only the
+    sort route compacts."""
+    from repro.core.backend.jax_backend import JaxBackend
+    if route == "dense_pallas":
+        monkeypatch.setenv("REPRO_GROUPBY_IMPL", "interpret")
+        monkeypatch.setenv("REPRO_SEGSUM_IMPL", "interpret")
+    n = 3000
+    t = _masked_table(n, seed=7, route=route)
+    mask = t["d"] != 0
+    with np.errstate(divide="ignore", invalid="ignore"):
+        y = t["x"] / t["d"]
+    w = t["price"] * t["price"]
+    keys = [t[k] for k in _masked_keys(route)]
+
+    def values(wcol):
+        cols = {"small": t["small"], "w": wcol, "y": y}
+        return {out: (cols[c], op) for out, (c, op) in MASKED_AGGS.items()}
+    want_keys, want = get_backend("numpy").groupby_reduce(
+        [k[mask] for k in keys],
+        {out: (c[mask], op) for out, (c, op) in values(w).items()},
+        int(mask.sum())) if mask.any() else (
+        [np.zeros(0, np.int64) for _ in keys],
+        {out: np.zeros(0, np.float64) for out in MASKED_AGGS})
+    bk = get_backend("jax")
+    if route != "sort":
+        monkeypatch.setattr(JaxBackend, "_compact", _no_compact)
+    dev = bk.asarray(y.astype(np.float32))
+    got_keys, got = bk.groupby_reduce(
+        keys, {out: (dev if c is y else c, op)
+               for out, (c, op) in values(wideint.from_host(w)).items()},
+        n, mask=mask)
+    names = [f"k{i}" for i in range(len(keys))]
+    _check_masked(dict(zip(names, (bk.to_host(k) for k in got_keys)),
+                       **{o: bk.to_host(v) for o, v in got.items()}),
+                  dict(zip(names, want_keys), **want))
+    if route in ("dense", "far_keys"):
+        assert len(want_keys[0]) == 12          # no group of a masked row
+        assert want["w"].dtype == np.int64      # past 2**53
+    if route == "none_kept":
+        assert got_keys == [] or all(len(k) == 0 for k in got_keys)
+        # the numpy backend gives the same for a mask that keeps none
+        assert _same_tables(get_backend("numpy").groupby_reduce(
+            keys, values(w), n, mask=mask), (got_keys, got))
+
+
+def _same_tables(a, b) -> bool:
+    ka, va = a
+    kb, vb = b
+    return (all(np.asarray(x).dtype == np.asarray(y).dtype
+                and np.array_equal(x, y) for x, y in zip(ka, kb))
+            and list(va) == list(vb)
+            and all(np.asarray(va[o]).dtype == np.asarray(vb[o]).dtype
+                    and np.array_equal(va[o], vb[o]) for o in va))
+
+
+@pytest.mark.parametrize("route", MASK_ROUTES)
+def test_fused_masked_aggregate_equals_numpy(route, monkeypatch):
+    """The same routes through a fused segment that defers its keep-mask
+    to the Aggregate: the sink table equals the numpy backend's, and no
+    cache is compacted but on the sort route."""
+    from repro.core.shared_cache import SharedCache
+    if route == "dense_pallas":
+        monkeypatch.setenv("REPRO_GROUPBY_IMPL", "interpret")
+        monkeypatch.setenv("REPRO_SEGSUM_IMPL", "interpret")
+    t = _masked_table(6000, seed=8, route=route)
+    want = repro.Session(backend="numpy", metadata=None).run(
+        _masked_flow(t, route), engine="streaming").table
+    if route != "sort":
+        monkeypatch.setattr(SharedCache, "compact", _no_compact)
+    res = repro.Session(backend="jax", metadata=None).run(
+        _masked_flow(t, route), engine="streaming", fuse=True, num_splits=3)
+    assert any(r["rule"] == "fuse-segment-aggregate"
+               for r in res.run.rewrites)
+    _check_masked(res.table, want)
+    if route == "none_kept":      # the empty table of an empty input
+        assert {k: v.dtype for k, v in res.table.items()} == {
+            **{k: np.int64 for k in _masked_keys(route)},
+            **{o: np.float64 for o in MASKED_AGGS}}
+    elif route != "sort":          # (the sort route's groups are single rows)
+        assert res.table["w"].dtype == np.int64
+
+
+def test_deferring_q1_reduces_without_compacting(monkeypatch):
+    """A fused Q1 that defers its keep-mask reduces the merged chunks in
+    place: nothing compacts a cache, and the ``groupby`` ``mask`` counter
+    reads the merged rows and the rows the filter keeps, once."""
+    from repro.core.shared_cache import SharedCache
+    from repro.obs import trace as obs_trace
+    n = 20_000
+    table = _lineitem(n, seed=9)
+    monkeypatch.setattr(SharedCache, "compact", _no_compact)
+    with obs_trace.trace_scope() as tr:
+        res = repro.Session(backend="jax", metadata=None).run(
+            _q1_flow(table), engine="streaming", fuse=True)
+    _exact_equal(res.table, _q1_numpy(table))
+    (ev,) = [e for e in tr.events if (e["ph"], e["cat"], e["name"])
+             == ("C", "groupby", "mask")]
+    assert ev["args"] == {
+        "rows": n, "kept": int((table["l_shipdate"] <= 19980902).sum())}
+
+
+@pytest.mark.parametrize("keyed", [True, False])
+def test_masked_reduce_of_unsigned_columns(keyed):
+    """uint32 keys and extremes: the masked ranges and identities take the
+    column's own dtype (``2**32 - 1`` does not fit int32)."""
+    rng = np.random.default_rng(11)
+    n = 2000
+    k = rng.integers(0, 5, n).astype(np.uint32)
+    v = rng.integers(0, 2**32, n, dtype=np.uint64).astype(np.uint32)
+    mask = rng.random(n) < 0.5
+    values = {"mn": (v, "min"), "mx": (v, "max"), "n": (v, "count")}
+    keys = [k] if keyed else []
+    want_keys, want = get_backend("numpy").groupby_reduce(
+        keys, values, n, mask=mask)
+    bk = get_backend("jax")
+    got_keys, got = bk.groupby_reduce(keys, values, n, mask=mask)
+    for g, w in zip(got_keys, want_keys):
+        assert np.array_equal(np.asarray(bk.to_host(g)), w)
+    for out, w in want.items():
+        assert np.array_equal(np.asarray(bk.to_host(got[out])), w), out

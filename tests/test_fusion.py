@@ -168,7 +168,7 @@ def test_fuse_segments_flow_defers_mask_to_aggregate():
 def test_deferring_segment_hands_on_only_the_aggregates_columns():
     """On the jax backend a segment that defers its keep-mask to the
     Aggregate leaves in the cache only the columns the Aggregate reads
-    and the mask: the others would be merged and compacted for nothing."""
+    and the mask: the others would be merged for nothing."""
     pytest.importorskip("jax")
     from repro.core.backend import SEGMENT_KEEP_MASK, get_backend
     from repro.core.shared_cache import SharedCache
@@ -249,8 +249,9 @@ def test_fused_engine_byte_identical(qname):
     assert r_f.dispatch_calls < r_s.dispatch_calls
     if get_default_backend().name == "jax":
         assert r_f.h2d_transfers < r_s.h2d_transfers
-        # deferral removes the per-chunk keep-mask sync: one compact at
-        # Aggregate.finish replaces num_splits per-chunk compacts
+        # deferral removes the per-chunk keep-mask syncs: the Aggregate's
+        # reduce applies the mask on the device, in place of num_splits
+        # per-chunk compacts
         assert r_s.d2h_transfers - r_f.d2h_transfers >= 4 - 1
 
 
